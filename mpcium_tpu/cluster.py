@@ -139,6 +139,8 @@ class LocalCluster(SyncOps):
         batch_deadline_ms: Optional[int] = None,  # config defaults; see
         batch_max_queue_depth: Optional[int] = None,  # config.py batch_*)
         batch_manifest_timeout_s: Optional[float] = None,
+        loopback_workers: int = 16,  # fabric pool size: the signing bridge
+        # holds a queue worker per in-flight sign, so this bounds them
     ):
         from .config import init_config
 
@@ -177,7 +179,7 @@ class LocalCluster(SyncOps):
             )
             self.fabric = None
         else:
-            self.fabric = LoopbackFabric()
+            self.fabric = LoopbackFabric(workers=loopback_workers)
             self._mk_transport = self.fabric.transport
         # fault-injection seam (mpcium_tpu/faults): nodes with a plan get
         # their transport wrapped; with no plan nothing is constructed and

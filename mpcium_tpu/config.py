@@ -56,7 +56,7 @@ class AppConfig:
     warm_budget_s: float = 300.0  # boot stays "warming" at most this long
     warm_schemes: str = "eddsa"  # comma list of eddsa,ecdsa,dkg,reshare ("" = all)
     warm_max_b: int = 64  # largest batch bucket to pre-warm
-    warm_cache_dir: str = ""  # "" = <db_dir>/<node>/warm_cache_<hostfp>
+    warm_cache_dir: str = ""  # "" = <checkout>/.jax_cache; JAX_COMPILATION_CACHE_DIR wins over both
 
     def to_json(self, mask_secrets: bool = True) -> Dict[str, Any]:
         out = {}

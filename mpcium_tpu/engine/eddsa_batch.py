@@ -58,13 +58,25 @@ _WIDE_LIMBS = 43
 # each party-round across all its chips with no kernel changes
 # (SURVEY.md §2.2 dimension 2). None ⇒ plain single-device placement.
 _SESSION_SHARDING = None
+# how many tensors entered the engine on the DEFAULT device while a mesh
+# was armed (session axis not divisible by the mesh): legal for small
+# batches, but a full-width wave that counts any is running on one chip
+_UNSHARDED_PLACEMENTS = 0
 
 
 def arm_session_sharding(sharding) -> None:
     """Install (or clear, with None) the NamedSharding applied by
-    :func:`to_dev`. Called by engine.sharded.arm_session_axis()."""
-    global _SESSION_SHARDING
+    :func:`to_dev`, and zero the unsharded-placement count. Called by
+    engine.sharded.arm_session_axis()."""
+    global _SESSION_SHARDING, _UNSHARDED_PLACEMENTS
     _SESSION_SHARDING = sharding
+    _UNSHARDED_PLACEMENTS = 0
+
+
+def unsharded_placements() -> int:
+    """Tensors :func:`to_dev` placed on the default device since the
+    mesh was armed because their session axis did not divide it."""
+    return _UNSHARDED_PLACEMENTS
 
 
 def to_dev(x, axis: int = 0) -> jnp.ndarray:
@@ -73,13 +85,17 @@ def to_dev(x, axis: int = 0) -> jnp.ndarray:
     (round tensors like (q, B, 32) are party-leading: sharding axis 0
     there would partition the committee, forcing cross-device gathers in
     the aggregations). Axes that don't divide the mesh fall back to
-    default placement rather than failing the dispatch."""
+    default placement rather than failing the dispatch (sub-mesh batches
+    are legal traffic); each fallback is counted
+    (:func:`unsharded_placements`)."""
+    global _UNSHARDED_PLACEMENTS
     arr = jnp.asarray(x)
     s = _SESSION_SHARDING
     if s is None or arr.ndim <= axis:
         return arr
     n = s.mesh.devices.size
     if arr.shape[axis] % n != 0:
+        _UNSHARDED_PLACEMENTS += 1
         return arr
     if axis == 0:
         return jax.device_put(arr, s)

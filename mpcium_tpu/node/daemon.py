@@ -181,18 +181,14 @@ def run_node(
         session_wal=session_wal,
     )
     # multi-device hosts shard the session axis of batched dispatches
-    # over every local chip (engine/sharded.py; no-op on one device)
-    try:
-        import jax as _jax
+    # over every local chip (engine/sharded.py; no-op on one device). A
+    # node that cannot shard over the chips it sees does not start.
+    from ..engine.sharded import arm_session_axis
 
-        from ..engine.sharded import arm_session_axis
-
-        mesh = arm_session_axis()
-        if mesh is not None:
-            log.info("session axis sharded over local devices",
-                     devices=len(_jax.devices()))
-    except Exception as e:  # noqa: BLE001 — never block startup on this
-        log.warn("session-axis sharding unavailable", error=repr(e))
+    mesh = arm_session_axis()
+    if mesh is not None:
+        log.info("session axis sharded over local devices",
+                 devices=mesh.devices.size)
 
     consumer = EventConsumer(
         node, transport,

@@ -13,8 +13,8 @@ paid once per host+toolchain, not once per restart
 The walk is budget-aware and failure-isolated: a deadline miss marks the
 remaining entries ``skipped`` (the daemon goes ready anyway — cold, but
 alive), and a runner exception marks that entry ``failed`` without
-taking boot down. The report lands as ``WARM_MANIFEST.json`` next to
-the cache, one verdict per signature.
+taking boot down. The report lands as ``WARM_MANIFEST.json`` in the
+caller's report directory, one verdict per signature.
 """
 from __future__ import annotations
 
@@ -210,21 +210,18 @@ RUNNERS: Dict[str, Callable[[wm.WarmEntry], None]] = {
 # -- cache configuration -----------------------------------------------------
 
 
-def configure_cache(cache_dir: str, min_compile_s: float = 0.0) -> None:
-    """Point the XLA persistent cache at ``cache_dir`` and drop the
-    min-compile-time floor so every warmed executable persists (the
-    default floor silently skips sub-second compiles — a warm pass wants
-    all of them on disk)."""
-    import jax
+def configure_cache(
+    cache_dir: Optional[str], min_compile_s: float = 0.0
+) -> Optional[str]:
+    """Point the XLA persistent cache at ``cache_dir`` (None: the
+    checkout's default) — unless ``JAX_COMPILATION_CACHE_DIR`` is set,
+    which wins (utils/jax_cache) — and drop the min-compile-time floor so
+    every warmed executable persists (the default floor silently skips
+    sub-second compiles — a warm pass wants all of them on disk).
+    Returns the directory in effect."""
+    from ..utils import jax_cache
 
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    try:
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", min_compile_s
-        )
-    except Exception:  # noqa: BLE001 — knob renamed across jax versions
-        pass
+    return jax_cache.configure(cache_dir, min_compile_s)
 
 
 # -- the walk ----------------------------------------------------------------
@@ -337,24 +334,16 @@ def prewarm(
 # -- daemon / drill entry points ---------------------------------------------
 
 
-def default_cache_dir(base_dir: str) -> str:
-    """Per-host cache location: a cache compiled on one CPU generation
-    must not be trusted on another, so the host fingerprint is in the
-    path (coarser than the manifest key — jax version changes invalidate
-    *artifacts* via the key check, not the whole directory)."""
-    return os.path.join(
-        base_dir, f"warm_cache_{wm.envfp.host_fingerprint()}"
-    )
-
-
 def prewarm_for_daemon(cfg, node_name: str) -> Optional[dict]:
     """The boot-time warm pass (node/daemon.py, between ``mark_warming``
     and ``mark_ready``). Never raises — a broken warm config degrades to
     a cold-but-serving node, loudly."""
     try:
+        # per-node report + AOT artifacts sit beside the compile ledger;
+        # the XLA cache is the operator's warm_cache_dir, else
+        # <checkout>/.jax_cache, and JAX_COMPILATION_CACHE_DIR wins
         db_dir = os.path.join(cfg.db_dir, node_name)
-        cache_dir = cfg.warm_cache_dir or default_cache_dir(db_dir)
-        configure_cache(cache_dir)
+        cache_dir = configure_cache(cfg.warm_cache_dir or None)
         surface = wm.load_default_surface()
         knobs = wm.knobs_from_config(cfg)
         schemes = tuple(
@@ -373,8 +362,8 @@ def prewarm_for_daemon(cfg, node_name: str) -> Optional[dict]:
             cache=cache_dir,
         )
         report = prewarm(
-            manifest, cfg.warm_budget_s, report_dir=cache_dir,
-            aot_store=aot.ArtifactStore(os.path.join(cache_dir, "aot")),
+            manifest, cfg.warm_budget_s, report_dir=db_dir,
+            aot_store=aot.ArtifactStore(os.path.join(db_dir, "aot")),
         )
         t = report["totals"]
         log.info(

@@ -113,14 +113,9 @@ def main(argv=None) -> int:
     if 1 not in ks:
         ks.insert(0, 1)  # K=1 is the serial oracle every K compares to
 
-    import jax
+    from mpcium_tpu.utils import jax_cache
 
-    # share the tier-1 persistent compile cache: the proof shapes are
-    # exactly the ones tests/test_pipeline.py compiles
-    jax.config.update(
-        "jax_compilation_cache_dir", os.path.join(_ROOT, ".jax_cache_tests")
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax_cache.configure()
 
     from mpcium_tpu.engine import eddsa_batch as eb
     from mpcium_tpu.perf.envfp import env_fingerprint

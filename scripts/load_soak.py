@@ -23,9 +23,8 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-# protocol math on CPU, mirroring tests/conftest.py: never touch a real
-# accelerator here, and reuse the tests' persistent XLA compile cache so
-# repeat soaks skip the minutes-long kernel compiles
+# protocol math on CPU: this harness rehearses the served path's control
+# flow and accounting; the chip run of the same path is chip_smoke.py
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -36,12 +35,10 @@ if "xla_force_host_platform_device_count" not in _flags:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-if not os.environ.get("MPCIUM_TESTS_NO_CACHE"):
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(__file__), "..", ".jax_cache_tests"),
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+
+from mpcium_tpu.utils import jax_cache  # noqa: E402
+
+jax_cache.configure()
 
 
 def main() -> int:

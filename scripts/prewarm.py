@@ -66,7 +66,8 @@ def main(argv=None) -> int:
                    help="wall-clock budget; remaining entries are skipped")
     p.add_argument("--cache-dir", default="",
                    help="XLA persistent cache dir (default: "
-                        "./warm_cache_<hostfp>)")
+                        "<checkout>/.jax_cache; JAX_COMPILATION_CACHE_DIR "
+                        "wins over both)")
     p.add_argument("--ledger", default="",
                    help="COMPILE_LEDGER.json for traffic priority")
     p.add_argument("--history", default="",
@@ -106,10 +107,7 @@ def main(argv=None) -> int:
     # jax from here on: configure the cache, then walk
     from mpcium_tpu.warm import prewarm as pw
 
-    cache_dir = args.cache_dir or os.path.join(
-        os.getcwd(), f"warm_cache_{wm.envfp.host_fingerprint()}"
-    )
-    pw.configure_cache(cache_dir)
+    cache_dir = pw.configure_cache(args.cache_dir or None)
     report = pw.prewarm(
         manifest, args.budget_s, report_dir=args.out or cache_dir,
         aot_store=None,

@@ -49,12 +49,9 @@ def _setup_cpu_jax() -> None:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    if not os.environ.get("MPCIUM_TESTS_NO_CACHE"):
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.join(_ROOT, ".jax_cache_tests"),
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    from mpcium_tpu.utils import jax_cache
+
+    jax_cache.configure()
 
 
 def regen_sample() -> dict:

@@ -3,7 +3,7 @@
 AEAD-encrypted broker roundtrip."""
 import os
 
-# mirror tests/conftest.py env so the warmed compile cache is reused
+# protocol math on CPU (the chip run of the served path is chip_smoke.py)
 os.environ["JAX_PLATFORMS"] = "cpu"
 flags = os.environ.get("XLA_FLAGS", "")
 if "host_platform_device_count" not in flags:
@@ -12,14 +12,10 @@ if "host_platform_device_count" not in flags:
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-import os as _os
 
-jax.config.update(
-    "jax_compilation_cache_dir",
-    _os.path.join(_os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
-                  ".jax_cache_tests"),
-)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+from mpcium_tpu.utils import jax_cache
+
+jax_cache.configure()
 
 import faulthandler
 import secrets

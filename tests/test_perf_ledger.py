@@ -20,8 +20,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def test_discovers_every_committed_artifact():
     names = {os.path.basename(p) for p in ledger.discover_artifacts(ROOT)}
     expected = (
-        {f"BENCH_r0{i}.json" for i in range(1, 6)}
-        | {f"MULTICHIP_r0{i}.json" for i in range(1, 6)}
+        {f"BENCH_r0{i}.json" for i in range(3, 6)}
+        | {f"MULTICHIP_r0{i}.json" for i in range(2, 6)}
         | {"SOAK_r01.json", "BENCH_TPU_LATEST.json", "BENCH_TPU_OT.json"}
     )
     assert expected <= names
@@ -36,9 +36,17 @@ def test_every_committed_artifact_normalizes():
         assert isinstance(rec["metrics"], dict)
 
 
-def test_dnf_rounds_are_degraded_with_notes():
-    for name, rc in (("BENCH_r02.json", 1), ("BENCH_r04.json", 124)):
-        rec = ledger.normalize(os.path.join(ROOT, name))
+def test_dnf_rounds_are_degraded_with_notes(tmp_path):
+    # a crashed round (rc=1, nothing parsed), written here; the committed
+    # BENCH_r04.json is the watchdog-timeout DNF (rc=124)
+    crashed = tmp_path / "BENCH_r98.json"
+    crashed.write_text(json.dumps({
+        "n": 98, "cmd": "python bench.py", "rc": 1,
+        "tail": "Traceback (most recent call last):\n", "parsed": None,
+    }))
+    for path, rc in ((str(crashed), 1),
+                     (os.path.join(ROOT, "BENCH_r04.json"), 124)):
+        rec = ledger.normalize(path)
         assert rec["degraded"]
         assert rec["context"]["rc"] == rc
         assert any("DNF" in n for n in rec["notes"])
@@ -116,8 +124,13 @@ def test_soak_with_env_stamp_groups_by_platform(tmp_path):
     assert rec["platform"] == doc["env"]["platform"]
 
 
-def test_multichip_ok_vs_failed():
-    r1 = ledger.normalize(os.path.join(ROOT, "MULTICHIP_r01.json"))
+def test_multichip_ok_vs_failed(tmp_path):
+    failed = tmp_path / "MULTICHIP_r99.json"
+    failed.write_text(json.dumps({
+        "n_devices": 8, "rc": 1, "ok": False, "skipped": False,
+        "tail": "Traceback (most recent call last):\n",
+    }))
+    r1 = ledger.normalize(str(failed))
     r2 = ledger.normalize(os.path.join(ROOT, "MULTICHIP_r02.json"))
     assert r1["metrics"]["dryrun_ok"] == 0.0 and r1["degraded"]
     assert r2["metrics"]["dryrun_ok"] == 1.0 and not r2["degraded"]
@@ -125,7 +138,7 @@ def test_multichip_ok_vs_failed():
 
 def test_history_roundtrip_and_determinism(tmp_path):
     records = ledger.build_history(ROOT)
-    assert len(records) >= 13
+    assert len(records) >= 12
     path = str(tmp_path / "hist.jsonl")
     ledger.write_history(records, path)
     assert ledger.load_history(path) == records

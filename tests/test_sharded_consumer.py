@@ -37,9 +37,12 @@ def test_to_dev_actually_shards(armed_mesh):
     # dispatch through a real engine kernel keeps the partitioning
     r, R = eb.nonce_commitments(x)
     assert len(r.sharding.device_set) == len(jax.devices())
-    # odd tails degrade to default placement instead of failing
+    # odd tails degrade to default placement instead of failing — and
+    # are counted, so a full-width run can refuse to have any
+    assert eb.unsharded_placements() == 0
     y = eb.to_dev(np.zeros((N_WALLETS - 1, 64), np.uint8))
     assert len(y.sharding.device_set) == 1
+    assert eb.unsharded_placements() == 1
     # party-leading round tensors shard their SESSION axis (axis=1) —
     # sharding axis 0 would partition the committee instead
     z = eb.to_dev(np.zeros((2, N_WALLETS, 32), np.uint8), axis=1)
