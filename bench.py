@@ -331,20 +331,15 @@ def main() -> None:
     profiled_s = 0.0
     idle_fraction = 0.0
     if platform == "tpu":
-        from mpcium_tpu.perf import profile as perf_profile
         from mpcium_tpu.utils import tracing
 
         _STATE["stage"] = "profiled_run"
         spans: list = []
-        profile_logdir = perf_profile.default_logdir(_HERE)
         tracing.enable(sink=spans.append)
         try:
-            # MPCIUM_PROFILE=1 additionally captures the jax device
-            # timeline for this run; no-op context otherwise
-            with perf_profile.device_profile(profile_logdir) as profiling:
-                t0 = time.perf_counter()
-                out = signer.sign(digests)
-                profiled_s = time.perf_counter() - t0
+            t0 = time.perf_counter()
+            out = signer.sign(digests)
+            profiled_s = time.perf_counter() - t0
         finally:
             tracing.disable()
         assert out["ok"].all()
@@ -354,10 +349,6 @@ def main() -> None:
         # kept out of phase_s so the 2-decimal rounding there cannot
         # flatten a small idle share to 0.00
         idle_fraction = tracing.device_idle_fraction(spans)
-        if profiling:
-            # fold per-phase device-op seconds from the captured profile
-            # into the phase table (keys <phase>_device_op_s)
-            phases.update(perf_profile.fold_device_ops(spans, profile_logdir))
 
     # timed runs (no internal sync)
     _STATE["stage"] = "timed_run"

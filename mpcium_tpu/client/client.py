@@ -11,12 +11,13 @@ them to the cluster; consumes result queues for callbacks (client.go:28-37):
 from __future__ import annotations
 
 import json
+import time
 from typing import Callable, Optional
 
 from .. import wire
 from ..identity.identity import InitiatorKey
 from ..transport.api import Transport
-from ..utils import log
+from ..utils import log, tracing
 
 
 def _result_topic(base: str, scope_id: Optional[str]) -> str:
@@ -41,12 +42,16 @@ class MPCClient:
         log.info("wallet creation requested", wallet=wallet_id)
 
     def sign_transaction(self, msg: wire.SignTxMessage) -> None:
-        msg.signature = self.initiator.sign(msg.raw())
-        self.transport.queues.enqueue(
-            wire.TOPIC_SIGNING_REQUEST,
-            wire.canonical_json(msg.to_json()),
-            idempotency_key=msg.tx_id,
-        )
+        with tracing.span("client:submit", node="client", tid="sign",
+                          tx=msg.tx_id) as sp:
+            t0 = time.perf_counter()
+            msg.signature = self.initiator.sign(msg.raw())
+            sp.set(sign_s=time.perf_counter() - t0)
+            self.transport.queues.enqueue(
+                wire.TOPIC_SIGNING_REQUEST,
+                wire.canonical_json(msg.to_json()),
+                idempotency_key=msg.tx_id,
+            )
         log.info("signing requested", wallet=msg.wallet_id, tx=msg.tx_id)
 
     def resharing(self, wallet_id: str, new_threshold: int, key_type: str,
@@ -90,11 +95,15 @@ class MPCClient:
         land on per-tx topics (TOPIC_SIGNING_RESULT.{tx_id}); passing
         ``tx_id`` scopes the work-queue subscription so concurrent
         clients can't round-robin-steal each other's results."""
+        def deliver(raw: bytes) -> None:
+            with tracing.span("client:result", node="client",
+                              tid="results") as sp:
+                ev = wire.SigningResultEvent.from_json(json.loads(raw))
+                sp.set(tx=ev.tx_id)
+                handler(ev)
+
         return self.transport.queues.dequeue(
-            _result_topic(wire.TOPIC_SIGNING_RESULT, tx_id),
-            lambda raw: handler(
-                wire.SigningResultEvent.from_json(json.loads(raw))
-            ),
+            _result_topic(wire.TOPIC_SIGNING_RESULT, tx_id), deliver
         )
 
     def on_resharing_result(

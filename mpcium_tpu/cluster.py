@@ -25,6 +25,7 @@ from .registry.registry import PeerRegistry
 from .store.keyinfo import KeyinfoStore
 from .store.kvstore import EncryptedFileKV, MemoryKV
 from .trace import arm as _trace_arm
+from .trace import recorder as _trace_recorder
 from .trace import snapshot_chrome as _trace_snapshot_chrome
 from .transport.loopback import LoopbackFabric
 from .utils import log
@@ -268,7 +269,8 @@ class LocalCluster(SyncOps):
         ec.run()
         self.consumers.append(ec)
         self.node_consumers[nid] = ec
-        sc = SigningConsumer(transport, reply_timeout_s=self._reply_timeout_s)
+        sc = SigningConsumer(transport, reply_timeout_s=self._reply_timeout_s,
+                             metrics=ec.metrics)
         sc.run()
         self.signing_consumers.append(sc)
         TimeoutConsumer(transport).run()
@@ -298,7 +300,17 @@ class LocalCluster(SyncOps):
 
     def metrics_snapshot(self) -> Dict[str, dict]:
         """Just the metric registries, keyed by node id (the soak harness
-        and smoke tests consume this)."""
+        and smoke tests consume this). ``trace.dropped_spans`` is brought
+        up to date first: each node's own ring, and on the first node also
+        the rings no node owns (``engine``, ``client``, ``local``), so the
+        sum over the snapshot counts every ring once."""
+        dropped = _trace_recorder.dropped_totals()
+        shared = sum(d for ring, d in dropped.items()
+                     if ring not in self.node_consumers)
+        for nid, ec in self.node_consumers.items():
+            ec.metrics.gauge("trace.dropped_spans").set(
+                float(dropped.get(nid, 0) + shared))
+            shared = 0
         return {
             nid: ec.metrics.snapshot()
             for nid, ec in self.node_consumers.items()

@@ -68,7 +68,7 @@ from ..node.node import Node, NotEnoughParticipants
 from ..node.session import Session
 from ..protocol.base import KeygenShare, ProtocolError
 from ..protocol.eddsa.batch_signing import BatchedEDDSASigningParty
-from ..transport.api import Transport
+from ..transport.api import Transport, observe_delivery_wait
 from ..utils import log, tracing
 from ..utils.annotations import locked_by
 from ..utils.metrics import MetricsRegistry
@@ -398,6 +398,11 @@ class BatchSigningScheduler:
         self._m_repacked = m.counter("scheduler.repacked_total")
         self._m_e2e = m.histogram("scheduler.e2e_latency_s")
         self._m_decl_evict = m.counter("scheduler.declines_evicted_total")
+        self._m_admit = m.histogram("batch.manifest_admit_s")
+        self._m_prepare = m.histogram("batch.prepare_s")
+        self._m_share_load = m.histogram("batch.share_load_s")
+        self._m_egress = m.histogram("egress.result_s")
+        self._m_pubsub_wait = m.histogram("transport.pubsub_wait_s")
         self._sub = transport.pubsub.subscribe(
             wire.TOPIC_BATCH_MANIFEST, self._on_manifest_raw
         )
@@ -516,9 +521,7 @@ class BatchSigningScheduler:
         ``deadline_ms`` 0 on the wire means "server default"; keygen
         commands carry no SLO fields and always take the defaults."""
         deadline_ms = getattr(msg, "deadline_ms", 0) or self.default_deadline_ms
-        lane = getattr(msg, "priority", wire.PRIORITY_BULK)
-        if lane not in wire.PRIORITIES:
-            lane = wire.PRIORITY_BULK
+        lane = wire.lane_of(msg)
         deadline_at = (
             time.monotonic() + deadline_ms / 1000.0
             if deadline_ms > 0
@@ -616,13 +619,16 @@ class BatchSigningScheduler:
             )
             if entry.deadline_at != float("inf"):
                 self._arm_deadline_locked(key, entry.deadline_at)
-        tracing.instant(
-            "intake", node=self.node.node_id, tid=f"lane:{entry.lane}",
-            req_kind=entry.kind, deadline_ms=(
-                0 if entry.deadline_at == float("inf")
-                else int((entry.deadline_at - entry.added_at) * 1000)
-            ),
-        )
+        if entry.kind != "sign":
+            # a sign request's intake is a span of the consumer's whole
+            # handling (EventConsumer._on_sign), not this marker
+            tracing.instant(
+                "intake", node=self.node.node_id, tid=f"lane:{entry.lane}",
+                req_kind=entry.kind, deadline_ms=(
+                    0 if entry.deadline_at == float("inf")
+                    else int((entry.deadline_at - entry.added_at) * 1000)
+                ),
+            )
         if fire_after:
             # continuous batching: drain every full chunk ready right now
             # (the remainder waits for the window or the next submit)
@@ -698,6 +704,7 @@ class BatchSigningScheduler:
             "queue", int(e.added_at * 1e9), tracing.now_ns(),
             node=self.node.node_id, tid=f"lane:{e.lane}",
             req_kind=e.kind, outcome="shed", backpressure=backpressure,
+            tx=getattr(e.msg, "tx_id", ""),
         )
         tracing.incident(
             "shed", node=self.node.node_id, tid=f"lane:{e.lane}",
@@ -1016,12 +1023,14 @@ class BatchSigningScheduler:
             )
             # the dispatch decision + each entry's queued lifetime, on the
             # lane track, linked to the downstream batch session by id
+            tracing.clock_anchor()
             t_disp = tracing.now_ns()
             for e in entries:
                 tracing.emit(
                     "queue", int(e.added_at * 1e9), t_disp,
                     node=self.node.node_id, tid=f"lane:{e.lane}",
                     req_kind=kind, outcome="dispatched", batch=batch_id,
+                    tx=getattr(e.msg, "tx_id", ""),
                 )
             tracing.emit(
                 "dispatch", t_fire0, t_disp,
@@ -1116,7 +1125,39 @@ class BatchSigningScheduler:
 
     # -- all quorum members: manifest execution ------------------------------
 
+    def _batch_stage(self, name: str, histogram, batch_id: str, t0_ns: int,
+                     **attrs) -> None:
+        """A batch-level host stage of ``bsign:<batch_id>`` ends now: its
+        seconds since ``t0_ns`` go to ``histogram``, and the interval is
+        the span ``name`` on the track and under the trace id the
+        session's spans have."""
+        t1_ns = tracing.now_ns()
+        histogram.observe((t1_ns - t0_ns) / 1e9)
+        sid = f"bsign:{batch_id}"
+        tracing.emit(
+            name, t0_ns, t1_ns, node=self.node.node_id, tid=sid,
+            trace_id=tracing.trace_id_for(sid), batch=batch_id, **attrs,
+        )
+
     def _on_manifest_raw(self, raw: bytes) -> None:
+        """A sign manifest's admission is the ``host:manifest_admit``
+        span (and ``batch.manifest_admit_s``): raw bytes in → its batch
+        thread started, or refused with the ``outcome`` that says why."""
+        observe_delivery_wait(self._m_pubsub_wait)
+        t0_ns = tracing.now_ns()
+        seen: dict = {"outcome": "bad_manifest", "verify_s": 0.0}
+        try:
+            self._admit_manifest(raw, seen)
+        finally:
+            if seen.get("kind") == "sign":
+                self._batch_stage(
+                    "host:manifest_admit", self._m_admit, seen["batch_id"],
+                    t0_ns, n=seen["n"], outcome=seen["outcome"],
+                    parse_s=seen["parse_s"], verify_s=seen["verify_s"],
+                )
+
+    def _admit_manifest(self, raw: bytes, seen: dict) -> None:
+        t0 = time.perf_counter()
         try:
             man = json.loads(raw)
             batch_id = man["batch_id"]
@@ -1138,6 +1179,8 @@ class BatchSigningScheduler:
             return
         if not reqs:
             return
+        seen.update(kind=kind, batch_id=batch_id, n=len(reqs),
+                    parse_s=time.perf_counter() - t0)
         # the cohort count is leader-advertised but engine-clamped: an
         # off-grid K degrades to the serial oracle, it cannot force a
         # foreign compile shape (resolve_cohorts re-validates against B)
@@ -1149,7 +1192,11 @@ class BatchSigningScheduler:
         body = _manifest_body(
             batch_id, leader, requests, kind, int(man.get("cohorts", 1))
         )
-        if not self.node.identity.verify_peer(leader, body, sig):
+        t0 = time.perf_counter()
+        verified = self.node.identity.verify_peer(leader, body, sig)
+        seen["verify_s"] = time.perf_counter() - t0
+        if not verified:
+            seen["outcome"] = "bad_leader_signature"
             log.warn("batch manifest with BAD leader signature dropped",
                      batch=batch_id)
             return
@@ -1164,6 +1211,7 @@ class BatchSigningScheduler:
         # content checks below carry the trust, rank only picks the sender
         info = self.node.keyinfo.get(reqs[0][0].key_type, reqs[0][0].wallet_id)
         if info is None or leader not in info.participant_peer_ids:
+            seen["outcome"] = "non_member"
             log.warn("batch manifest from non-member dropped",
                      batch=batch_id, claimed=leader)
             return
@@ -1174,6 +1222,7 @@ class BatchSigningScheduler:
         # material homogeneity is enforced by the party constructor in
         # _run_batch (requires share loads; a mixed batch fails retryably).
         kt = reqs[0][0].key_type
+        seen["outcome"] = "mixed_batch"
         if kt not in (wire.KEY_TYPE_ED25519, wire.KEY_TYPE_SECP256K1):
             log.warn("unsupported curve in manifest dropped", batch=batch_id)
             return
@@ -1189,11 +1238,17 @@ class BatchSigningScheduler:
                 return
         # the leader is untrusted for content: re-verify every initiator
         # signature
-        for msg, _reply in reqs:
-            if not self.node.identity.verify_initiator(msg.raw(), msg.signature):
-                log.warn("batch manifest with BAD initiator signature dropped",
-                         batch=batch_id)
-                return
+        t0 = time.perf_counter()
+        verified = all(
+            self.node.identity.verify_initiator(msg.raw(), msg.signature)
+            for msg, _reply in reqs
+        )
+        seen["verify_s"] += time.perf_counter() - t0
+        if not verified:
+            seen["outcome"] = "bad_initiator_signature"
+            log.warn("batch manifest with BAD initiator signature dropped",
+                     batch=batch_id)
+            return
         # drop covered entries from local buffers BEFORE any early return,
         # so follower fallback timers cannot race a manifest we act on.
         # Entries pulled from our buckets carry a dedup claim acquired by
@@ -1208,6 +1263,7 @@ class BatchSigningScheduler:
             kwargs={"inherited": inherited},
             name=f"bsign-{batch_id}", daemon=True,
         ).start()
+        seen["outcome"] = "admitted"
 
     @staticmethod
     def _dedup_str(kind: str, ek: Tuple[str, str]) -> str:
@@ -1739,6 +1795,7 @@ class BatchSigningScheduler:
         inherited: List[Tuple[str, str]] = (),
     ) -> None:
         node = self.node
+        t_prep0 = tracing.now_ns()
         first = reqs[0][0]
         info = node.keyinfo.get(first.key_type, first.wallet_id)
         if info is None:
@@ -1779,14 +1836,20 @@ class BatchSigningScheduler:
         shares: List[KeygenShare] = []
         messages: List[bytes] = []
         kt = first.key_type
+        load_s = 0.0
         try:
             for msg, _r in reqs:
+                t0 = time.perf_counter()
                 share = node.load_share(msg.key_type, msg.wallet_id)
+                dt = time.perf_counter() - t0
+                self._m_share_load.observe(dt)
+                load_s += dt
                 winfo = node.keyinfo.get(msg.key_type, msg.wallet_id)
                 if winfo is None or share.epoch != winfo.epoch:
                     raise NotEnoughParticipants("epoch fence (mid-reshare)")
                 shares.append(share)
                 messages.append(msg.tx)
+            t_party0 = time.perf_counter()
             if kt == wire.KEY_TYPE_SECP256K1:
                 from ..engine.gg18_batch import Domains
                 from ..protocol.ecdsa.batch_signing import (
@@ -1803,6 +1866,7 @@ class BatchSigningScheduler:
                     f"bsign:{batch_id}", node.node_id, quorum, shares,
                     messages, cohorts=cohorts,
                 )
+            party_s = time.perf_counter() - t_party0
         except (ProtocolError, NotEnoughParticipants) as e:
             log.warn("batch not signable here — waiting for redelivery",
                      batch=batch_id, reason=str(e), node=node.node_id)
@@ -1810,6 +1874,8 @@ class BatchSigningScheduler:
             return
 
         def on_done(result):
+            t_egress0 = tracing.now_ns()
+            enqueue_s = 0.0
             ok = result["ok"]
             for i, (msg, reply) in enumerate(reqs):
                 if bool(ok[i]) and kt == wire.KEY_TYPE_SECP256K1:
@@ -1840,11 +1906,13 @@ class BatchSigningScheduler:
                         network_internal_code=msg.network_internal_code,
                         error_reason="batched signature failed verification",
                     )
+                raw = wire.canonical_json(ev.to_json())
+                t0 = time.perf_counter()
                 self.transport.queues.enqueue(
-                    f"{wire.TOPIC_SIGNING_RESULT}.{msg.tx_id}",
-                    wire.canonical_json(ev.to_json()),
+                    f"{wire.TOPIC_SIGNING_RESULT}.{msg.tx_id}", raw,
                     idempotency_key=msg.tx_id,
                 )
+                enqueue_s += time.perf_counter() - t0
                 if reply:
                     self.transport.pubsub.publish(
                         reply, b"OK" if bool(ok[i]) else b"ERR"
@@ -1852,6 +1920,8 @@ class BatchSigningScheduler:
                 if (msg.wallet_id, msg.tx_id) in owned_set:
                     self.on_tx_done(msg.wallet_id, msg.tx_id)
                 self._observe_e2e("sign", (msg.wallet_id, msg.tx_id))
+            self._batch_stage("host:result_egress", self._m_egress, batch_id,
+                              t_egress0, n=len(reqs), enqueue_s=enqueue_s)
             log.info("batch signed", batch=batch_id, size=len(reqs),
                      node=node.node_id)
             _prune()
@@ -1899,6 +1969,7 @@ class BatchSigningScheduler:
             on_error=on_error,
             hello_timeout_s=self.batch_patience_s,
             send_patience_s=self.batch_patience_s,
+            metrics=self.metrics,
         )
         with self._lock:
             if self._closed:
@@ -1912,3 +1983,5 @@ class BatchSigningScheduler:
             }
             self.batches_run += 1
         session.listen()
+        self._batch_stage("host:batch_prepare", self._m_prepare, batch_id,
+                          t_prep0, n=len(reqs), load_s=load_s, party_s=party_s)

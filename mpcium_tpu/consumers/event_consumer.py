@@ -27,8 +27,8 @@ from typing import Dict, Optional
 from .. import wire
 from ..node.node import Node, NotEnoughParticipants
 from ..node.session import RetryableSessionError
-from ..transport.api import Transport
-from ..utils import log
+from ..transport.api import Transport, observe_delivery_wait
+from ..utils import log, tracing
 
 SESSION_TIMEOUT_S = 30 * 60  # event_consumer.go:71
 GC_INTERVAL_S = 5 * 60  # event_consumer.go:72
@@ -59,6 +59,9 @@ class EventConsumer:
         self._subs = []
         self._gc_stop = threading.Event()
         self._gc_thread: Optional[threading.Thread] = None
+        self._m_intake = self.metrics.histogram("intake.handle_s")
+        self._m_verify = self.metrics.histogram("intake.verify_initiator_s")
+        self._m_pubsub_wait = self.metrics.histogram("transport.pubsub_wait_s")
         self.scheduler = None
         if batch_signing:
             from .batch_scheduler import BatchSigningScheduler
@@ -125,7 +128,7 @@ class EventConsumer:
         from ..trace import recorder
 
         self.metrics.gauge("trace.dropped_spans").set(
-            float(recorder.recorder_for(self.node.node_id).dropped)
+            float(recorder.recorder_for(self.node.node_id).dropped_total)
         )
         if self.scheduler is not None:
             self.metrics.gauge("scheduler.settled_size").set(
@@ -479,7 +482,31 @@ class EventConsumer:
 
     def _on_sign(self, raw: bytes) -> None:
         """Handles mpc:sign — wrapped by publish_with_reply, so the payload
-        carries the reply inbox."""
+        carries the reply inbox. The whole handling is the request's
+        ``intake`` span on its lane (and ``intake.handle_s``)."""
+        observe_delivery_wait(self._m_pubsub_wait)
+        t0_ns = tracing.now_ns()
+        seen: dict = {"outcome": "bad_event", "verify_s": 0.0}
+        try:
+            self._take_in_sign(raw, seen)
+        finally:
+            t1_ns = tracing.now_ns()
+            self._m_intake.observe((t1_ns - t0_ns) / 1e9)
+            msg = seen.get("msg")
+            if msg is not None:
+                sched = self.scheduler
+                tracing.emit(
+                    "intake", t0_ns, t1_ns, node=self.node.node_id,
+                    tid=f"lane:{wire.lane_of(msg)}", tx=msg.tx_id,
+                    req_kind="sign", outcome=seen["outcome"],
+                    deadline_ms=msg.deadline_ms or (
+                        sched.default_deadline_ms if sched else 0),
+                    verify_s=seen["verify_s"],
+                )
+
+    def _take_in_sign(self, raw: bytes, seen: dict) -> None:
+        """``seen`` reports to the ``intake`` span: the message once it
+        parsed, the initiator verification's seconds, the outcome."""
         try:
             outer = json.loads(raw)
             reply_topic = outer.get("reply", "")
@@ -494,12 +521,19 @@ class EventConsumer:
             except Exception as e:  # noqa: BLE001
                 log.warn("bad sign event", error=repr(e))
                 return
-        if not self.node.identity.verify_initiator(msg.raw(), msg.signature):
+        seen["msg"] = msg
+        t0 = time.perf_counter()
+        verified = self.node.identity.verify_initiator(msg.raw(), msg.signature)
+        seen["verify_s"] = time.perf_counter() - t0
+        self._m_verify.observe(seen["verify_s"])
+        if not verified:
+            seen["outcome"] = "bad_signature"
             log.warn("sign event with BAD initiator signature dropped",
                      wallet=msg.wallet_id, tx=msg.tx_id)
             return
         dedup = f"{msg.wallet_id}-{msg.tx_id}"
         if not self._claim(dedup, meta=("sign", msg)):
+            seen["outcome"] = "duplicate"
             log.info("duplicate signing session ignored", key=dedup)
             # Answer the (fresh) reply inbox anyway: a batched dispatch
             # can legitimately outlive the durable bridge's reply window
@@ -518,7 +552,9 @@ class EventConsumer:
         if self.scheduler is not None and self.scheduler.submit(
             msg, reply_topic
         ):
+            seen["outcome"] = "batched"
             return
+        seen["outcome"] = "single"
         self._start_single(msg, reply_topic, dedup)
 
     def _batch_fallback(self, msg, reply_topic) -> None:

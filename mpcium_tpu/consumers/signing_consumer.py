@@ -14,20 +14,33 @@ queues, never the inbox."""
 from __future__ import annotations
 
 import threading
+import time
 import uuid
 
 from .. import wire
-from ..transport.api import Transport
+from ..transport.api import Transport, observe_delivery_wait
 from ..utils import log
 
 REPLY_TIMEOUT_S = 30.0  # sign_consumer.go:16-20
 
 
 class SigningConsumer:
-    def __init__(self, transport: Transport, reply_timeout_s: float = REPLY_TIMEOUT_S):
+    def __init__(self, transport: Transport,
+                 reply_timeout_s: float = REPLY_TIMEOUT_S, metrics=None):
+        from ..utils.metrics import MetricsRegistry
+
         self.transport = transport
         self.reply_timeout_s = reply_timeout_s
+        self.metrics = metrics if metrics is not None else MetricsRegistry()
         self._sub = None
+        self._lock = threading.Lock()
+        self._closed = False
+        self._waiting: set = set()  # reply events of the requests in flight
+        m = self.metrics
+        self._m_inflight = m.gauge("bridge.inflight")
+        self._m_peak = m.gauge("bridge.inflight_peak")
+        self._m_reply_wait = m.histogram("bridge.reply_wait_s")
+        self._m_queue_wait = m.histogram("transport.queue_wait_s")
 
     def run(self) -> None:
         self._sub = self.transport.queues.dequeue(
@@ -35,8 +48,16 @@ class SigningConsumer:
         )
 
     def close(self) -> None:
+        """Stop taking requests and end the reply waits in flight: each
+        leaves un-acked (the durable queue redelivers), so no queue worker
+        outlives its bridge by a whole reply window."""
         if self._sub:
             self._sub.unsubscribe()
+        with self._lock:
+            self._closed = True
+            waiting = list(self._waiting)
+        for got_reply in waiting:
+            got_reply.set()
 
     def _handle(self, data: bytes) -> None:
         """One delivery: publish on mpc:sign with a fresh inbox, wait one
@@ -48,20 +69,35 @@ class SigningConsumer:
         gone from the queue and the client learns via its own timeout
         rather than an explicit event — the bound is the client timeout,
         same as the reference's initiator-side budget."""
+        observe_delivery_wait(self._m_queue_wait)
         reply_topic = f"_inbox.{uuid.uuid4().hex}"
         got_reply = threading.Event()
+        with self._lock:
+            if self._closed:
+                raise TimeoutError("signing bridge closed")  # nak
+            self._waiting.add(got_reply)
+            self._m_inflight.set(len(self._waiting))
+            self._m_peak.set(max(self._m_peak.value, len(self._waiting)))
         sub = self.transport.pubsub.subscribe(
             reply_topic, lambda _d: got_reply.set()
         )
         try:
+            t0 = time.monotonic()
             self.transport.pubsub.publish_with_reply(
                 wire.TOPIC_SIGN, reply_topic, data
             )
-            if not got_reply.wait(self.reply_timeout_s):
+            replied = got_reply.wait(self.reply_timeout_s)
+            if self._closed:
+                raise TimeoutError("signing bridge closed")  # nak
+            if not replied:
                 log.warn("signing request timed out waiting for reply")
                 raise TimeoutError("no signing reply")  # nak ⇒ redelivery
+            self._m_reply_wait.observe(time.monotonic() - t0)
         finally:
             sub.unsubscribe()
+            with self._lock:
+                self._waiting.discard(got_reply)
+                self._m_inflight.set(len(self._waiting))
 
 
 class TimeoutConsumer:

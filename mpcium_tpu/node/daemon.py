@@ -206,7 +206,7 @@ def run_node(
             consumer.resume_incomplete()
         except Exception as e:  # noqa: BLE001 — recovery must never block boot
             log.warn("WAL resume scan failed", node=name, error=repr(e))
-    signing = SigningConsumer(transport)
+    signing = SigningConsumer(transport, metrics=consumer.metrics)
     signing.run()
     # health surface: periodically publish the consumer's operational
     # snapshot (live sessions, dedup claims, scheduler lane depths, shed

@@ -22,7 +22,7 @@ from typing import Callable, List, Optional, Sequence
 from ..identity.identity import IdentityStore
 from ..protocol.base import PartyBase, ProtocolError, RoundMsg
 from ..store.session_wal import SessionWALWriter
-from ..transport.api import Transport, TransportError
+from ..transport.api import Transport, TransportError, observe_delivery_wait
 from ..utils import log, tracing
 from ..utils.annotations import locked_by
 from ..wire import Envelope
@@ -102,6 +102,7 @@ class Session:
         resume_fresh: bool = False,
         resume_sent: Optional[Sequence[dict]] = None,
         resume_envelopes: Optional[Sequence[bytes]] = None,
+        metrics=None,
     ):
         self.session_id = session_id
         self.party = party
@@ -142,6 +143,13 @@ class Session:
         # coordination; wire context only refines parent/child edges
         self._trace_id = tracing.trace_id_for(session_id)
         self._trace_t0 = tracing.now_ns()
+        self._listen_ns = 0
+        # the owner's registry, where it has one (batch sessions): how
+        # long inbound deliveries waited for a transport worker
+        self._m_pubsub_wait = (
+            metrics.histogram("transport.pubsub_wait_s")
+            if metrics is not None else None
+        )
         self._done_evt = threading.Event()
         # one-shot claim for _finish, distinct from _done_evt: close() sets
         # the event for waiters, which must not make a racing _finish skip
@@ -169,6 +177,9 @@ class Session:
     def listen(self) -> None:
         """Subscribe broadcast + own direct topic, then announce readiness
         (replaces ListenToIncomingMessageAsync + sleep barrier)."""
+        # before the first subscription: a peer's hello can complete the
+        # quorum (and read this) on a transport worker at once
+        self._listen_ns = tracing.now_ns()
         self._subs.append(
             self.transport.pubsub.subscribe(self.broadcast_topic, self._on_raw)
         )
@@ -431,6 +442,8 @@ class Session:
     # -- inbound ------------------------------------------------------------
 
     def _on_raw(self, raw: bytes) -> None:
+        t_in = tracing.now_ns()
+        observe_delivery_wait(self._m_pubsub_wait)
         try:
             env = Envelope.decode(raw)
         except Exception as e:  # noqa: BLE001
@@ -489,13 +502,23 @@ class Session:
             payload=env.payload,
             to=env.to,
         )
-        parent = env.trace.get("s") if env.trace else None
         with self._lock:
             self.last_activity = time.monotonic()
-            if not self._started:
+            started = self._started
+            if not started:
                 self._buffer.append(msg)
-                return
-        self._deliver(msg, parent=parent)
+        # decode, verify and journal are the envelope's own host stage:
+        # the round span it causes hangs under it, and it under the
+        # sender's round span where the envelope carries one
+        parent = env.trace.get("s") if env.trace else None
+        stage = tracing.emit(
+            "host:envelope_in", t_in, tracing.now_ns(),
+            trace_id=self._trace_id, parent_id=parent,
+            node=self.node_id, tid=self.session_id,
+            round=env.round, sender=env.from_id, buffered=not started,
+        )
+        if started:
+            self._deliver(msg, parent=stage or parent)
 
     def _on_hello(self, from_id: str) -> None:
         start_now = False
@@ -526,6 +549,11 @@ class Session:
             # buffers while _started is False, so receive() cannot run
             # before start() has, and start() runs exactly once
             # (_start_claimed is a one-shot)
+            tracing.emit(
+                "wait:hello", self._listen_ns, tracing.now_ns(),
+                trace_id=self._trace_id, node=self.node_id,
+                tid=self.session_id,
+            )
             with tracing.span(
                 "round:start", trace_id=self._trace_id,
                 node=self.node_id, tid=self.session_id,

@@ -1,6 +1,6 @@
 """mpcperf: the performance observatory (PERFORMANCE.md "perf observatory").
 
-Four coupled parts, each importable on its own so nothing here rides the
+Three coupled parts, each importable on its own so nothing here rides the
 hot path unless asked:
 
 - ``compile_watch``: the compile-wall ledger. Engines report every
@@ -19,9 +19,6 @@ hot path unless asked:
   Fast CPU-safe micro-benches compared against committed baselines with
   a Mann-Whitney + bootstrap noise band (``scripts/perfcheck.py``,
   ``make perfcheck``, wired into ``make check`` and tier-1).
-- ``profile``: optional deep profiling (``MPCIUM_PROFILE=1``) capturing
-  ``jax.profiler`` device timelines and folding device-op time into the
-  PhaseTimer span tables.
 
 ``envfp`` stamps bench/soak records with the environment fingerprint
 (git sha, jax version, device kind/count, MPCIUM_* knobs) the ledger

@@ -28,8 +28,7 @@ _REPO = os.path.dirname(os.path.dirname(_HERE))
 # (paths, passwords) is noise the fingerprint must not leak
 _KNOB_PREFIXES = (
     "MPCIUM_MTA", "MPCIUM_OT_CHUNKS", "MPCIUM_NATIVE_THREADS",
-    "MPCIUM_BENCH_B", "MPCIUM_BENCH_RUNS", "MPCIUM_PROFILE",
-    "JAX_PLATFORMS",
+    "MPCIUM_BENCH_B", "MPCIUM_BENCH_RUNS", "JAX_PLATFORMS",
 )
 
 

@@ -7,6 +7,7 @@ See OBSERVABILITY.md for the span model and how-to.
 """
 from __future__ import annotations
 
+import sys
 from typing import Dict, List, Optional
 
 from ..utils import tracing
@@ -33,6 +34,22 @@ def arm(
     recorder.set_dump_dir(dump_dir)
     tracing.enable(sink=recorder.record)
     tracing.set_incident_hook(recorder.dump_incident)
+    tracing.set_clock_anchor_hook(_profiler_clock_anchor)
+
+
+CLOCK_ANCHOR_PREFIX = "mpctrace_clock:"
+
+
+def _profiler_clock_anchor(t_ns: int) -> None:
+    """``mpctrace_clock:<monotonic_ns>`` in the profiler's own trace: the
+    event's start on the profiler's clock, less the reading in its name,
+    is the offset between the two. Costs nothing when no capture runs,
+    and a process that never loaded jax has none running."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return
+    with jax.profiler.TraceAnnotation(f"{CLOCK_ANCHOR_PREFIX}{t_ns}"):
+        pass
 
 
 def disarm() -> None:

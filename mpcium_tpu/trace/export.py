@@ -6,7 +6,10 @@ Mapping: pid = node (one process row per node), tid = the span's track
 events so Perfetto shows ``node0`` / ``lane:interactive`` instead of
 bare integers. Timestamps are microseconds relative to the earliest
 span in the document (monotonic clocks share a timebase in-process, so
-cross-node alignment is exact for LocalCluster traces).
+cross-node alignment is exact for LocalCluster traces); that span's
+``monotonic_ns`` is kept as ``otherData.monotonic_base_ns``, so the
+document can be laid over a profiler capture that holds the program's
+``mpctrace_clock:<monotonic_ns>`` annotations.
 """
 from __future__ import annotations
 
@@ -75,6 +78,7 @@ def chrome_trace(
 
     other = {
         "format": TRACE_FORMAT,
+        "monotonic_base_ns": t_base,
         "dropped_spans": {
             node: d for node, (_s, d) in sorted(per_node.items())
         },

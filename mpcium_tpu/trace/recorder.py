@@ -26,7 +26,10 @@ _DUMP_LIMIT = 8
 
 
 class FlightRecorder:
-    """Bounded ring buffer of span dicts with an exact dropped count."""
+    """Bounded ring buffer of span dicts with an exact dropped count:
+    ``dropped`` since the last clearing snapshot (what a snapshot's
+    document is missing), ``dropped_total`` since the ring was made
+    (monotone: what the ``trace.dropped_spans`` gauge reports)."""
 
     def __init__(self, node_id: str, capacity: int = DEFAULT_CAPACITY) -> None:
         self.node_id = node_id
@@ -34,11 +37,13 @@ class FlightRecorder:
         self._lock = threading.Lock()
         self._spans: Deque[dict] = deque(maxlen=capacity)
         self.dropped = 0
+        self.dropped_total = 0
 
     def record(self, span: dict) -> None:
         with self._lock:
             if len(self._spans) == self.capacity:
                 self.dropped += 1
+                self.dropped_total += 1
             self._spans.append(span)
 
     def snapshot(self, clear: bool = False) -> Tuple[List[dict], int]:
@@ -97,6 +102,14 @@ def snapshot_all(
             if node_ids is None or nid in node_ids
         ]
     return {nid: rec.snapshot(clear=clear) for nid, rec in items}
+
+
+def dropped_totals() -> Dict[str, int]:
+    """Every ring's monotone dropped count, the shared tracks
+    (``engine``, ``client``, ``local``) included."""
+    with _lock:
+        recs = list(_recorders.items())
+    return {nid: rec.dropped_total for nid, rec in recs}
 
 
 def set_dump_dir(path: Optional[str]) -> None:

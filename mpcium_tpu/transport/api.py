@@ -13,6 +13,8 @@ All handlers receive raw ``bytes``.
 from __future__ import annotations
 
 import abc
+import threading
+import time
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
@@ -20,6 +22,38 @@ Handler = Callable[[bytes], None]
 # queue handler returns: None/ACK_OK → ack; raising → retry (nak);
 # raising Permanent → terminate (no redelivery)
 QueueHandler = Callable[[bytes], Optional[str]]
+
+
+# How long the delivery now being handled on this thread waited for its
+# worker. A fabric owns no metrics registry (the in-process one is shared
+# by every node): its worker stamps the wait before it calls the handler,
+# and the handler's owner observes it into its node's registry
+# (``transport.queue_wait_s`` / ``transport.pubsub_wait_s``).
+_delivery = threading.local()
+
+
+def stamped(fn: Callable, *args) -> Callable[[], None]:
+    """``fn(*args)`` as a pool job that stamps, when a worker picks it
+    up, how long it waited since this call."""
+    posted = time.monotonic()
+
+    def job() -> None:
+        _delivery.wait_s = time.monotonic() - posted
+        try:
+            fn(*args)
+        finally:
+            _delivery.wait_s = None
+
+    return job
+
+
+def observe_delivery_wait(histogram) -> None:
+    """Observe the wait of the delivery this thread is handling into the
+    handler's ``histogram``; nothing when the caller is not running inside
+    a fabric worker's delivery (or keeps no such histogram)."""
+    waited = getattr(_delivery, "wait_s", None)
+    if waited is not None and histogram is not None:
+        histogram.observe(waited)
 
 
 class Permanent(Exception):

@@ -38,6 +38,7 @@ from .api import (
     Subscription,
     Transport,
     TransportError,
+    stamped,
 )
 
 
@@ -152,7 +153,7 @@ class LoopbackFabric:
                     if self._inflight == 0:
                         self._idle.notify_all()
 
-        (self._qpool if blocking else self._pool).submit(run)
+        (self._qpool if blocking else self._pool).submit(stamped(run))
 
     # -- pub/sub ------------------------------------------------------------
 

@@ -81,6 +81,7 @@ from .api import (
     Subscription,
     Transport,
     TransportError,
+    stamped,
 )
 from .loopback import topic_matches
 from ..utils import log
@@ -1187,7 +1188,7 @@ class TcpClient:
                     data = json.dumps(
                         {"reply": reply, "data": data.hex()}
                     ).encode()
-                self._pool.submit(self._safe, handler, data)
+                self._pool.submit(stamped(self._safe, handler, data))
         elif op == "dmsg":
             ent = self._handlers.get(f["sid"])
 
@@ -1204,7 +1205,7 @@ class TcpClient:
                 except TransportError:
                     pass
 
-            self._pool.submit(run)
+            self._pool.submit(stamped(run))
         elif op == "dack":
             ent = self._dack_events.get(f["rid"])
             if ent:
@@ -1230,7 +1231,7 @@ class TcpClient:
                 except Exception:  # noqa: BLE001
                     self._send({"op": "qnak", "did": f["did"]})
 
-            self._qpool.submit(runq)
+            self._qpool.submit(stamped(runq))
         elif op == "dead":
             for h in list(self._dead_handlers):
                 self._pool.submit(

@@ -26,6 +26,7 @@ traced protocol run makes exactly the same decisions as an untraced one.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import itertools
 import threading
@@ -38,6 +39,7 @@ now_ns = time.monotonic_ns
 _ENABLED = False
 _sink: Optional[Callable[[dict], None]] = None
 _incident_hook: Optional[Callable[[str, str, dict], None]] = None
+_clock_anchor_hook: Optional[Callable[[int], None]] = None
 
 _ids = itertools.count(1)
 _state = threading.local()  # .stack: List[Span] of open spans in this thread
@@ -63,10 +65,11 @@ def enable(sink: Optional[Callable[[dict], None]] = None) -> None:
 
 
 def disable() -> None:
-    global _ENABLED, _sink, _incident_hook
+    global _ENABLED, _sink, _incident_hook, _clock_anchor_hook
     _ENABLED = False
     _sink = None
     _incident_hook = None
+    _clock_anchor_hook = None
 
 
 def set_incident_hook(hook: Optional[Callable[[str, str, dict], None]]) -> None:
@@ -74,6 +77,24 @@ def set_incident_hook(hook: Optional[Callable[[str, str, dict], None]]) -> None:
     flight recorder uses it to dump buffers on shed/timeout/failure."""
     global _incident_hook
     _incident_hook = hook
+
+
+def set_clock_anchor_hook(hook: Optional[Callable[[int], None]]) -> None:
+    """Install the clock-anchor callback: ``hook(monotonic_ns)``. The
+    flight recorder's ``arm()`` installs one that writes the reading
+    into a running profiler capture, so this module stays jax-free."""
+    global _clock_anchor_hook
+    _clock_anchor_hook = hook
+
+
+def clock_anchor() -> None:
+    """Hand the span clock's current reading to the anchor hook: a
+    profiler capture that holds it can be laid over a span export."""
+    if not _ENABLED:
+        return
+    hook = _clock_anchor_hook
+    if hook is not None:
+        hook(now_ns())
 
 
 def declassify_attr(name: str, reason: str) -> None:
@@ -88,7 +109,10 @@ def declassified_attrs() -> Dict[str, str]:
     return dict(_DECLASSIFIED_ATTRS)
 
 
+@functools.lru_cache(maxsize=1024)
 def _is_secret_attr(name: str) -> bool:
+    # attribute names are the code's own few: the taxonomy's verdict on a
+    # name is looked up once, not at every span (2.5 us an attribute).
     # lazy import: taxonomy is stdlib-only but lives in the analysis
     # package; importing it here at module load would couple every
     # tracing user to the analyzer package's import time
@@ -265,19 +289,22 @@ def emit(
     parent_id: Optional[str] = None,
     kind: str = "X",
     **attrs: Any,
-) -> None:
+) -> Optional[str]:
     """Record an already-finished interval as a span (retroactive form:
     the scheduler turns queue-entry lifetimes into spans at dispatch or
-    shed time without holding live span objects in its entries)."""
+    shed time without holding live span objects in its entries).
+    Returns the span's id, for a caller that parents later spans under
+    it; None when nothing was recorded."""
     if not _ENABLED:
-        return
+        return None
     sink = _sink
     if sink is None:
-        return
+        return None
+    span_id = _next_span_id()
     sink({
         "name": name,
         "trace_id": trace_id or trace_id_for(name),
-        "span_id": _next_span_id(),
+        "span_id": span_id,
         "parent_id": parent_id,
         "node": node,
         "tid": tid,
@@ -286,6 +313,7 @@ def emit(
         "kind": kind,
         "attrs": clean_attrs(attrs) if attrs else {},
     })
+    return span_id
 
 
 def instant(name: str, *, node: str = "local", tid: str = "main",

@@ -25,6 +25,13 @@ PRIORITY_BULK = "bulk"
 PRIORITIES = (PRIORITY_INTERACTIVE, PRIORITY_BULK)
 
 
+def lane_of(msg) -> str:
+    """The SLO lane a command rides: its ``priority`` where that names a
+    lane, else bulk (keygen commands carry none)."""
+    lane = getattr(msg, "priority", PRIORITY_BULK)
+    return lane if lane in PRIORITIES else PRIORITY_BULK
+
+
 def canonical_json(obj: Any) -> bytes:
     """Deterministic JSON: sorted keys, no whitespace, UTF-8."""
     return json.dumps(obj, sort_keys=True, separators=(",", ":")).encode()

@@ -9,7 +9,10 @@ Three checks, all zero-dependency:
    protocol spans, session spans, device phases.
 2. Transcript equality: the SAME deterministic batched-signing run,
    traced and untraced, produces byte-identical round transcripts and
-   signatures — tracing must be observationally free.
+   signatures — tracing must be observationally free. Run twice: party
+   to party through the in-process runner, and through real Sessions
+   over a loopback fabric under the armed flight recorder (the served
+   path's envelope and hello spans, the clock-anchor hook).
 3. (unless --no-sweep) the mpclint + mpcflow + mpcshape static gate via
    scripts/check_all.py — span attributes that hit the secret taxonomy
    must go through the declassify registry, never into the baseline.
@@ -38,6 +41,12 @@ REQUIRED_SPAN_LAYERS = {
     "scheduler intake": lambda n: n == "intake",
     "scheduler queue": lambda n: n == "queue",
     "scheduler dispatch": lambda n: n == "dispatch",
+    "client SDK": lambda n: n.startswith("client:"),
+    "manifest admission": lambda n: n == "host:manifest_admit",
+    "batch preparation": lambda n: n == "host:batch_prepare",
+    "hello barrier": lambda n: n == "wait:hello",
+    "inbound envelopes": lambda n: n == "host:envelope_in",
+    "result egress": lambda n: n == "host:result_egress",
     "protocol rounds": lambda n: n.startswith("round:"),
     "sessions": lambda n: n == "session",
     "device phases": lambda n: n.startswith("phase:"),
@@ -109,16 +118,29 @@ def check_sample() -> list:
 
 def check_transcript_equality() -> list:
     """The same deterministic 2-party batched EdDSA signing run, traced
-    and untraced: round transcripts and signatures must be identical."""
+    and untraced: round transcripts and signatures must be identical —
+    party to party through the runner, and through Sessions on a
+    loopback fabric with the flight recorder armed."""
     _setup_cpu_jax()
     import random
+    import tempfile
+    import time
 
+    from mpcium_tpu import trace
     from mpcium_tpu.engine import eddsa_batch as eb
+    from mpcium_tpu.identity.identity import IdentityStore, generate_identity
+    from mpcium_tpu.node.session import Session
     from mpcium_tpu.protocol.eddsa.batch_signing import (
         BatchedEDDSASigningParty,
     )
     from mpcium_tpu.protocol.runner import run_protocol
+    from mpcium_tpu.transport.loopback import LoopbackFabric
     from mpcium_tpu.utils import tracing
+
+    ids = ["n0", "n1"]
+    # a known start: --regen's cluster leaves the recorder armed
+    trace.disarm()
+    trace.recorder.reset(ids)
 
     class DetRng:
         def __init__(self, seed):
@@ -130,55 +152,107 @@ def check_transcript_equality() -> list:
         def randbelow(self, n):
             return self._r.randrange(n)
 
-    def one_run(traced):
-        spans = []
-        transcript = []
-        shares = eb.dealer_keygen_batch(2, ["n0", "n1"], 1, rng=DetRng(5))
+    def make_parties(transcript):
+        shares = eb.dealer_keygen_batch(2, ids, 1, rng=DetRng(5))
+        parties = {
+            pid: BatchedEDDSASigningParty(
+                "trace-eq", pid, ids, shares[i],
+                [b"a" * 32, b"b" * 32], rng=DetRng(11 + i),
+            )
+            for i, pid in enumerate(ids)
+        }
+        for p in parties.values():
+            orig = p.receive
+
+            def rec(m, _o=orig):
+                transcript.append(
+                    (m.round, m.from_id, m.to, repr(m.payload))
+                )
+                return _o(m)
+
+            p.receive = rec
+        return parties
+
+    def signatures(parties):
+        return {p: parties[p].result["signatures"].tobytes()
+                for p in parties}
+
+    def through_runner(traced):
+        spans, transcript = [], []
         if traced:
             tracing.enable(sink=spans.append)
         try:
-            parties = {
-                pid: BatchedEDDSASigningParty(
-                    "trace-eq", pid, ["n0", "n1"], shares[i],
-                    [b"a" * 32, b"b" * 32], rng=DetRng(11 + i),
-                )
-                for i, pid in enumerate(["n0", "n1"])
-            }
-            for p in parties.values():
-                orig = p.receive
-
-                def rec(m, _o=orig):
-                    transcript.append(
-                        (m.round, m.from_id, m.to, repr(m.payload))
-                    )
-                    return _o(m)
-
-                p.receive = rec
+            parties = make_parties(transcript)
             run_protocol(parties)
         finally:
             tracing.disable()
-        sigs = {p: parties[p].result["signatures"].tobytes()
-                for p in parties}
-        return transcript, sigs, spans
+        return transcript, signatures(parties), spans
 
-    t_off, sig_off, s_off = one_run(False)
-    t_on, sig_on, s_on = one_run(True)
+    def through_sessions(traced):
+        """Sessions deliver on the fabric's worker threads, so the
+        transcript is compared as a sorted list."""
+        transcript = []
+        if traced:
+            trace.arm(node_ids=ids)
+        try:
+            parties = make_parties(transcript)
+            fabric = LoopbackFabric()
+            with tempfile.TemporaryDirectory() as d:
+                for n in ids:
+                    generate_identity(n, d)
+                sessions = [
+                    Session(
+                        session_id="trace-eq", party=parties[n], node_id=n,
+                        participants=ids, transport=fabric.transport(),
+                        identity=IdentityStore(d, n, {i: i for i in ids}),
+                        broadcast_topic="trace-eq.bcast",
+                        direct_topic_fn=lambda to: f"trace-eq.direct.{to}",
+                    )
+                    for n in ids
+                ]
+                try:
+                    for s in sessions:
+                        s.listen()
+                    deadline = time.monotonic() + 600
+                    for s in sessions:
+                        s.wait(max(0.0, deadline - time.monotonic()))
+                    tracing.clock_anchor()  # armed: the hook runs, idle
+                finally:
+                    for s in sessions:
+                        s.close()
+                    fabric.close()
+            if not all(p.done for p in parties.values()):
+                return [], {}, []
+            spans = [s for _n, (ss, _d) in trace.recorder.snapshot_all(
+                ids, clear=True).items() for s in ss]
+        finally:
+            trace.disarm()
+        return sorted(transcript), signatures(parties), spans
+
     errors = []
-    if s_off:
-        errors.append("transcript-equality: spans emitted while disabled")
-    if not s_on:
-        errors.append("transcript-equality: no spans emitted while traced")
-    if t_off != t_on:
-        errors.append(
-            "transcript-equality: traced run CHANGED the round transcript"
-        )
-    if sig_off != sig_on:
-        errors.append(
-            "transcript-equality: traced run CHANGED the signatures"
-        )
-    if not errors:
-        print(f"trace-check: transcript equality OK "
-              f"({len(t_off)} messages, {len(s_on)} spans)")
+    for how, one_run, must_span in (
+        ("runner", through_runner, {"phase:bsign_nonce_commit"}),
+        ("sessions", through_sessions,
+         {"host:envelope_in", "wait:hello", "session",
+          "phase:bsign_nonce_commit"}),
+    ):
+        t_off, sig_off, s_off = one_run(False)
+        t_on, sig_on, s_on = one_run(True)
+        tag = f"transcript-equality ({how})"
+        if not t_off or not sig_off:
+            errors.append(f"{tag}: the untraced run did not finish")
+        if s_off:
+            errors.append(f"{tag}: spans emitted while disabled")
+        missing = must_span - {s["name"] for s in s_on}
+        if missing:
+            errors.append(f"{tag}: traced run lacks spans {sorted(missing)}")
+        if t_off != t_on:
+            errors.append(f"{tag}: traced run CHANGED the round transcript")
+        if sig_off != sig_on:
+            errors.append(f"{tag}: traced run CHANGED the signatures")
+        if not errors:
+            print(f"trace-check: transcript equality OK through the {how} "
+                  f"({len(t_off)} messages, {len(s_on)} spans)")
     return errors
 
 
