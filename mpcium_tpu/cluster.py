@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import json
 import tempfile
+import threading
 from pathlib import Path
 from typing import Dict, List, Optional
 
@@ -186,6 +187,7 @@ class LocalCluster(SyncOps):
         # their transport wrapped; with no plan nothing is constructed and
         # behavior is byte-identical to a bare cluster
         self._fault_plans = fault_plans or {}
+        self._fabric_stats_lock = threading.Lock()
         self.fault_transports: Dict[str, object] = {}
         self._retired_fault_transports: List[object] = []
         self._hello_timeout_s = hello_timeout_s
@@ -296,14 +298,34 @@ class LocalCluster(SyncOps):
         """Per-node operational snapshots (EventConsumer.health): live
         sessions, dedup claims, and every scheduler metric — lane queue
         depths, shed counters, fill ratios, latency percentiles."""
+        self._fold_fabric_stats()
         return {nid: ec.health() for nid, ec in self.node_consumers.items()}
+
+    def _fold_fabric_stats(self) -> None:
+        """Bring ``transport.dedup_hits`` / ``transport.dedup_keys`` /
+        ``transport.subscriptions`` up to what the loopback fabric counts
+        now, in the first node's registry only: one fabric stands behind
+        every node, and a sum over the nodes counts it once. Nothing over
+        TCP (the broker is another process and keeps its own)."""
+        if self.fabric is None or not self.node_consumers:
+            return
+        stats = self.fabric.stats()
+        metrics = next(iter(self.node_consumers.values())).metrics
+        with self._fabric_stats_lock:  # two snapshots, one delta
+            for name, total in stats["counters"].items():
+                counter = metrics.counter(name)
+                counter.inc(max(0.0, total - counter.value))
+        for name, value in stats["gauges"].items():
+            metrics.gauge(name).set(value)
 
     def metrics_snapshot(self) -> Dict[str, dict]:
         """Just the metric registries, keyed by node id (the soak harness
         and smoke tests consume this). ``trace.dropped_spans`` is brought
         up to date first: each node's own ring, and on the first node also
         the rings no node owns (``engine``, ``client``, ``local``), so the
-        sum over the snapshot counts every ring once."""
+        sum over the snapshot counts every ring once. The loopback
+        fabric, which no node owns either, is counted the same way
+        (:meth:`_fold_fabric_stats`)."""
         dropped = _trace_recorder.dropped_totals()
         shared = sum(d for ring, d in dropped.items()
                      if ring not in self.node_consumers)
@@ -311,6 +333,7 @@ class LocalCluster(SyncOps):
             ec.metrics.gauge("trace.dropped_spans").set(
                 float(dropped.get(nid, 0) + shared))
             shared = 0
+        self._fold_fabric_stats()
         return {
             nid: ec.metrics.snapshot()
             for nid, ec in self.node_consumers.items()

@@ -23,9 +23,9 @@ from __future__ import annotations
 import itertools
 import threading
 import time
-from collections import defaultdict, deque
+from collections import deque
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Tuple
+from typing import Callable, Dict, List
 
 from .api import (
     DeadLetterHandler,
@@ -40,6 +40,7 @@ from .api import (
     TransportError,
     stamped,
 )
+from .dedup import DedupWindow
 
 
 def topic_matches(pattern: str, topic: str) -> bool:
@@ -50,7 +51,7 @@ def topic_matches(pattern: str, topic: str) -> bool:
     return False
 
 
-@dataclass
+@dataclass(eq=False)  # a subscription is itself, not its fields
 class _Sub(Subscription):
     fabric: "LoopbackFabric"
     kind: str
@@ -61,9 +62,47 @@ class _Sub(Subscription):
     def unsubscribe(self) -> None:
         self.active = False
         with self.fabric._lock:
-            subs = self.fabric._subs[self.kind].get(self.pattern, [])
-            if self in subs:
-                subs.remove(self)
+            self.fabric._subs[self.kind].remove(self)
+
+
+class _SubIndex:
+    """One kind's live subscriptions, split by how a pattern can match
+    (:func:`topic_matches`): one without a trailing ``*`` matches only
+    the topic equal to it, so those are found by a dict lookup; the few
+    with one are tried in turn. A pattern whose last subscription goes
+    leaves with it, so a delivery costs what is subscribed now, not what
+    ever was (the signing bridge subscribes a fresh inbox per request)."""
+
+    def __init__(self) -> None:
+        self.exact: Dict[str, List[_Sub]] = {}
+        self.wild: Dict[str, List[_Sub]] = {}
+
+    def __len__(self) -> int:
+        return len(self.exact) + len(self.wild)
+
+    def _bucket(self, pattern: str) -> Dict[str, List[_Sub]]:
+        return self.wild if pattern.endswith("*") else self.exact
+
+    def add(self, sub: _Sub) -> None:
+        self._bucket(sub.pattern).setdefault(sub.pattern, []).append(sub)
+
+    def remove(self, sub: _Sub) -> None:
+        bucket = self._bucket(sub.pattern)
+        subs = bucket.get(sub.pattern, [])
+        if sub in subs:
+            subs.remove(sub)
+            if not subs:
+                del bucket[sub.pattern]
+
+    def targets(self, topic: str) -> List[_Sub]:
+        """The active subscriptions ``topic`` reaches: those of the
+        pattern equal to it, then the wildcards' in the order their
+        patterns were first subscribed."""
+        found = [s for s in self.exact.get(topic, ()) if s.active]
+        for pattern, subs in self.wild.items():
+            if topic_matches(pattern, topic):
+                found.extend(s for s in subs if s.active)
+        return found
 
 
 class LoopbackFabric:
@@ -75,18 +114,13 @@ class LoopbackFabric:
         from concurrent.futures import ThreadPoolExecutor
 
         self._lock = threading.RLock()
-        self._subs: Dict[str, Dict[str, List[_Sub]]] = {
-            "pubsub": defaultdict(list),
-            "direct": defaultdict(list),
-            "queue": defaultdict(list),
+        self._subs: Dict[str, _SubIndex] = {
+            "pubsub": _SubIndex(),
+            "direct": _SubIndex(),
+            "queue": _SubIndex(),
         }
         self._queue_config = queue_config
-        # idempotency keys live for a bounded window (JetStream's duplicate
-        # window semantics): repeats within it are deduped, later legitimate
-        # re-submissions (e.g. a second reshare of the same wallet) pass,
-        # and the set cannot grow without bound
-        self._dedup_window_s = 120.0
-        self._seen_msg_ids: Dict[Tuple[str, str], float] = {}
+        self._dedup = DedupWindow()  # idempotent enqueue; under _lock
         self._dead_letter: List[DeadLetterHandler] = []
         self._pending_queue_msgs: deque = deque()  # undelivered (no consumer yet)
         self._seq = itertools.count()
@@ -159,20 +193,14 @@ class LoopbackFabric:
 
     def publish(self, topic: str, data: bytes) -> None:
         with self._lock:
-            targets = [
-                s
-                for pat, subs in self._subs["pubsub"].items()
-                if topic_matches(pat, topic)
-                for s in subs
-                if s.active
-            ]
+            targets = self._subs["pubsub"].targets(topic)
         for s in targets:
             self._post(lambda s=s: s.active and s.handler(data))
 
     def subscribe(self, pattern: str, handler: Handler, kind: str = "pubsub") -> _Sub:
         sub = _Sub(self, kind, pattern, handler)
         with self._lock:
-            self._subs[kind][pattern].append(sub)
+            self._subs[kind].add(sub)
         if kind == "queue":
             self._flush_pending()
         return sub
@@ -192,13 +220,7 @@ class LoopbackFabric:
             done = threading.Event()
             err: List[BaseException] = []
             with self._lock:
-                targets = [
-                    s
-                    for pat, subs in self._subs["direct"].items()
-                    if topic_matches(pat, topic)
-                    for s in subs
-                    if s.active
-                ]
+                targets = self._subs["direct"].targets(topic)
             if targets:
                 def run(s=targets[0]):
                     try:
@@ -239,27 +261,13 @@ class LoopbackFabric:
     def enqueue(self, topic: str, data: bytes, idempotency_key: str = "") -> None:
         if idempotency_key:
             with self._lock:
-                now = time.monotonic()
-                key = (topic.rsplit(".", 1)[0], idempotency_key)
-                self._seen_msg_ids = {
-                    k: t
-                    for k, t in self._seen_msg_ids.items()
-                    if now - t < self._dedup_window_s
-                }
-                if key in self._seen_msg_ids:
+                if not self._dedup.admit(topic, idempotency_key):
                     return  # deduped (Nats-Msg-Id semantics)
-                self._seen_msg_ids[key] = now
         self._deliver_queue_msg(topic, data, deliveries=0)
 
     def _deliver_queue_msg(self, topic: str, data: bytes, deliveries: int) -> None:
         with self._lock:
-            targets = [
-                s
-                for pat, subs in self._subs["queue"].items()
-                if topic_matches(pat, topic)
-                for s in subs
-                if s.active
-            ]
+            targets = self._subs["queue"].targets(topic)
         if not targets:
             with self._lock:
                 self._pending_queue_msgs.append((topic, data, deliveries))
@@ -298,6 +306,25 @@ class LoopbackFabric:
     def add_dead_letter_handler(self, handler: DeadLetterHandler) -> None:
         with self._lock:
             self._dead_letter.append(handler)
+
+    # -- what the fabric counts ---------------------------------------------
+
+    def stats(self) -> Dict[str, Dict[str, float]]:
+        """The fabric's own counts, in a registry snapshot's shape. It owns
+        no registry (every in-process node shares it): the cluster folds
+        these into one when it takes the nodes' metrics
+        (``LocalCluster.metrics_snapshot``)."""
+        with self._lock:
+            return {
+                "counters": {
+                    "transport.dedup_hits": float(self._dedup.hits),
+                },
+                "gauges": {
+                    "transport.dedup_keys": float(len(self._dedup)),
+                    "transport.subscriptions": float(
+                        sum(len(index) for index in self._subs.values())),
+                },
+            }
 
     # -- node-facing views --------------------------------------------------
 

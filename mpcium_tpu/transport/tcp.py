@@ -83,6 +83,7 @@ from .api import (
     TransportError,
     stamped,
 )
+from .dedup import DedupWindow
 from .loopback import topic_matches
 from ..utils import log
 
@@ -173,9 +174,7 @@ class BrokerServer:
         self._cid = itertools.count(1)
         self._did = itertools.count(1)
         self._rr = itertools.count()
-        # bounded dedup window (JetStream duplicate-window semantics)
-        self._dedup_window_s = 120.0
-        self._seen_ids: Dict[Tuple[str, str], float] = {}
+        self._dedup = DedupWindow()  # idempotent enqueue; under _lock
         self._pending_q: deque = deque()  # (topic, data, deliveries, mid)
         self._pending_mids: Set[int] = set()  # mirror of _pending_q mids
         # Work-queue TTL: per-tx/per-wallet RESULT topics mean a result
@@ -264,7 +263,6 @@ class BrokerServer:
                         self._kv.pop(rec["k"], None)
         self._mid_next = max_mid + 1
         tmp = path + ".tmp"
-        now = time.monotonic()
         with open(tmp, "w") as fh:
             for mid, (topic, data, key, ts) in sorted(pending.items()):
                 fh.write(json.dumps(
@@ -274,7 +272,7 @@ class BrokerServer:
                 self._pending_mids.add(mid)
                 self._enq_ts[mid] = ts
                 if key:
-                    self._seen_ids[(topic.rsplit(".", 1)[0], key)] = now
+                    self._dedup.mark(topic, key)
             for k in sorted(self._kv):
                 fh.write(json.dumps(
                     {"j": "kvp", "k": k, "v": self._kv[k]},
@@ -455,16 +453,8 @@ class BrokerServer:
             key = f.get("key", "")
             if key:
                 with self._lock:
-                    now = time.monotonic()
-                    self._seen_ids = {
-                        k: t
-                        for k, t in self._seen_ids.items()
-                        if now - t < self._dedup_window_s
-                    }
-                    dk = (f["topic"].rsplit(".", 1)[0], key)
-                    if dk in self._seen_ids:
+                    if not self._dedup.admit(f["topic"], key):
                         return
-                    self._seen_ids[dk] = now
             with self._lock:
                 mid = self._mid_next
                 self._mid_next += 1
@@ -674,9 +664,7 @@ class BrokerServer:
                 if mid in self._pending_mids:
                     return  # snapshot/stream or re-follow overlap
                 if key:
-                    self._seen_ids[(topic.rsplit(".", 1)[0], key)] = (
-                        time.monotonic()
-                    )
+                    self._dedup.mark(topic, key)
                 self._pending_q.append((topic, data, 0, mid))
                 self._pending_mids.add(mid)
                 self._enq_ts[mid] = ts
