@@ -350,6 +350,10 @@ class BatchSigningScheduler:
         # GG18 exponent domains (None = production defaults); tests with
         # shrunk keys set this on every quorum member's scheduler
         self.gg18_dom = None
+        # this node's GG18 modulus contexts, kept across its batches
+        # (protocol/ecdsa/batch_signing.ContextCache: what stays resident
+        # and for how long); made at the first GG18 batch, emptied by close
+        self._gg18_contexts = None
         # hello/unicast budgets for batch sessions: one round of a batched
         # party can spend minutes in XLA compiles or DLN verification, so
         # a busy (not gone) peer must not trip the 3x3s transport budget
@@ -416,10 +420,22 @@ class BatchSigningScheduler:
         with self._lock:
             return len(self._settled)
 
+    def gg18_contexts(self):
+        """The node's GG18 context cache (made on first use: the module
+        that defines it loads the GG18 engine)."""
+        with self._lock:
+            if self._gg18_contexts is None:
+                from ..protocol.ecdsa.batch_signing import ContextCache
+
+                self._gg18_contexts = ContextCache()
+            return self._gg18_contexts
+
     def close(self) -> None:
         self._closed = True
         self._sub.unsubscribe()
         self._wheel.close()
+        if self._gg18_contexts is not None:
+            self._gg18_contexts.clear()
         with self._lock:
             for s in self._sessions:
                 s.close()
@@ -1859,7 +1875,8 @@ class BatchSigningScheduler:
                 party = BatchedECDSASigningParty(
                     f"bsign:{batch_id}", node.node_id, quorum, shares,
                     messages, dom=self.gg18_dom or Domains(),
-                    cohorts=cohorts,
+                    cohorts=cohorts, metrics=self.metrics,
+                    contexts=self.gg18_contexts(),
                 )
             else:
                 party = BatchedEDDSASigningParty(

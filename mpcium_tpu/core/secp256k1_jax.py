@@ -76,45 +76,44 @@ def to_host(p: SecpPointJ) -> list:
     return out
 
 
-def add(a: SecpPointJ, b: SecpPointJ) -> SecpPointJ:
-    """Complete addition, RCB15 Algorithm 7 (a=0, b3=21)."""
+def _mul_small_each(x: jnp.ndarray, ks) -> jnp.ndarray:
+    """Row i of a stack (k, ..., 22) times the small constant ks[i]."""
     F = secp256k1_field()
-    m, s, A = F.mul, F.mul_small, F.add
-    S = F.sub
-    t0 = m(a.X, b.X)
-    t1 = m(a.Y, b.Y)
-    t2 = m(a.Z, b.Z)
-    t3 = A(a.X, a.Y)
-    t4 = A(b.X, b.Y)
-    t3 = m(t3, t4)
-    t4 = A(t0, t1)
-    t3 = S(t3, t4)
-    t4 = A(a.Y, a.Z)
-    x3 = A(b.Y, b.Z)
-    t4 = m(t4, x3)
-    x3 = A(t1, t2)
-    t4 = S(t4, x3)
-    x3 = A(a.X, a.Z)
-    y3 = A(b.X, b.Z)
-    x3 = m(x3, y3)
-    y3 = A(t0, t2)
-    y3 = S(x3, y3)
-    x3 = A(t0, t0)
-    t0 = A(x3, t0)
-    t2 = s(t2, _B3)
-    z3 = A(t1, t2)
-    t1 = S(t1, t2)
-    y3 = s(y3, _B3)
-    x3 = m(t4, y3)
-    t2 = m(t3, t1)
-    x3 = S(t2, x3)
-    y3 = m(y3, t0)
-    t1 = m(t1, z3)
-    y3 = A(t1, y3)
-    t0 = m(t0, t3)
-    z3 = m(z3, t4)
-    z3 = A(z3, t0)
-    return SecpPointJ(x3, y3, z3)
+    k = jnp.asarray(ks, jnp.int32).reshape((len(ks),) + (1,) * (x.ndim - 1))
+    return F.fold(bn.carry(bn.pad_limbs(x * k, 1), PROF))
+
+
+def add(a: SecpPointJ, b: SecpPointJ) -> SecpPointJ:
+    """Complete addition, RCB15 Algorithm 7 (a=0, b3=21).
+
+    The formula's independent field operations run as ONE operation over a
+    leading stack axis (its twelve multiplications as two of six, its
+    additions and subtractions as five), so a compiled addition holds nine
+    field operations one after another and not thirty-six: the programs
+    that inline it are a quarter of the size, and a ladder's chain of
+    dependent operations a quarter of the length."""
+    F = secp256k1_field()
+    shape = jnp.broadcast_shapes(a.X.shape, b.X.shape)
+    aX, aY, aZ, bX, bY, bZ = (
+        jnp.broadcast_to(c, shape) for c in (*a, *b)
+    )
+    st = jnp.stack
+    # aX+aY, aY+aZ, aX+aZ and the same of b
+    s = F.add(st([aX, aY, aX, bX, bY, bX]), st([aY, aZ, aZ, bY, bZ, bZ]))
+    m = F.mul(st([aX, aY, aZ, s[0], s[1], s[2]]),
+              st([bX, bY, bZ, s[3], s[4], s[5]]))
+    t0, t1, t2 = m[0], m[1], m[2]
+    k = _mul_small_each(st([t0, t2]), (3, _B3))       # 3·t0, b3·t2
+    p = F.add(st([t0, t1, t0, t1]), st([t1, t2, t2, k[1]]))
+    z3 = p[3]                                          # t1 + b3·t2
+    # t3, t4, y3 (the cross terms) and t1 - b3·t2
+    d = F.sub(st([m[3], m[4], m[5], t1]), st([p[0], p[1], p[2], k[1]]))
+    t3, t4, t1 = d[0], d[1], d[3]
+    y3 = F.mul_small(d[2], _B3)
+    w = F.mul(st([t4, t3, y3, t1, k[0], z3]),
+              st([y3, t1, k[0], z3, t3, t4]))
+    yz = F.add(st([w[3], w[5]]), st([w[2], w[4]]))
+    return SecpPointJ(F.sub(w[1], w[0]), yz[0], yz[1])
 
 
 def double(a: SecpPointJ) -> SecpPointJ:
@@ -137,45 +136,104 @@ def scalars_to_bits(ks, n_bits: int = SCALAR_BITS) -> np.ndarray:
     return out
 
 
+# Both ladders take 4-bit windows. At the batch widths a served wave has
+# (tens of lanes) a ladder's time is its count of point additions one
+# after another, not their width: a window step is four doublings and one
+# addition of a table entry chosen per lane, so a 256-bit scalar costs 334
+# additions (14 to build the lane's table) where double-and-add cost 512;
+# the fixed base's table is a constant, so k·G costs 64.
+_WINDOW = 4
+_N_WINDOWS = SCALAR_BITS // _WINDOW
+
+
+def _digits(bits: jnp.ndarray) -> jnp.ndarray:
+    """(..., 256) bits LSB-first → (64, ...) window digits, the most
+    significant window first."""
+    w = bits.reshape(bits.shape[:-1] + (_N_WINDOWS, _WINDOW))
+    d = jnp.sum(w << jnp.arange(_WINDOW, dtype=jnp.int32), axis=-1)
+    return jnp.moveaxis(d, -1, 0)[::-1].astype(jnp.int32)
+
+
+def _pick(table: SecpPointJ, d: jnp.ndarray) -> SecpPointJ:
+    """table: coordinates (16, ..., 22), one entry a digit; d (...,) →
+    the entry of each lane's digit."""
+    idx = d[None, ..., None]
+    return SecpPointJ(*(
+        jnp.take_along_axis(c, jnp.broadcast_to(idx, (1,) + c.shape[1:]),
+                            axis=0)[0]
+        for c in table
+    ))
+
+
 def scalar_mul(bits: jnp.ndarray, p: SecpPointJ) -> SecpPointJ:
-    """Variable-base double-and-add; bits (..., 256) LSB-first."""
-    acc = identity(bits.shape[:-1])
+    """Variable-base k·P by 4-bit windows; bits (..., 256) LSB-first (a
+    shorter scalar is zero-extended)."""
+    n_bits = bits.shape[-1]
+    if n_bits < SCALAR_BITS:
+        bits = jnp.pad(
+            bits, [(0, 0)] * (bits.ndim - 1) + [(0, SCALAR_BITS - n_bits)]
+        )
+    rows = [identity(bits.shape[:-1]), p]
 
-    def step(carry, bit):
-        acc, addend = carry
-        acc = select(bit > 0, add(acc, addend), acc)
-        return (acc, double(addend)), None
+    def next_row(row, _):
+        row = add(row, p)
+        return row, row
 
-    (acc, _), _ = lax.scan(step, (acc, p), jnp.moveaxis(bits, -1, 0))
+    _, more = lax.scan(next_row, p, None, length=(1 << _WINDOW) - 2)
+    table = SecpPointJ(*(
+        jnp.concatenate([jnp.stack([r0, r1]), m], axis=0)
+        for r0, r1, m in zip(rows[0], rows[1], more)
+    ))
+
+    def step(acc, d):
+        entry = _pick(table, d)
+
+        # four doublings and the entry's addition as five runs of ONE
+        # compiled addition (the last with the entry for its second term)
+        def run(i, a):
+            other = SecpPointJ(*(
+                jnp.where(i < _WINDOW, c, e) for c, e in zip(a, entry)
+            ))
+            return add(a, other)
+
+        return lax.fori_loop(0, _WINDOW + 1, run, acc), None
+
+    acc, _ = lax.scan(step, identity(bits.shape[:-1]), _digits(bits))
     return acc
 
 
 @functools.lru_cache(maxsize=None)
 def _base_table() -> tuple:
-    """Constants G·2^i for i in [0, 256): three (256, 22) int32 arrays."""
+    """Constants d·16^i·G for window i in [0, 64), digit d in [0, 16):
+    three (64, 16, 22) int32 arrays, the entry of digit 0 the identity
+    (0:1:0)."""
     F = secp256k1_field()
-    pts = []
-    cur = hm.SECP_G
-    for _ in range(SCALAR_BITS):
-        pts.append((cur.x, cur.y))
-        cur = hm.secp_add(cur, cur)
-    X = F.from_ints([p[0] for p in pts])
-    Y = F.from_ints([p[1] for p in pts])
-    Z = np.broadcast_to(bn.to_limbs(1, PROF), X.shape).copy()
-    return X, Y, Z
+    xs, ys, zs = [], [], []
+    base = hm.SECP_G
+    for _ in range(_N_WINDOWS):
+        xs.append(0), ys.append(1), zs.append(0)
+        cur = base
+        for _d in range(1, 1 << _WINDOW):
+            xs.append(cur.x), ys.append(cur.y), zs.append(1)
+            cur = hm.secp_add(cur, base)
+        base = cur  # 16·base
+    shape = (_N_WINDOWS, 1 << _WINDOW, PROF.n_limbs)
+    return tuple(np.asarray(F.from_ints(v)).reshape(shape)
+                 for v in (xs, ys, zs))
 
 
 def base_mul(bits: jnp.ndarray) -> SecpPointJ:
-    """Fixed-base mult k·G via the G·2^i table."""
-    Xt, Yt, Zt = (jnp.asarray(a) for a in _base_table())
-    acc = identity(bits.shape[:-1])
+    """Fixed-base k·G: one addition a 4-bit window from the table of
+    d·16^i·G (no doublings)."""
+    table = tuple(jnp.asarray(a) for a in _base_table())
+    digits = _digits(bits)[::-1]  # the table's window 0 is the lowest
 
     def step(acc, sl):
-        bit, X, Y, Z = sl
-        tbl = SecpPointJ(*(jnp.broadcast_to(c, acc.X.shape) for c in (X, Y, Z)))
-        return select(bit > 0, add(acc, tbl), acc), None
+        d, X, Y, Z = sl
+        entry = SecpPointJ(*(c[d] for c in (X, Y, Z)))
+        return add(acc, entry), None
 
-    acc, _ = lax.scan(step, acc, (jnp.moveaxis(bits, -1, 0), Xt, Yt, Zt))
+    acc, _ = lax.scan(step, identity(bits.shape[:-1]), (digits,) + table)
     return acc
 
 

@@ -44,6 +44,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 import numpy as np
 
 from ..core import bignum as bn
@@ -93,14 +94,24 @@ def _fold_add(x: jnp.ndarray, extra_limbs: int = 3) -> jnp.ndarray:
     return mm.carry(jnp.sum(x, axis=0, keepdims=True))
 
 
-def _host_pow_single(x_limbs: jnp.ndarray, exp: int, ctx) -> jnp.ndarray:
+def _host_pow_single(x_limbs: jnp.ndarray, exp: int, ctx) -> int:
     """(1, n) limbs → x^exp mod ctx.modulus via one host bigint modexp
     (a single 2048-bit-exponent value: device scan would serialize ~2.5k
     tiny dispatches; CPython pow is milliseconds)."""
     v = bn.batch_from_limbs(np.asarray(x_limbs), ctx.prof)[0]
-    return jnp.asarray(
-        bn.batch_to_limbs([pow(v, exp, ctx.modulus)], ctx.prof)
+    return pow(v, exp, ctx.modulus)
+
+
+def agg_holds(alice: "PartyCtx", agg) -> bool:
+    """The combined ciphertext equation of a batch-verified proof,
+    settled on the host: E · S^N == R mod N² for the three single values
+    a ``*_dev`` check returns."""
+    n2 = alice.pmx.ctx_N2
+    E, R = (
+        bn.batch_from_limbs(np.asarray(x), n2.prof)[0]  # mpcflow: host-ok — single aggregated proof verdict gates the strict fallback
+        for x in (agg[0], agg[2])
     )
+    return E * _host_pow_single(agg[1], alice.N, n2) % n2.modulus == R
 
 
 def _host_pow_batch(x_limbs: jnp.ndarray, exp: int, ctx) -> jnp.ndarray:
@@ -147,11 +158,16 @@ def rand_bits(batch: int, bits: int, rng=secrets) -> np.ndarray:
     return out
 
 
-def rand_bit_tensor(batch: int, bits: int, rng=secrets) -> jnp.ndarray:
-    """(B, bits) int32 uniform CSPRNG bits, LSB-first per value."""
+def rand_bit_array(batch: int, bits: int, rng=secrets) -> np.ndarray:
+    """(B, bits) int32 uniform CSPRNG bits, LSB-first per value (host)."""
     by = rand_bits(batch, bits, rng)
     arr = np.unpackbits(by, axis=-1, bitorder="little")[:, :bits]
-    return jnp.asarray(arr.astype(np.int32))
+    return arr.astype(np.int32)
+
+
+def rand_bit_tensor(batch: int, bits: int, rng=secrets) -> jnp.ndarray:
+    """:func:`rand_bit_array`, placed on the device."""
+    return jnp.asarray(rand_bit_array(batch, bits, rng))
 
 
 def dev_hash(tag: bytes, *rows) -> jnp.ndarray:
@@ -247,15 +263,50 @@ class PartyCtx:
         self.ctx_nt = mm.MXUBarrett(NTilde)
         self.h1 = h1
         self.h2 = h2
+        self.nt_bits = NTilde.bit_length()
         self.nt_bytes = -(-NTilde.bit_length() // 8)
         self.n2_bytes = -(-(2 * N.bit_length()) // 8)
         self.n_bytes = -(-N.bit_length() // 8)
 
+    # -- pytree: a party's context is an ARGUMENT of the jitted round
+    # programs (its arrays the operands, its widths the only statics), so
+    # one executable serves every key of a width and none holds a key -----
+
+    def _tree_flatten(self):
+        return (self.pmx, self.ctx_nt), (
+            self.nt_bits, self.nt_bytes, self.n2_bytes, self.n_bytes,
+        )
+
+    @classmethod
+    def _tree_unflatten(cls, aux, children):
+        self = object.__new__(cls)
+        self.pmx, self.ctx_nt = children
+        self.nt_bits, self.nt_bytes, self.n2_bytes, self.n_bytes = aux
+        self.pid = self.pre = self.N = self.NTilde = None
+        self.h1 = self.h2 = None  # inside a trace: the named combs only
+        return self
+
+    def name_ring_combs(self, h1_bits: int, h2_bits: int) -> None:
+        """Keep the combs of h1 and h2 for exponents of up to these widths
+        (the proof domains decide them: MtaBatch asks)."""
+        if self.h1 is None:
+            return  # rebuilt inside a trace: its combs came as operands
+        self.ctx_nt.name_comb("h1", self.h1, h1_bits)
+        self.ctx_nt.name_comb("h2", self.h2, h2_bits)
+
     def commit_ring(self, m_bits: jnp.ndarray, r_bits: jnp.ndarray) -> jnp.ndarray:
         """h1^m · h2^r mod NTilde — two comb-table fixed-base exps."""
-        a = self.ctx_nt.powmod_fixed_base(self.h1, m_bits)
-        b = self.ctx_nt.powmod_fixed_base(self.h2, r_bits)
+        a = self.ctx_nt.powmod_named_base("h1", m_bits)
+        b = self.ctx_nt.powmod_named_base("h2", r_bits)
         return self.ctx_nt.mulmod(a, b)
+
+    def commit_ring_many(self, pairs) -> list:
+        """[(m_bits, r_bits), ...] → [h1^m · h2^r, ...]: the h1 legs as one
+        comb pass over all lanes, the h2 legs as another, one product."""
+        nt = self.ctx_nt
+        a = nt.powmod_named_base_many("h1", [m for m, _ in pairs])
+        b = nt.powmod_named_base_many("h2", [r for _, r in pairs])
+        return nt.mulmod_many(list(zip(a, b)))
 
     def nt_row(self, x: jnp.ndarray) -> jnp.ndarray:
         return bn.limbs_to_bytes_le(x, self.ctx_nt.prof, self.nt_bytes)
@@ -285,46 +336,103 @@ class MtaBatch:
         self.p_e = _prof7(d.scalar)
         self.p_alpha = _prof7(d.alpha)
         self.p_s1 = _prof7(d.scalar + d.alpha + 7)
-        nt_bits = bob.NTilde.bit_length()
-        nt_bits_a = alice.NTilde.bit_length()
+        nt_bits = bob.nt_bits
+        nt_bits_a = alice.nt_bits
         self.p_rho = _prof7(d.scalar + max(nt_bits, nt_bits_a) + d.rho_extra)
         self.p_s2 = _prof7(d.scalar + self.p_rho.n_limbs * 7 + 7)
         self.p_bp = _prof7(d.beta_prime)
         self.p_gb = _prof7(d.gamma_bob)
         self.p_t1 = _prof7(d.scalar + d.gamma_bob + 7)
+        # (a context built with its combs at these widths or wider is
+        # left as it is: protocol/ecdsa/batch_signing.ContextCache)
+        for side in (alice, bob):
+            side.name_ring_combs(
+                *self.ring_comb_bits(d, max(nt_bits, nt_bits_a)))
+
+    @staticmethod
+    def ring_comb_bits(d: Domains, nt_bits: int) -> Tuple[int, int]:
+        """The widest exponent each ring-Pedersen base (h1, h2) meets in a
+        pair whose wider NTilde has ``nt_bits`` bits."""
+        p_s1 = _prof7(d.scalar + d.alpha + 7)
+        p_t1 = _prof7(d.scalar + d.gamma_bob + 7)
+        p_rho = _prof7(d.scalar + nt_bits + d.rho_extra)
+        p_s2 = _prof7(d.scalar + p_rho.n_limbs * 7 + 7)
+        h1_bits = max(d.scalar, d.alpha, d.beta_prime, d.gamma_bob,
+                      p_s1.n_limbs * 7, p_t1.n_limbs * 7)
+        return h1_bits, max(p_rho.n_limbs, p_s2.n_limbs) * 7
+
+    def _tree_flatten(self):
+        return (self.alice, self.bob), self.dom
+
+    @classmethod
+    def _tree_unflatten(cls, dom, children):
+        return cls(children[0], children[1], dom)
 
     # -- randomness bundles (host CSPRNG → device) --------------------------
 
-    @staticmethod
-    def _dom_limbs(B, bits, prof, rng):
-        return bn.bytes_to_limbs_le(
-            jnp.asarray(rand_bits(B, bits, rng)), prof, prof.n_limbs
+    # Each draw is (name, bits, profile): uniform CSPRNG bytes from the
+    # host, in this order; profile None marks an Enc randomizer, drawn as
+    # a (B, RAND_BITS) bit tensor. ``*_raw`` draws; ``randoms_from`` turns
+    # the bytes into limbs and is traceable, so a jitted round program
+    # takes the raw draw and no small program runs between the two.
+
+    def _alice_draws(self):
+        d, nt_b = self.dom, self.bob.nt_bits
+        return (
+            ("u_enc", RAND_BITS, None),  # Enc(α) randomizer
+            ("alpha", d.alpha - 8, self.p_alpha),
+            ("rho", d.scalar + nt_b - 8, self.p_rho),
+            ("gamma", d.alpha + nt_b - 8, self.p_s2),
         )
 
-    def alice_randoms(self, B: int, rng=secrets) -> Dict[str, jnp.ndarray]:
-        d = self.dom
-        nt_b = self.bob.NTilde.bit_length()
+    def _bob_draws(self):
+        d, nt_a = self.dom, self.alice.nt_bits
+        return (
+            ("beta_prime", d.beta_prime - 8, self.p_bp),
+            ("u_bp", RAND_BITS, None),  # Enc(β′) randomizer
+            ("alpha", d.alpha - 8, self.p_alpha),
+            ("rho", d.scalar + nt_a - 8, self.p_rho),
+            ("rho_p", d.alpha + nt_a - 8, self.p_s2),
+            ("sigma", d.scalar + nt_a - 8, self.p_rho),
+            ("tau", d.alpha + nt_a - 8, self.p_s2),
+            ("u_g", RAND_BITS, None),  # Enc(γ) randomizer
+            ("gamma", d.gamma_bob - 8, self.p_gb),
+        )
+
+    @staticmethod
+    def _draw(draws, B: int, rng) -> Dict[str, np.ndarray]:
         return {
-            "u_enc": rand_bit_tensor(B, RAND_BITS, rng),  # Enc(α) randomizer
-            "alpha": self._dom_limbs(B, d.alpha - 8, self.p_alpha, rng),
-            "rho": self._dom_limbs(B, d.scalar + nt_b - 8, self.p_rho, rng),
-            "gamma": self._dom_limbs(B, d.alpha + nt_b - 8, self.p_s2, rng),
+            name: (rand_bit_array(B, bits, rng) if prof is None
+                   else rand_bits(B, bits, rng))
+            for name, bits, prof in draws
         }
 
-    def bob_randoms(self, B: int, rng=secrets) -> Dict[str, jnp.ndarray]:
-        d = self.dom
-        nt_a = self.alice.NTilde.bit_length()
+    @staticmethod
+    def _limbs(draws, raw) -> Dict[str, jnp.ndarray]:
         return {
-            "beta_prime": self._dom_limbs(B, d.beta_prime - 8, self.p_bp, rng),
-            "u_bp": rand_bit_tensor(B, RAND_BITS, rng),  # Enc(β′) randomizer
-            "alpha": self._dom_limbs(B, d.alpha - 8, self.p_alpha, rng),
-            "rho": self._dom_limbs(B, d.scalar + nt_a - 8, self.p_rho, rng),
-            "rho_p": self._dom_limbs(B, d.alpha + nt_a - 8, self.p_s2, rng),
-            "sigma": self._dom_limbs(B, d.scalar + nt_a - 8, self.p_rho, rng),
-            "tau": self._dom_limbs(B, d.alpha + nt_a - 8, self.p_s2, rng),
-            "u_g": rand_bit_tensor(B, RAND_BITS, rng),  # Enc(γ) randomizer
-            "gamma": self._dom_limbs(B, d.gamma_bob - 8, self.p_gb, rng),
+            name: (jnp.asarray(raw[name]) if prof is None
+                   else bn.bytes_to_limbs_le(
+                       jnp.asarray(raw[name]), prof, prof.n_limbs))
+            for name, _bits, prof in draws
         }
+
+    def alice_raw(self, B: int, rng=secrets) -> Dict[str, np.ndarray]:
+        return self._draw(self._alice_draws(), B, rng)
+
+    def bob_raw(self, B: int, rng=secrets) -> Dict[str, np.ndarray]:
+        return self._draw(self._bob_draws(), B, rng)
+
+    def alice_randoms_from(self, raw) -> Dict[str, jnp.ndarray]:
+        return self._limbs(self._alice_draws(), raw)
+
+    def bob_randoms_from(self, raw) -> Dict[str, jnp.ndarray]:
+        return self._limbs(self._bob_draws(), raw)
+
+    def alice_randoms(self, B: int, rng=secrets) -> Dict[str, jnp.ndarray]:
+        return self.alice_randoms_from(self.alice_raw(B, rng))
+
+    def bob_randoms(self, B: int, rng=secrets) -> Dict[str, jnp.ndarray]:
+        return self.bob_randoms_from(self.bob_raw(B, rng))
 
     # -- Alice: range proof for c_a = Enc_A(m; y^u) -------------------------
 
@@ -333,16 +441,14 @@ class MtaBatch:
         transcript {z, u, w} (c_a itself is per-party, passed separately).
         """
         A, Bo = self.alice, self.bob
-        z = Bo.commit_ring(
-            _bits_of(m_limbs, A.pmx.prof_n, self.dom.scalar),
-            _bits_of(R["rho"], self.p_rho, self.p_rho.n_limbs * 7),
-        )
+        z, w = Bo.commit_ring_many([
+            (_bits_of(m_limbs, A.pmx.prof_n, self.dom.scalar),
+             _bits_of(R["rho"], self.p_rho, self.p_rho.n_limbs * 7)),
+            (_bits_of(R["alpha"], self.p_alpha, self.dom.alpha),
+             _bits_of(R["gamma"], self.p_s2, self.p_s2.n_limbs * 7)),
+        ])
         u_c, _u_r = A.pmx.encrypt(
             bn.take_limbs(R["alpha"], 0, A.pmx.prof_n.n_limbs), R["u_enc"]
-        )
-        w = Bo.commit_ring(
-            _bits_of(R["alpha"], self.p_alpha, self.dom.alpha),
-            _bits_of(R["gamma"], self.p_s2, self.p_s2.n_limbs * 7),
         )
         return {"z": z, "u": u_c, "w": w}
 
@@ -378,8 +484,8 @@ class MtaBatch:
             bn.take_limbs(prod, 0, p_E.n_limbs)
             + bn.take_limbs(u_enc, 0, p_E.n_limbs)
         )
-        s = A.pmx.ctx_N.powmod_fixed_base(
-            A.pmx.y % A.N, _bits_of(E, p_E, p_E.n_limbs * 7)
+        s = A.pmx.ctx_N.powmod_named_base(
+            "y", _bits_of(E, p_E, p_E.n_limbs * 7)
         )
         m_e = bn.take_limbs(m_limbs, 0, self.p_e.n_limbs)
         e_l = self.e_limbs_from(e)
@@ -398,8 +504,9 @@ class MtaBatch:
             return self.e_limbs(e)
         return e
 
-    def bob_check_alice(self, c_a, T, P, e, rng=secrets) -> jnp.ndarray:
-        """Batched Alice-proof verification → (B,) bool."""
+    def _alice_ring_leg(self, T, P, e):
+        """Bounds and ring-Pedersen leg of the Alice proof →
+        ((B,) bool, the challenge's bits, s1 mod N)."""
         A, Bo = self.alice, self.bob
         e_l = self.e_limbs_from(e)
         q3 = jnp.broadcast_to(
@@ -410,13 +517,35 @@ class MtaBatch:
         s1_modN = A.pmx.ctx_N.reduce(
             bn.take_limbs(P["s1"], 0, min(P["s1"].shape[-1], 2 * A.pmx.prof_n.n_limbs))
         )
-        ok = ok & self._alice_enc_leg(c_a, T, P, e_bits, s1_modN, rng)
         lhs2 = Bo.commit_ring(
             _bits_of(P["s1"], self.p_s1, self.p_s1.n_limbs * 7),
             _bits_of(P["s2"], self.p_s2, self.p_s2.n_limbs * 7),
         )
         rhs2 = Bo.ctx_nt.mulmod(T["w"], Bo.ctx_nt.powmod(T["z"], e_bits))
-        return ok & _eq_all(lhs2, rhs2)
+        return ok & _eq_all(lhs2, rhs2), e_bits, s1_modN
+
+    def bob_check_alice_dev(self, c_a, T, P, e, rho_bits):
+        """The device part of the batched Alice-proof verification
+        (traceable): → ((B,) bool of the bounds and the ring leg, and
+        the three single values (E, S, R) of the combined ciphertext
+        equation E · S^N == R mod N², which :func:`agg_holds` settles)."""
+        ok, e_bits, s1_modN = self._alice_ring_leg(T, P, e)
+        return ok, self._alice_enc_agg(c_a, T, P, e_bits, s1_modN, rho_bits)
+
+    def bob_check_alice(self, c_a, T, P, e, rng=secrets) -> jnp.ndarray:
+        """Batched Alice-proof verification → (B,) bool."""
+        if BATCH_VERIFY != "rand":
+            return self.bob_check_alice_strict(c_a, T, P, e)
+        rho_bits = rand_bit_tensor(P["s1"].shape[0], RHO_BITS, rng)
+        ok, agg = self.bob_check_alice_dev(c_a, T, P, e, rho_bits)
+        if agg_holds(self.alice, agg):
+            return ok
+        log.warn("batched Alice-proof check failed — strict re-verification")
+        return self.bob_check_alice_strict(c_a, T, P, e)
+
+    def bob_check_alice_strict(self, c_a, T, P, e) -> jnp.ndarray:
+        ok, e_bits, s1_modN = self._alice_ring_leg(T, P, e)
+        return ok & self._alice_enc_leg_strict(c_a, T, P, e_bits, s1_modN)
 
     def _alice_enc_leg_strict(self, c_a, T, P, e_bits, s1_modN) -> jnp.ndarray:
         """Per-session ciphertext-leg check:
@@ -433,28 +562,21 @@ class MtaBatch:
         rhs = n2.mulmod(T["u"], n2.powmod(c_a, e_bits))
         return _eq_all(lhs, rhs)
 
-    def _alice_enc_leg(self, c_a, T, P, e_bits, s1_modN, rng) -> jnp.ndarray:
+    def _alice_enc_agg(self, c_a, T, P, e_bits, s1_modN, rho_bits):
         """Ciphertext leg of the Alice proof, batch-verified (module
         docstring at BATCH_VERIFY): Enc_det(Σρ·s1) · (Πs^ρ)^N ==
-        Π(u·c_a^e)^ρ. Strict per-session fallback attributes failures."""
-        if BATCH_VERIFY != "rand":
-            return self._alice_enc_leg_strict(c_a, T, P, e_bits, s1_modN)
+        Π(u·c_a^e)^ρ → the single values (Enc_det(Σρ·s1), Πs^ρ,
+        Π(u·c_a^e)^ρ)."""
         A = self.alice
         n2 = A.pmx.ctx_N2
-        B = s1_modN.shape[0]
-        rho_bits = rand_bit_tensor(B, RHO_BITS, rng)
         rhs = n2.mulmod(T["u"], n2.powmod(c_a, e_bits))
-        Rp = n2.prod_over_batch(n2.powmod(rhs, rho_bits))[None]
         s2 = bn.take_limbs(P["s"], 0, n2.prof.n_limbs)
-        Sp = n2.prod_over_batch(n2.powmod(s2, rho_bits))[None]
-        SN = _host_pow_single(Sp, A.N, n2)
+        rhs_rho, s_rho = n2.powmod_many([(rhs, rho_bits), (s2, rho_bits)])
+        Rp = n2.prod_over_batch(rhs_rho)[None]
+        Sp = n2.prod_over_batch(s_rho)[None]
         rho_l = _bits_pack(rho_bits, _prof7(RHO_BITS))
         tot = A.pmx.ctx_N.reduce(_fold_add(mm.mul_pair(rho_l, s1_modN)))
-        lhs = n2.mulmod(A.pmx.enc_deterministic(tot), SN)
-        if bool(np.asarray(_eq_all(lhs, Rp))[0]):  # mpcflow: host-ok — single aggregated proof verdict gates the strict fallback
-            return jnp.ones((B,), bool)
-        log.warn("batched Alice-proof check failed — strict re-verification")
-        return self._alice_enc_leg_strict(c_a, T, P, e_bits, s1_modN)
+        return A.pmx.enc_deterministic(tot), Sp, Rp
 
     # -- Bob: homomorphic response + proof ----------------------------------
 
@@ -462,36 +584,27 @@ class MtaBatch:
         """c_b = c_a^b · Enc_A(β′; y^u_bp); pre-challenge proof transcript.
         ``b_limbs``: Bob's secret (< q) in the 7-bit e-profile."""
         A = self.alice
+        n2 = A.pmx.ctx_N2
+        nl = A.pmx.prof_n.n_limbs
         b_bits = _bits_of(b_limbs, self.p_e, self.dom.scalar)
-        enc_bp, _r = A.pmx.encrypt(
-            bn.take_limbs(R["beta_prime"], 0, A.pmx.prof_n.n_limbs), R["u_bp"]
-        )
-        c_b = A.pmx.ctx_N2.mulmod(A.pmx.ctx_N2.powmod(c_a, b_bits), enc_bp)
-        z = A.commit_ring(
-            _bits_of(b_limbs, self.p_e, self.dom.scalar),
-            _bits_of(R["rho"], self.p_rho, self.p_rho.n_limbs * 7),
-        )
-        z_p = A.commit_ring(
-            _bits_of(R["alpha"], self.p_alpha, self.dom.alpha),
-            _bits_of(R["rho_p"], self.p_s2, self.p_s2.n_limbs * 7),
-        )
-        t = A.commit_ring(
-            _bits_of(R["beta_prime"], self.p_bp, self.dom.beta_prime),
-            _bits_of(R["sigma"], self.p_rho, self.p_rho.n_limbs * 7),
-        )
-        enc_g, _r2 = A.pmx.encrypt(
-            bn.take_limbs(R["gamma"], 0, A.pmx.prof_n.n_limbs), R["u_g"]
-        )
-        v = A.pmx.ctx_N2.mulmod(
-            A.pmx.ctx_N2.powmod(
-                c_a, _bits_of(R["alpha"], self.p_alpha, self.dom.alpha)
-            ),
-            enc_g,
-        )
-        w = A.commit_ring(
-            _bits_of(R["gamma"], self.p_gb, self.dom.gamma_bob),
-            _bits_of(R["tau"], self.p_s2, self.p_s2.n_limbs * 7),
-        )
+        alpha_bits = _bits_of(R["alpha"], self.p_alpha, self.dom.alpha)
+        enc_bp, enc_g = A.pmx.encrypt_many([
+            (bn.take_limbs(R["beta_prime"], 0, nl), R["u_bp"]),
+            (bn.take_limbs(R["gamma"], 0, nl), R["u_g"]),
+        ])
+        c_b, v = n2.mulmod_many(list(zip(
+            n2.powmod_many([(c_a, b_bits), (c_a, alpha_bits)]),
+            (enc_bp, enc_g),
+        )))
+        z, z_p, t, w = A.commit_ring_many([
+            (b_bits, _bits_of(R["rho"], self.p_rho, self.p_rho.n_limbs * 7)),
+            (alpha_bits,
+             _bits_of(R["rho_p"], self.p_s2, self.p_s2.n_limbs * 7)),
+            (_bits_of(R["beta_prime"], self.p_bp, self.dom.beta_prime),
+             _bits_of(R["sigma"], self.p_rho, self.p_rho.n_limbs * 7)),
+            (_bits_of(R["gamma"], self.p_gb, self.dom.gamma_bob),
+             _bits_of(R["tau"], self.p_s2, self.p_s2.n_limbs * 7)),
+        ])
         return {"c_b": c_b, "z": z, "z_p": z_p, "t": t, "v": v, "w": w}
 
     def bob_challenge(self, c_a, T, extra_rows: Sequence = ()) -> jnp.ndarray:
@@ -520,8 +633,8 @@ class MtaBatch:
             bn.take_limbs(prod, 0, p_E.n_limbs)
             + bn.take_limbs(u_g, 0, p_E.n_limbs)
         )
-        s = A.pmx.ctx_N.powmod_fixed_base(
-            A.pmx.y % A.N, _bits_of(E, p_E, p_E.n_limbs * 7)
+        s = A.pmx.ctx_N.powmod_named_base(
+            "y", _bits_of(E, p_E, p_E.n_limbs * 7)
         )
         s1 = _int_mul_add(
             e_l, bn.take_limbs(b_limbs, 0, self.p_e.n_limbs),
@@ -541,9 +654,10 @@ class MtaBatch:
         )
         return {"s": s, "s1": s1, "s2": s2, "t1": t1, "t2": t2}
 
-    def alice_check_bob(self, c_a, T, P, e, rng=secrets) -> jnp.ndarray:
-        """Batched Bob-proof verification (ciphertext + ring legs; the
-        with-check curve leg is checked by the caller)."""
+    def _bob_ring_legs(self, c_a, T, P, e):
+        """Bounds and ring-Pedersen legs of the Bob proof, and the two
+        sides of its ciphertext leg less s^N →
+        ((B,) bool, M = c_a^s1 · Enc_det(t1), v · c_b^e, s lifted)."""
         A = self.alice
         e_l = self.e_limbs_from(e)
         q3 = jnp.broadcast_to(
@@ -557,39 +671,56 @@ class MtaBatch:
         )
         ok = ok & (bn.compare(P["t1"], q7) <= 0)
         e_bits = _bits_of(e_l, self.p_e, self.dom.scalar)
-        lhs = A.commit_ring(
-            _bits_of(P["s1"], self.p_s1, self.p_s1.n_limbs * 7),
-            _bits_of(P["s2"], self.p_s2, self.p_s2.n_limbs * 7),
-        )
-        rhs = A.ctx_nt.mulmod(T["z_p"], A.ctx_nt.powmod(T["z"], e_bits))
-        ok = ok & _eq_all(lhs, rhs)
-        lhs = A.commit_ring(
-            _bits_of(P["t1"], self.p_t1, self.p_t1.n_limbs * 7),
-            _bits_of(P["t2"], self.p_s2, self.p_s2.n_limbs * 7),
-        )
-        rhs = A.ctx_nt.mulmod(T["w"], A.ctx_nt.powmod(T["t"], e_bits))
-        ok = ok & _eq_all(lhs, rhs)
+        s1_bits = _bits_of(P["s1"], self.p_s1, self.p_s1.n_limbs * 7)
+        nt = A.ctx_nt
+        lhs_s, lhs_t = A.commit_ring_many([
+            (s1_bits, _bits_of(P["s2"], self.p_s2, self.p_s2.n_limbs * 7)),
+            (_bits_of(P["t1"], self.p_t1, self.p_t1.n_limbs * 7),
+             _bits_of(P["t2"], self.p_s2, self.p_s2.n_limbs * 7)),
+        ])
+        rhs_s, rhs_t = nt.mulmod_many(list(zip(
+            (T["z_p"], T["w"]),
+            nt.powmod_many([(T["z"], e_bits), (T["t"], e_bits)]),
+        )))
+        ok = ok & _eq_all(lhs_s, rhs_s) & _eq_all(lhs_t, rhs_t)
         n2 = A.pmx.ctx_N2
         t1_modN = A.pmx.ctx_N.reduce(
             bn.take_limbs(P["t1"], 0, min(P["t1"].shape[-1], 2 * A.pmx.prof_n.n_limbs))
         )
         # ciphertext leg: c_a^s1 · Enc_det(t1) · s^N == v · c_b^e (mod N²)
-        M = n2.mulmod(
-            n2.powmod(c_a, _bits_of(P["s1"], self.p_s1, self.p_s1.n_limbs * 7)),
-            A.pmx.enc_deterministic(t1_modN),
+        M, rhs = n2.mulmod_many(list(zip(
+            n2.powmod_many([(c_a, s1_bits), (T["c_b"], e_bits)]),
+            (A.pmx.enc_deterministic(t1_modN), T["v"]),
+        )))
+        return ok, M, rhs, bn.take_limbs(P["s"], 0, n2.prof.n_limbs)
+
+    def alice_check_bob_dev(self, c_a, T, P, e, rho_bits):
+        """The device part of the batched Bob-proof verification
+        (traceable): → ((B,) bool of the bounds and the ring legs, and the
+        single values (ΠM^ρ, Πs^ρ, Π(v·c_b^e)^ρ) for :func:`agg_holds`)."""
+        ok, M, rhs, s_lift = self._bob_ring_legs(c_a, T, P, e)
+        n2 = self.alice.pmx.ctx_N2
+        Mp, Sp, Rp = (
+            n2.prod_over_batch(x)[None] for x in n2.powmod_many(
+                [(M, rho_bits), (s_lift, rho_bits), (rhs, rho_bits)])
         )
-        rhs = n2.mulmod(T["v"], n2.powmod(T["c_b"], e_bits))
-        s_lift = bn.take_limbs(P["s"], 0, n2.prof.n_limbs)
+        return ok, (Mp, Sp, Rp)
+
+    def alice_check_bob(self, c_a, T, P, e, rng=secrets) -> jnp.ndarray:
+        """Batched Bob-proof verification (ciphertext + ring legs; the
+        with-check curve leg is checked by the caller)."""
         if BATCH_VERIFY == "rand":
-            B = s_lift.shape[0]
-            rho_bits = rand_bit_tensor(B, RHO_BITS, rng)
-            Mp = n2.prod_over_batch(n2.powmod(M, rho_bits))[None]
-            Sp = n2.prod_over_batch(n2.powmod(s_lift, rho_bits))[None]
-            Rp = n2.prod_over_batch(n2.powmod(rhs, rho_bits))[None]
-            SN = _host_pow_single(Sp, A.N, n2)
-            if bool(np.asarray(_eq_all(n2.mulmod(Mp, SN), Rp))[0]):  # mpcflow: host-ok — single aggregated proof verdict gates the strict fallback
+            rho_bits = rand_bit_tensor(P["s1"].shape[0], RHO_BITS, rng)
+            ok, agg = self.alice_check_bob_dev(c_a, T, P, e, rho_bits)
+            if agg_holds(self.alice, agg):
                 return ok
             log.warn("batched Bob-proof check failed — strict re-verification")
+        return self.alice_check_bob_strict(c_a, T, P, e)
+
+    def alice_check_bob_strict(self, c_a, T, P, e) -> jnp.ndarray:
+        ok, M, rhs, s_lift = self._bob_ring_legs(c_a, T, P, e)
+        A = self.alice
+        n2 = A.pmx.ctx_N2
         lhs = n2.mulmod(M, _host_pow_batch(s_lift, A.N, n2))
         return ok & _eq_all(lhs, rhs)
 
@@ -658,12 +789,14 @@ def _mod_q_from_limbs(x: jnp.ndarray, prof: bn.LimbProfile) -> jnp.ndarray:
     if pad:
         b = jnp.pad(b, [(0, 0)] * (b.ndim - 1) + [(0, pad)])
     chunks = b.reshape(b.shape[:-1] + (n_chunks, chunk_bytes))
-    acc = ring.const(0, x.shape[:-1])
-    shift = pow(2, chunk_bytes * 8, Q)
-    shift_l = ring.const(shift, x.shape[:-1])
-    for i in range(n_chunks - 1, -1, -1):
-        c = bn.bytes_to_limbs_le(chunks[..., i, :], P256, P256.n_limbs)
-        acc = ring.addmod(ring.mulmod(acc, shift_l), ring.reduce(c))
+    shift_l = ring.const(pow(2, chunk_bytes * 8, Q), x.shape[:-1])
+    limbs = bn.bytes_to_limbs_le(chunks, P256, P256.n_limbs)
+
+    def horner(acc, c):  # one compiled step, the most significant chunk first
+        return ring.addmod(ring.mulmod(acc, shift_l), ring.reduce(c)), None
+
+    acc, _ = lax.scan(horner, ring.const(0, x.shape[:-1]),
+                      jnp.moveaxis(limbs, -2, 0), reverse=True)
     return acc
 
 
@@ -676,6 +809,33 @@ def _mod_q_from_limbs(x: jnp.ndarray, prof: bn.LimbProfile) -> jnp.ndarray:
 # rides an index-byte OPERAND, not per-party hash tags, so block HLO is
 # party-independent.)
 # ---------------------------------------------------------------------------
+
+
+def _cat_pts(pts):
+    return type(pts[0])(*(jnp.concatenate(c, axis=0) for c in zip(*pts)))
+
+
+def _split_pts(pt, n: int):
+    B = pt.X.shape[0] // n
+    return [type(pt)(*(c[i * B:(i + 1) * B] for c in pt)) for i in range(n)]
+
+
+def _scalar_mul_many(pairs):
+    """[(scalar limbs, points), ...] of one lane count → [k·P, ...] as ONE
+    ladder over all lanes (a ladder's time at these widths is its steps,
+    not its lanes)."""
+    bits = jnp.concatenate(
+        [bn.limbs_to_bits(k, P256, SCALAR_BITS) for k, _ in pairs], axis=0
+    )
+    out = sp.scalar_mul(bits, _cat_pts([p for _, p in pairs]))
+    return _split_pts(out, len(pairs))
+
+
+def _compress_many(*pts):
+    """Several point batches compressed with one field inversion ladder."""
+    n = pts[0].X.shape[0]
+    comp = sp.compress(_cat_pts(pts))
+    return [comp[i * n:(i + 1) * n] for i in range(len(pts))]
 
 
 def _idx_row(i: int, B: int) -> jnp.ndarray:
@@ -721,11 +881,11 @@ def _blk_R(delta, Gamma_sum):
     R_pt = sp.scalar_mul(
         bn.limbs_to_bits(delta_inv, P256, SCALAR_BITS), Gamma_sum
     )
-    Rx = sp.x_coordinate(R_pt)
+    F = secp256k1_field()
+    zi = F.inv(R_pt.Z)  # one inversion serves both affine coordinates
+    Rx = F.canonical(F.mul(R_pt.X, zi))
     r = ring.reduce(Rx)
     ok = ok & ~jnp.all(r == 0, axis=-1)
-    F = secp256k1_field()
-    zi = F.inv(R_pt.Z)
     y_aff = F.canonical(F.mul(R_pt.Y, zi))
     n_limbs_ = jnp.broadcast_to(jnp.asarray(bn.to_limbs(Q, P256)), Rx.shape)
     rec = (y_aff[..., 0] & 1) | jnp.where(bn.compare(Rx, n_limbs_) >= 0, 2, 0)
@@ -801,12 +961,10 @@ def _blk_pedersen_verify(Apok_comp, sa, sb, V_i: sp.SecpPointJ, R_pt, vc, ac, id
     ring = sp.scalar_ring()
     e32 = dev_hash(b"pedersen", idx, Apok_comp, vc, ac)
     e5 = ring.reduce(bn.bytes_to_limbs_le(e32, P256, 22))
+    saR, eV = _scalar_mul_many([(sa, R_pt), (e5, V_i)])
     lhs = sp.add(
-        sp.add(
-            sp.scalar_mul(bn.limbs_to_bits(sa, P256, SCALAR_BITS), R_pt),
-            sp.base_mul(bn.limbs_to_bits(sb, P256, SCALAR_BITS)),
-        ),
-        sp.scalar_mul(bn.limbs_to_bits(e5, P256, SCALAR_BITS), V_i),
+        sp.add(saR, sp.base_mul(bn.limbs_to_bits(sb, P256, SCALAR_BITS))),
+        eV,
     )
     return _eq_all(sp.compress(lhs), Apok_comp)
 
@@ -815,31 +973,6 @@ def _blk_pedersen_verify(Apok_comp, sa, sb, V_i: sp.SecpPointJ, R_pt, vc, ac, id
 def _blk_va_check(blind_i, vc, ac, idx, commit):
     """Phase-5B decommit check of a peer's (V_c, A_c) commitment."""
     return _eq_all(dev_hash(b"VA", idx, blind_i, vc, ac), commit)
-
-
-@functools.partial(jax.jit, static_argnums=(1,))
-def _blk_W_from_vss(C_comp, xj: int, lam_bits):
-    """W_j = λ_j · Σ_k x_j^k · C_k from aggregated VSS commitments.
-
-    ``C_comp``: (t+1, B, 33) compressed commitment points (wallet order),
-    ``xj``: the party's Shamir x (static small int), ``lam_bits``: (256,)
-    LSB-first bits of λ_j (shared across the batch; an operand so one
-    executable serves every quorum). Returns (W points, ok mask)."""
-    pts, ok_all = sp.decompress(C_comp)
-    ok = jnp.all(ok_all, axis=0)
-    t1 = C_comp.shape[0]
-    acc = sp.SecpPointJ(pts.X[t1 - 1], pts.Y[t1 - 1], pts.Z[t1 - 1])
-    nb = max(1, xj.bit_length())
-    xj_bits = jnp.asarray(sp.scalars_to_bits([xj], n_bits=nb)[0])
-    for k in range(t1 - 2, -1, -1):
-        acc = sp.scalar_mul(
-            jnp.broadcast_to(xj_bits, acc.X.shape[:-1] + (nb,)), acc
-        )
-        acc = sp.add(acc, sp.SecpPointJ(pts.X[k], pts.Y[k], pts.Z[k]))
-    W = sp.scalar_mul(
-        jnp.broadcast_to(lam_bits, acc.X.shape[:-1] + (SCALAR_BITS,)), acc
-    )
-    return W, ok
 
 
 @jax.jit
@@ -852,7 +985,7 @@ def _blk_va(m, r, k_i, sigma_i, l_i, rho_i, R_pt, blind_i, idx):
         sp.base_mul(bn.limbs_to_bits(l_i, P256, SCALAR_BITS)),
     )
     A_i = sp.base_mul(bn.limbs_to_bits(rho_i, P256, SCALAR_BITS))
-    vc, ac = sp.compress(V_i), sp.compress(A_i)
+    vc, ac = _compress_many(V_i, A_i)
     commit = dev_hash(b"VA", idx, blind_i, vc, ac)
     return s_i, V_i, A_i, vc, ac, commit
 
@@ -897,9 +1030,8 @@ def _blk_V(V_sum, m, r, Y):
 @jax.jit
 def _blk_ut(rho_i, l_i, V, A_sum, blind_i, idx):
     """Phase 5C per party: U_i = ρ_i·V, T_i = l_i·ΣA, commit."""
-    U_i = sp.scalar_mul(bn.limbs_to_bits(rho_i, P256, SCALAR_BITS), V)
-    T_i = sp.scalar_mul(bn.limbs_to_bits(l_i, P256, SCALAR_BITS), A_sum)
-    uc, tc = sp.compress(U_i), sp.compress(T_i)
+    U_i, T_i = _scalar_mul_many([(rho_i, V), (l_i, A_sum)])
+    uc, tc = _compress_many(U_i, T_i)
     commit = dev_hash(b"UT", idx, blind_i, uc, tc)
     return U_i, T_i, uc, tc, commit
 
@@ -938,6 +1070,399 @@ def _withcheck_curve(s1_q, e_q, U_pt, W_pt):
         sp.scalar_mul(bn.limbs_to_bits(e_q, P256, SCALAR_BITS), W_pt),
     )
     return sp.equal(lhs, rhs)
+
+
+# ---------------------------------------------------------------------------
+# ROUND PROGRAMS of the distributed party (protocol.ecdsa.batch_signing).
+#
+# One jitted program per protocol step: wire blocks come in and go out as
+# (B, n) uint8 arrays, party contexts (PartyCtx / MtaBatch) are pytree
+# ARGUMENTS, and everything between (parsing, bit unpacking, slicing,
+# hashing, the ladders and the modular exponentiations, serialization) is
+# inside the one program. A served wave therefore runs a fixed, small set
+# of programs per node and asks XLA for none after the warm batch; the
+# eager per-op dispatches the party used to make between kernels (a
+# compile request and a program run each) are gone. Peers' blocks of a
+# broadcast round arrive stacked on a leading axis and are verified as one
+# (q-1)·B-lane batch. The programs are named ``gg18_*``: a device trace
+# shows them as ``jit_gg18_*`` (benchmark/, PERF.md).
+# ---------------------------------------------------------------------------
+
+
+def wire_bytes(prof: bn.LimbProfile) -> int:
+    """Bytes of a limb tensor's wire block row."""
+    return -(-prof.n_limbs * prof.bits // 8)
+
+
+def _wire(x: jnp.ndarray, prof: bn.LimbProfile) -> jnp.ndarray:
+    return bn.limbs_to_bytes_le(x, prof, wire_bytes(prof))
+
+
+def _unwire(b: jnp.ndarray, prof: bn.LimbProfile) -> jnp.ndarray:
+    return bn.bytes_to_limbs_le(b, prof, prof.n_limbs)
+
+
+def _scalar_be(b: jnp.ndarray) -> jnp.ndarray:
+    """(…, 32) big-endian bytes → scalar limbs mod q."""
+    return sp.scalar_ring().reduce(
+        bn.bytes_to_limbs_le(jnp.flip(b, axis=-1), P256, 22)
+    )
+
+
+def _flat(x: jnp.ndarray) -> jnp.ndarray:
+    """(P, B, …) → (P·B, …): the peers' blocks as one batch."""
+    return x.reshape((x.shape[0] * x.shape[1],) + x.shape[2:])
+
+
+def _tile_pt(pt, n: int):
+    """A (B, …) point pytree repeated for ``n`` peers → (n·B, …)."""
+    return type(pt)(*(jnp.tile(leaf, (n,) + (1,) * (leaf.ndim - 1))
+                      for leaf in pt))
+
+
+def _sum_pts_many(terms, n: int):
+    """[(own, flat peers), ...] → [own + Σ over the n peers of a (n·B, …)
+    point batch, ...]: the sums side by side on the lane axis, the peers
+    one after another through ONE compiled addition."""
+    B = terms[0][0].X.shape[0]
+    own = _cat_pts([o for o, _ in terms])
+    peers = type(own)(*(
+        jnp.concatenate(
+            [c.reshape((n, B) + c.shape[1:]) for c in coords], axis=1)
+        for coords in zip(*(f for _, f in terms))
+    ))
+    acc, _ = lax.scan(lambda acc, p: (sp.add(acc, p), None), own, peers)
+    return _split_pts(acc, len(terms))
+
+
+def _sum_pts(own, flat_peers, n: int):
+    return _sum_pts_many([(own, flat_peers)], n)[0]
+
+
+@jax.jit
+def gg18_setup(pub_comp, C_comp, digests, x_bits, lam_bits):
+    """What a batch keeps on the device from its public inputs: the
+    wallets' keys Y, every quorum member's W_j = λ_j · Σ_k x_j^k · C_k
+    (all members as one q·B-lane ladder) with its compressed form, the
+    digests as scalars, and the lanes whose encodings were sound.
+
+    ``C_comp`` (t+1, B, 33) Feldman commitments, ``x_bits`` (q, 8) and
+    ``lam_bits`` (q, 256) the members' Shamir x and Lagrange coefficient,
+    LSB first: operands, so one executable serves every quorum."""
+    q, B = x_bits.shape[0], pub_comp.shape[0]
+    Y, ok = sp.decompress(pub_comp)
+    pts, ok_c = sp.decompress(C_comp)
+    ok = ok & jnp.all(ok_c, axis=0)
+    t1 = C_comp.shape[0]
+    xb = jnp.repeat(x_bits, B, axis=0)      # (q·B, 8)
+    lb = jnp.repeat(lam_bits, B, axis=0)    # (q·B, 256)
+
+    def coeff(k):
+        return _tile_pt(sp.SecpPointJ(pts.X[k], pts.Y[k], pts.Z[k]), q)
+
+    acc = coeff(t1 - 1)
+    for k in range(t1 - 2, -1, -1):
+        acc = sp.add(sp.scalar_mul(xb, acc), coeff(k))
+    W = sp.scalar_mul(lb, acc)
+    W_comp = sp.compress(W)
+    W_pts = tuple(
+        sp.SecpPointJ(*(leaf[i * B:(i + 1) * B] for leaf in W))
+        for i in range(q)
+    )
+    W_comps = tuple(W_comp[i * B:(i + 1) * B] for i in range(q))
+    return Y, W_pts, W_comps, ok, _scalar_be(digests)
+
+
+@jax.jit
+def gg18_r1_commit(own: PartyCtx, k_raw, gamma_raw, gblind, bind, u_bits):
+    """Round 1, own side: k, γ, the Γ commitment and c = Enc(k)."""
+    k = _scalar_from_wide_bytes(k_raw)
+    gamma = _scalar_from_wide_bytes(gamma_raw)
+    Gam, Gam_comp, commit = _blk_gamma(gamma, gblind, bind)
+    kp = _scalar_to_plain(own.pmx, k)
+    c_k, _r = own.pmx.encrypt(kp, u_bits)
+    return {
+        "k": k, "gamma": gamma, "Gamma": Gam, "Gamma_comp": Gam_comp,
+        "commit": commit, "kp": kp, "c_k": c_k,
+        "ck": _wire(c_k, own.pmx.prof_n2),
+    }
+
+
+@jax.jit
+def gg18_r1_prove(mta: MtaBatch, kp, c_k, u_bits, raw):
+    """Round 1, per peer: Alice's range proof of c in the peer's ring."""
+    A, Bo = mta.alice, mta.bob
+    Ra = mta.alice_randoms_from(raw)
+    T = mta.alice_init(kp, Ra)
+    e = mta.e_limbs(mta.alice_challenge(c_k, T))
+    P = mta.alice_finish(e, kp, Ra, u_bits)
+    nt = Bo.ctx_nt.prof
+    return {
+        "z": _wire(T["z"], nt), "u": _wire(T["u"], A.pmx.prof_n2),
+        "w": _wire(T["w"], nt), "s": _wire(P["s"], A.pmx.prof_n),
+        "s1": _wire(P["s1"], mta.p_s1), "s2": _wire(P["s2"], mta.p_s2),
+    }
+
+
+@jax.jit
+def gg18_r2_verify(mta: MtaBatch, ok, ck, pf, rho_bits):
+    """Round 2, per peer (Alice = the peer): its ciphertext and range
+    proof parsed and checked → (c_a, lanes still sound, the combined
+    ciphertext equation's three values, the parsed proof for the strict
+    fallback)."""
+    A, Bo = mta.alice, mta.bob
+    nt = Bo.ctx_nt.prof
+    c_a = _unwire(ck, A.pmx.prof_n2)
+    T = {"z": _unwire(pf["z"], nt), "u": _unwire(pf["u"], A.pmx.prof_n2),
+         "w": _unwire(pf["w"], nt)}
+    P = {"s": _unwire(pf["s"], A.pmx.prof_n),
+         "s1": _unwire(pf["s1"], mta.p_s1),
+         "s2": _unwire(pf["s2"], mta.p_s2)}
+    e = mta.e_limbs(mta.alice_challenge(c_a, T))
+    ok_i, agg = mta.bob_check_alice_dev(c_a, T, P, e, rho_bits)
+    return c_a, ok & ok_i, agg, (T, P, e)
+
+
+def _halves(tree, B: int):
+    """A 2·B-lane tree of arrays → (the γ leg's lanes, the w leg's)."""
+    return (jax.tree.map(lambda x: x[:B], tree),
+            jax.tree.map(lambda x: x[B:], tree))
+
+
+def _twice(x: jnp.ndarray) -> jnp.ndarray:
+    return jnp.concatenate([x, x], axis=0)
+
+
+@jax.jit
+def gg18_r2_respond(mta: MtaBatch, c_a, gamma, w, raw, X_comp):
+    """Round 2, per peer: Bob's homomorphic responses to the peer's
+    ciphertext for BOTH of his secrets, γ and w, with their proofs, as one
+    2·B-lane batch (lanes [0, B) the γ leg, [B, 2B) the w leg: the same
+    moduli, so one pass of every ladder serves both; ``raw`` is drawn for
+    2·B lanes). The legs differ in their challenge alone: the w leg's
+    binds U = α·G and the public W (MtA with check). → (wire blocks of
+    2·B rows, the w leg's U block, Bob's additive shares −β′ mod q of the
+    two legs)."""
+    A = mta.alice
+    B = c_a.shape[0]
+    c2 = _twice(c_a)
+    Rb = mta.bob_randoms_from(raw)
+    b_e = _scalar_to_prof(jnp.concatenate([gamma, w], axis=0), mta.p_e)
+    Tb = mta.bob_respond(c2, b_e, Rb)
+    T_g, T_w = _halves(Tb, B)
+    alpha_q = _mod_q_from_limbs(Rb["alpha"][B:], mta.p_alpha)
+    _U, U_comp = _base_mul_compressed(alpha_q)
+    e_b = mta.e_limbs(jnp.concatenate([
+        mta.bob_challenge(c_a, T_g),
+        mta.bob_challenge(c_a, T_w, (U_comp, X_comp)),
+    ], axis=0))
+    Pb = mta.bob_finish(e_b, b_e, Rb)
+    beta = sp.scalar_ring().negmod(
+        _mod_q_from_limbs(Rb["beta_prime"], mta.p_bp)
+    )
+    nt, n2 = A.ctx_nt.prof, A.pmx.prof_n2
+    blocks = {
+        "cb": _wire(Tb["c_b"], n2), "z": _wire(Tb["z"], nt),
+        "zp": _wire(Tb["z_p"], nt), "t": _wire(Tb["t"], nt),
+        "v": _wire(Tb["v"], n2), "w": _wire(Tb["w"], nt),
+        "s": _wire(Pb["s"], A.pmx.prof_n), "s1": _wire(Pb["s1"], mta.p_s1),
+        "s2": _wire(Pb["s2"], mta.p_s2), "t1": _wire(Pb["t1"], mta.p_t1),
+        "t2": _wire(Pb["t2"], mta.p_s2),
+    }
+    return blocks, U_comp, (beta[:B], beta[B:])
+
+
+@jax.jit
+def gg18_r3_verify(mta: MtaBatch, ok, c_k, rs, U_comp, rho_bits, W_peer,
+                   X_comp):
+    """Round 3, per peer (Alice = self): the peer's two responses (``rs``:
+    blocks of 2·B rows, γ leg then w leg) and their proofs parsed and
+    checked as one 2·B-lane batch, the curve binding of the w leg, and
+    Alice's shares Dec(c_b) mod q of both → (lanes still sound, the
+    combined equation's values, the (γ, w) shares, the parsed proofs for
+    the strict fallback)."""
+    A = mta.alice
+    B = c_k.shape[0]
+    c2 = _twice(c_k)
+    nt, n2 = A.ctx_nt.prof, A.pmx.prof_n2
+    Tb = {"c_b": _unwire(rs["cb"], n2), "z": _unwire(rs["z"], nt),
+          "z_p": _unwire(rs["zp"], nt), "t": _unwire(rs["t"], nt),
+          "v": _unwire(rs["v"], n2), "w": _unwire(rs["w"], nt)}
+    Pb = {"s": _unwire(rs["s"], A.pmx.prof_n),
+          "s1": _unwire(rs["s1"], mta.p_s1),
+          "s2": _unwire(rs["s2"], mta.p_s2),
+          "t1": _unwire(rs["t1"], mta.p_t1),
+          "t2": _unwire(rs["t2"], mta.p_s2)}
+    T_g, T_w = _halves(Tb, B)
+    U_pt, ok_u = sp.decompress(U_comp)
+    e_b = mta.e_limbs(jnp.concatenate([
+        mta.bob_challenge(c_k, T_g),
+        mta.bob_challenge(c_k, T_w, (U_comp, X_comp)),
+    ], axis=0))
+    ok2, agg = mta.alice_check_bob_dev(c2, Tb, Pb, e_b, rho_bits)
+    ok = ok & ok_u & ok2[:B] & ok2[B:] & _withcheck_curve(
+        _mod_q_from_limbs(Pb["s1"][B:], mta.p_s1),
+        _mod_q_from_limbs(e_b[B:], mta.p_e), U_pt, W_peer,
+    )
+    alpha = mta.alice_decrypt_share(Tb["c_b"])
+    return ok, agg, (alpha[:B], alpha[B:]), (c2, Tb, Pb, e_b)
+
+
+@jax.jit
+def gg18_r3_delta(k, gamma, w, alphas, betas):
+    """δ_i = k·γ + Σ(α+β) and σ_i = k·w + Σ(α+β) over the peers' legs
+    (``alphas`` / ``betas``: per peer a (γ leg, w leg) pair)."""
+    ring = sp.scalar_ring()
+    d = ring.mulmod(k, gamma)
+    s_ = ring.mulmod(k, w)
+    for (a_g, a_w), (b_g, b_w) in zip(alphas, betas):
+        d = ring.addmod(d, ring.addmod(a_g, b_g))
+        s_ = ring.addmod(s_, ring.addmod(a_w, b_w))
+    return d, s_, sp.pack_be_32(d)
+
+
+@jax.jit
+def gg18_r4_pok(kpok_raw, gamma, Gamma_comp, bind):
+    """Round 4: Schnorr PoK of γ → (A block, s block)."""
+    A_comp, s_pok = _blk_schnorr_prove(
+        _scalar_from_wide_bytes(kpok_raw), gamma, Gamma_comp, bind
+    )
+    return A_comp, sp.pack_be_32(s_pok)
+
+
+@jax.jit
+def gg18_r5a_verify(ok, delta_own, Gamma_own, peers):
+    """Phase 5A, the peers' side: their Γ decommitments and Schnorr PoKs
+    checked as one (q-1)·B-lane batch → (lanes still sound, Σδ, ΣΓ).
+    ``peers``: G, blind, gc, A, spok, d, bind, each (q-1, B, ·)."""
+    n = peers["G"].shape[0]
+    ring = sp.scalar_ring()
+    G_comp = _flat(peers["G"])
+    bind_p = _flat(peers["bind"])
+    G_pt, ok_g = sp.decompress(G_comp)
+    ok_p = ok_g & _blk_gamma_check(
+        _flat(peers["blind"]), G_comp, bind_p, _flat(peers["gc"])
+    )
+    ok_p = ok_p & _blk_schnorr_verify(
+        _flat(peers["A"]), _scalar_be(_flat(peers["spok"])), G_pt, G_comp,
+        bind_p,
+    )
+    delta = delta_own
+    d_peers = _scalar_be(peers["d"])
+    for i in range(n):
+        delta = ring.addmod(delta, d_peers[i])
+    return (ok & jnp.all(ok_p.reshape(n, -1), axis=0), delta,
+            _sum_pts(Gamma_own, G_pt, n))
+
+
+@jax.jit
+def gg18_r5a_commit(ok, delta, Gamma_sum, m, k, sigma, raw, va_blind, bind):
+    """Phase 5A, the own side: R = δ⁻¹·ΣΓ, then s_i, V_i, A_i and their
+    commitment (``raw``: the wide bytes of l_i, ρ_i and the PoK nonces)."""
+    ok_R, R_pt, r, rec = _blk_R(delta, Gamma_sum)
+    li, rho, ka, kb = (_scalar_from_wide_bytes(raw[x])
+                       for x in ("li", "rho", "ka", "kb"))
+    s_i, V_i, A_i, vc, ac, cmt = _blk_va(
+        m, r, k, sigma, li, rho, R_pt, va_blind, bind
+    )
+    return {
+        "ok": ok & ok_R, "R": R_pt, "r": r, "rec": rec, "li": li,
+        "rho": rho, "ka": ka, "kb": kb, "s": s_i, "V": V_i, "A": A_i,
+        "vc": vc, "ac": ac, "commit": cmt,
+    }
+
+
+@jax.jit
+def gg18_r5b(ka, kb, s_i, l_i, R_pt, vc, ac, bind):
+    """Phase 5B: PedersenPoK of (s_i, l_i) → (A block, sa, sb blocks)."""
+    Apok, sa, sb = _blk_pedersen_prove(ka, kb, s_i, l_i, R_pt, vc, ac, bind)
+    return Apok, sp.pack_be_32(sa), sp.pack_be_32(sb)
+
+
+def _decompress_pair(a_comp, b_comp):
+    """Two blocks of points decompressed as one batch (one square-root
+    ladder in the program, not two)."""
+    n = a_comp.shape[0]
+    pts, ok = sp.decompress(jnp.concatenate([a_comp, b_comp], axis=0))
+    first = type(pts)(*(leaf[:n] for leaf in pts))
+    second = type(pts)(*(leaf[n:] for leaf in pts))
+    return first, second, ok[:n] & ok[n:]
+
+
+@jax.jit
+def gg18_r5c_verify(ok, V_own, A_own, R_pt, peers):
+    """Phase 5C, the peers' side: their (V, A) decommitments and
+    PedersenPoKs checked as one batch → (lanes still sound, ΣV, ΣA).
+    ``peers``: vc, ac, blind, c, apok, sa, sb, bind, each (q-1, B, ·)."""
+    n = peers["vc"].shape[0]
+    vc, ac = _flat(peers["vc"]), _flat(peers["ac"])
+    bind_p = _flat(peers["bind"])
+    V_pt, A_pt, ok_p = _decompress_pair(vc, ac)
+    ok_p = ok_p & _blk_va_check(
+        _flat(peers["blind"]), vc, ac, bind_p, _flat(peers["c"])
+    )
+    ok_p = ok_p & _blk_pedersen_verify(
+        _flat(peers["apok"]), _scalar_be(_flat(peers["sa"])),
+        _scalar_be(_flat(peers["sb"])), V_pt, _tile_pt(R_pt, n), vc, ac,
+        bind_p,
+    )
+    V_sum, A_sum = _sum_pts_many([(V_own, V_pt), (A_own, A_pt)], n)
+    return ok & jnp.all(ok_p.reshape(n, -1), axis=0), V_sum, A_sum
+
+
+@jax.jit
+def gg18_r5c_commit(V_sum, A_sum, m, r, Y, rho, li, ut_blind, bind):
+    """Phase 5C, the own side: V = ΣV − m·G − r·Y, then U_i = ρ_i·V,
+    T_i = l_i·ΣA and their commitment."""
+    V = _blk_V(V_sum, m, r, Y)
+    U_i, T_i, uc, tc, cmt = _blk_ut(rho, li, V, A_sum, ut_blind, bind)
+    return {"U": U_i, "T": T_i, "uc": uc, "tc": tc, "commit": cmt}
+
+
+@jax.jit
+def gg18_r5e(ok, U_own, T_own, s_own, peers):
+    """Phase 5E: the peers' (U, T) decommitments checked as one batch,
+    ΣU == ΣT, and the own partial signature's block.
+    ``peers``: uc, tc, blind, c, bind, each (q-1, B, ·)."""
+    n = peers["uc"].shape[0]
+    uc, tc = _flat(peers["uc"]), _flat(peers["tc"])
+    U_pt, T_pt, ok_p = _decompress_pair(uc, tc)
+    ok_p = ok_p & _blk_ut_check(
+        _flat(peers["blind"]), uc, tc, _flat(peers["bind"]),
+        _flat(peers["c"]),
+    )
+    ok = ok & jnp.all(ok_p.reshape(n, -1), axis=0)
+    U_sum, T_sum = _sum_pts_many([(U_own, U_pt), (T_own, T_pt)], n)
+    ok = ok & sp.equal(U_sum, T_sum)
+    return ok, sp.pack_be_32(s_own)
+
+
+@jax.jit
+def gg18_final(ok, s_own, s_peers, m, r, rec, Y):
+    """Combine the partial signatures, normalise to low s and verify each
+    signature in-protocol → (r block, s block, recovery ids, ok)."""
+    ring = sp.scalar_ring()
+    s = s_own
+    s_p = _scalar_be(s_peers)
+    for i in range(s_peers.shape[0]):
+        s = ring.addmod(s, s_p[i])
+    ok_f, s, rec = _blk_final(s, m, r, Y, rec)
+    return sp.pack_be_32(r), sp.pack_be_32(s), rec, ok & ok_f
+
+
+# the programs a served GG18 wave runs, by the wire round whose handler
+# runs them (a device trace names them ``jit_<name>``)
+ROUND_PROGRAMS = {
+    0: ("gg18_setup",),
+    1: ("gg18_r1_commit", "gg18_r1_prove"),
+    2: ("gg18_r2_verify", "gg18_r2_respond"),
+    3: ("gg18_r3_verify", "gg18_r3_delta"),
+    4: ("gg18_r4_pok",),
+    5: ("gg18_r5a_verify", "gg18_r5a_commit"),
+    6: ("gg18_r5b",),
+    7: ("gg18_r5c_verify", "gg18_r5c_commit"),
+    9: ("gg18_r5e", "gg18_final"),
+}
 
 
 # ---------------------------------------------------------------------------
@@ -1592,3 +2117,9 @@ def dealer_keygen_secp_batch(
                 )
             )
     return out
+
+
+for _cls in (PartyCtx, MtaBatch):
+    jax.tree_util.register_pytree_node(
+        _cls, _cls._tree_flatten, _cls._tree_unflatten
+    )

@@ -183,8 +183,14 @@ def sha256_core(data: jnp.ndarray, msg_len: int) -> jnp.ndarray:
     words = bytes_to_words32(full)  # (..., 16·n_blocks)
     n_blocks = words.shape[-1] // 16
     state = jnp.broadcast_to(jnp.asarray(_H256), data.shape[:-1] + (8,))
-    for i in range(n_blocks):
-        state = sha256_compress(state, words[..., 16 * i : 16 * (i + 1)])
+    # the blocks through ONE compiled compression (a GG18 challenge
+    # hashes ~40 blocks a row: unrolled, that is 40 copies in a program)
+    blocks = jnp.moveaxis(
+        words.reshape(words.shape[:-1] + (n_blocks, 16)), -2, 0
+    )
+    state, _ = lax.scan(
+        lambda st, blk: (sha256_compress(st, blk), None), state, blocks
+    )
     return words32_to_bytes(state)
 
 

@@ -141,18 +141,24 @@ def _const_matrices(
 
 def ints_to_limbs(vals, prof: bn.LimbProfile) -> np.ndarray:
     """Bulk python-int → limb conversion via byte packing (numpy-speed;
-    bn.to_limbs is a per-limb python loop — too slow for comb tables)."""
+    bn.to_limbs is a per-limb python loop — too slow for comb tables).
+    A limb of up to 17 bits lies within three consecutive bytes: it is
+    read out of that 24-bit window (no per-bit temporaries: a comb table
+    is ~90k values of ~300 limbs)."""
+    assert prof.bits <= 17
     nbytes = -(-prof.bits * prof.n_limbs // 8)
     raw = np.frombuffer(
-        b"".join(int(v).to_bytes(nbytes, "little") for v in vals),
+        b"".join(int(v).to_bytes(nbytes + 2, "little") for v in vals),
         dtype=np.uint8,
-    ).reshape(len(vals), nbytes)
-    bits = np.unpackbits(raw, axis=-1, bitorder="little")[
-        :, : prof.bits * prof.n_limbs
-    ]
-    groups = bits.reshape(len(vals), prof.n_limbs, prof.bits)
-    weights = (1 << np.arange(prof.bits)).astype(np.int64)
-    return (groups * weights).sum(-1).astype(np.int32)
+    ).reshape(len(vals), nbytes + 2)
+    at = prof.bits * np.arange(prof.n_limbs)
+    byte, shift = at // 8, (at % 8).astype(np.uint32)
+    window = (
+        raw[:, byte].astype(np.uint32)
+        | raw[:, byte + 1].astype(np.uint32) << 8
+        | raw[:, byte + 2].astype(np.uint32) << 16
+    )
+    return ((window >> shift) & ((1 << prof.bits) - 1)).astype(np.int32)
 
 
 def mul_const(x: jnp.ndarray, T: jnp.ndarray) -> jnp.ndarray:
@@ -581,6 +587,14 @@ def _k_powmod_fb(tbl, ebits, T_mu, T_m, comp, occ: int, n: int):
 # ---------------------------------------------------------------------------
 
 
+def _exp_digits(exponent: int) -> jnp.ndarray:
+    """4-bit digits of a constant exponent, most significant first."""
+    nw = -(-exponent.bit_length() // 4)
+    return jnp.asarray(
+        [(exponent >> (4 * i)) & 15 for i in range(nw)][::-1], jnp.int32
+    )
+
+
 class MXUBarrett:
     """Barrett context for a fixed modulus with MXU-formulated primitives.
 
@@ -622,6 +636,50 @@ class MXUBarrett:
         )
         self.m_limbs = bn.to_limbs(modulus, self.prof)
         self._fb_tables: Dict = {}
+        # named operands (comb tables, constant Toeplitz matrices, digit
+        # arrays of constant exponents): what a jitted round program may
+        # ask this context for by NAME, because inside a trace the context
+        # is rebuilt from its arrays alone (see _tree_flatten) and holds
+        # no python integer of the key
+        self._named: Dict[str, jnp.ndarray] = {}
+
+    # -- pytree: a context is an ARGUMENT of the jitted round programs ------
+
+    def _tree_flatten(self):
+        """Arrays are the children; only widths and names are static, so
+        one compiled program serves every modulus of a width and no key
+        material lands in a compiled executable or the compile cache."""
+        names = tuple(sorted(self._named))
+        children = (self._T_mu, self._T_m, self._comp, self._m1,
+                    tuple(self._named[k] for k in names))
+        return children, (self.occ, self.prof.n_limbs, names)
+
+    @classmethod
+    def _tree_unflatten(cls, aux, children):
+        self = object.__new__(cls)
+        self.occ, n, names = aux
+        self.prof = bn.LimbProfile(bits=LIMB_BITS, n_limbs=n)
+        self.modulus = None  # not known inside a trace, by design
+        self._T_mu, self._T_m, self._comp, self._m1, named = children
+        self._named = dict(zip(names, named))
+        self._fb_tables = None
+        return self
+
+    def name_comb(self, name: str, base: int, n_bits: int) -> None:
+        """Keep the comb table of ``base`` for exponents of up to
+        ``n_bits`` under ``name`` (rebuilt only to grow)."""
+        nw = -(-n_bits // COMB_W)
+        have = self._named.get(name)
+        if have is None or have.shape[0] < nw:
+            self._named[name] = self._comb_table(base, nw)
+
+    def name_const(self, name: str, value: int) -> None:
+        if name not in self._named:
+            self._named[name] = self._const_T(value)
+
+    def name_exponent(self, name: str, exponent: int) -> None:
+        if name not in self._named:
+            self._named[name] = _exp_digits(exponent)
 
     # -- audit --------------------------------------------------------------
 
@@ -661,8 +719,7 @@ class MXUBarrett:
     def sqrmod(self, a: jnp.ndarray) -> jnp.ndarray:
         return self.mulmod(a, a)
 
-    def mulmod_const(self, a: jnp.ndarray, value: int) -> jnp.ndarray:
-        """a times a python-int constant (cached width-padded Toeplitz)."""
+    def _const_T(self, value: int) -> jnp.ndarray:
         key = ("constT", value % self.modulus)
         T = self._fb_tables.get(key)
         if T is None:
@@ -672,10 +729,20 @@ class MXUBarrett:
                 value % self.modulus, self.prof.n_limbs, min_limbs=self.occ
             )
             self._fb_tables[key] = T
-            _track_fb_table(
-                sum(int(t.nbytes) for t in jax.tree.leaves(T)),
-                "constT", self.modulus.bit_length(),
-            )
+            size = sum(int(t.nbytes) for t in jax.tree.leaves(T))  # mpcflow: declassified — a table's size in bytes is its shape, not its values
+            bits = self.modulus.bit_length()  # mpcflow: declassified — a modulus' width is public
+            _track_fb_table(size, "constT", bits)
+        return T
+
+    def mulmod_const(self, a: jnp.ndarray, value: int) -> jnp.ndarray:
+        """a times a python-int constant (cached width-padded Toeplitz)."""
+        return self._mulmod_T(a, self._const_T(value))
+
+    def mulmod_named(self, a: jnp.ndarray, name: str) -> jnp.ndarray:
+        """a times the constant kept under ``name`` (:meth:`name_const`)."""
+        return self._mulmod_T(a, self._named[name])
+
+    def _mulmod_T(self, a: jnp.ndarray, T: jnp.ndarray) -> jnp.ndarray:
         self._audit("mulmod_const", 0.5)
         return _k_mulmod_const(
             a, T, self._T_mu, self._T_m, self._comp, self.occ,
@@ -700,11 +767,16 @@ class MXUBarrett:
         a runtime operand: one compile per digit count, any value)."""
         if exponent == 0:
             return self.one_like(x)
-        nw = -(-exponent.bit_length() // 4)
+        return self._powmod_digits(x, _exp_digits(exponent))
+
+    def powmod_named_exp(self, x: jnp.ndarray, name: str) -> jnp.ndarray:
+        """x^e mod m for the exponent kept under ``name``
+        (:meth:`name_exponent`)."""
+        return self._powmod_digits(x, self._named[name])
+
+    def _powmod_digits(self, x: jnp.ndarray, digits) -> jnp.ndarray:
+        nw = digits.shape[0]
         self._audit(f"powmod_const_exp/e{4 * nw}", 5 * nw + 14)
-        digits = jnp.asarray(
-            [(exponent >> (4 * i)) & 15 for i in range(nw)][::-1], jnp.int32
-        )
         return _k_powmod_digits(
             x, digits, self._T_mu, self._T_m, self._comp, self.occ,
             self.prof.n_limbs,
@@ -731,10 +803,25 @@ class MXUBarrett:
         limb layout (300 windows x 256 rows x 320 limbs x 4 B),
         device-resident once per process; budget ~200 MB per
         counterparty NTilde (h1+h2) when sizing HBM."""
-        n_bits = ebits.shape[-1]
+        nw = -(-ebits.shape[-1] // COMB_W)
+        return self._powmod_comb(self._comb_table(base, nw), ebits)
+
+    def powmod_named_base(self, name: str, ebits: jnp.ndarray) -> jnp.ndarray:
+        """base^e mod m for the comb kept under ``name``
+        (:meth:`name_comb`): a comb's first windows are the comb of a
+        shorter exponent, a static slice of the operand."""
+        nw = -(-ebits.shape[-1] // COMB_W)
+        return self._powmod_comb(self._named[name][:nw], ebits)
+
+    def _powmod_comb(self, tbl: jnp.ndarray, ebits: jnp.ndarray):
+        self._audit(f"powmod_fixed_base/e{ebits.shape[-1]}", tbl.shape[0])
+        return _k_powmod_fb(
+            tbl, ebits, self._T_mu, self._T_m, self._comp, self.occ,
+            self.prof.n_limbs,
+        )
+
+    def _comb_table(self, base: int, nw: int) -> jnp.ndarray:
         wbits = COMB_W
-        nw = -(-n_bits // wbits)
-        self._audit(f"powmod_fixed_base/e{n_bits}", nw)
         key = (base % self.modulus, nw, wbits)
         tbl = self._fb_tables.get(key)
         if tbl is None:
@@ -756,13 +843,55 @@ class MXUBarrett:
                 )
             )
             self._fb_tables[key] = tbl
-            _track_fb_table(
-                int(tbl.nbytes), "comb", self.modulus.bit_length()
-            )
-        return _k_powmod_fb(
-            tbl, ebits, self._T_mu, self._T_m, self._comp, self.occ,
-            self.prof.n_limbs,
+            size = int(tbl.nbytes)  # mpcflow: host-ok — a size from the shape, no transfer
+            bits = self.modulus.bit_length()  # mpcflow: declassified — a modulus' width is public
+            _track_fb_table(size, "comb", bits)
+        return tbl
+
+    # -- several exponentiations as ONE ladder ------------------------------
+    #
+    # A ladder's step costs nearly the same for 32 lanes as for 256 (its
+    # launch, the constants' way into fast memory and the MXU's weight
+    # loads do not grow with the lanes), and a program runs its ladders
+    # one after another. So independent exponentiations in one modulus
+    # are stacked on the lane axis, their exponents zero-extended at the
+    # top to the widest (a leading zero window multiplies by one), and
+    # run as one ladder: the steps of the longest, not the sum of all.
+
+    @staticmethod
+    def _stack_bits(ebits_list):
+        width = max(e.shape[-1] for e in ebits_list)
+        return jnp.concatenate([
+            jnp.pad(e, ((0, 0), (0, width - e.shape[-1])))
+            for e in ebits_list
+        ], axis=0)
+
+    @staticmethod
+    def _unstack(x: jnp.ndarray, sizes) -> list:
+        out, at = [], 0
+        for k in sizes:
+            out.append(x[at:at + k])
+            at += k
+        return out
+
+    def powmod_many(self, pairs) -> list:
+        """[(x, ebits), ...] → [x^e, ...]: one ladder over all lanes."""
+        xs = jnp.concatenate([x for x, _ in pairs], axis=0)
+        out = self.powmod(xs, self._stack_bits([e for _, e in pairs]))
+        return self._unstack(out, [x.shape[0] for x, _ in pairs])
+
+    def powmod_named_base_many(self, name: str, ebits_list) -> list:
+        """[ebits, ...] → [base^e, ...]: one comb pass over all lanes."""
+        out = self.powmod_named_base(name, self._stack_bits(ebits_list))
+        return self._unstack(out, [e.shape[0] for e in ebits_list])
+
+    def mulmod_many(self, pairs) -> list:
+        """[(a, b), ...] → [a·b, ...]: one product over all lanes."""
+        out = self.mulmod(
+            jnp.concatenate([a for a, _ in pairs], axis=0),
+            jnp.concatenate([b for _, b in pairs], axis=0),
         )
+        return self._unstack(out, [a.shape[0] for a, _ in pairs])
 
     def invmod_prime(self, x: jnp.ndarray) -> jnp.ndarray:
         return self.powmod_const_exp(x, self.modulus - 2)
@@ -780,3 +909,8 @@ class MXUBarrett:
                 k += 1
             x = self.mulmod(x[: k // 2], x[k // 2:])
         return x[0]
+
+
+jax.tree_util.register_pytree_node(
+    MXUBarrett, MXUBarrett._tree_flatten, MXUBarrett._tree_unflatten
+)

@@ -35,6 +35,7 @@ import os
 import secrets
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -61,6 +62,26 @@ class PaillierMXU:
         self.y = y if y is not None else (rng.randbelow(pk.N - 2) + 2)
         self.h = pow(self.y, pk.N, pk.N2)
         self._N_T = mm._const_matrices(pk.N, self.prof_n.n_limbs)
+        # the two fixed bases, by name (ops.modmul: named operands)
+        self.ctx_N2.name_comb("h", self.h, RAND_BITS)
+        # y also carries the range proofs' randomizer leg, whose exponent
+        # u·e + u' is 2·RAND_BITS + 8 bits in whole 7-bit limbs
+        self.ctx_N.name_comb(
+            "y", self.y % pk.N, -(-(2 * RAND_BITS + 8) // 7) * 7
+        )
+
+    # -- pytree: an argument of the jitted GG18 round programs --------------
+
+    def _tree_flatten(self):
+        return (self.ctx_N, self.ctx_N2, self._N_T), ()
+
+    @classmethod
+    def _tree_unflatten(cls, _aux, children):
+        self = object.__new__(cls)
+        self.ctx_N, self.ctx_N2, self._N_T = children
+        self.prof_n = self.ctx_N.prof
+        self.prof_n2 = self.ctx_N2.prof
+        return self
 
     # -- host <-> device ----------------------------------------------------
 
@@ -94,10 +115,23 @@ class PaillierMXU:
         the effective Paillier randomizer (c == (1+mN)·r^N), which the MtA
         range proofs consume.
         """
-        hu = self.ctx_N2.powmod_fixed_base(self.h, u_bits)
+        hu = self.ctx_N2.powmod_named_base("h", u_bits)
         c = self.ctx_N2.mulmod(self.enc_deterministic(m_limbs), hu)
-        r = self.ctx_N.powmod_fixed_base(self.y % self.pk.N, u_bits)
+        r = self.ctx_N.powmod_named_base("y", u_bits)
         return c, r
+
+    def encrypt_many(self, pairs) -> list:
+        """[(m_limbs, u_bits), ...] → [c, ...]: the encryptions of several
+        plaintext batches under this key as one pass (one comb of h over
+        all lanes; the randomizers' values are not computed)."""
+        sizes = [m.shape[0] for m, _ in pairs]
+        hu = self.ctx_N2.powmod_named_base(
+            "h", jnp.concatenate([u for _, u in pairs], axis=0)
+        )
+        det = self.enc_deterministic(
+            jnp.concatenate([m for m, _ in pairs], axis=0)
+        )
+        return self.ctx_N2._unstack(self.ctx_N2.mulmod(det, hu), sizes)
 
     def add(self, c1: jnp.ndarray, c2: jnp.ndarray) -> jnp.ndarray:
         return self.ctx_N2.mulmod(c1, c2)
@@ -135,10 +169,29 @@ class PaillierMXUPrivate(PaillierMXU):
         # CRT combine: m = m_p + p·((m_q - m_p)·p^-1 mod q)
         self.p_inv_mod_q = pow(p, -1, q)
         self._p_T_wide = mm._const_matrices(p, self.ctx_q.prof.n_limbs)
+        # the key's constants as named operands: a jitted program gets
+        # them as arguments, never as constants of its executable
+        self.ctx_p2.name_exponent("r-1", p - 1)
+        self.ctx_q2.name_exponent("r-1", q - 1)
+        self.ctx_p.name_const("h_r", self.h_p)
+        self.ctx_q.name_const("h_r", self.h_q)
+        self.ctx_q.name_const("p_inv", self.p_inv_mod_q)
 
-    def _half_decrypt(self, c, ctx2, ctx1, r: int, hr: int, inv_T) -> jnp.ndarray:
+    def _tree_flatten(self):
+        pub, _ = super()._tree_flatten()
+        return pub + (self.ctx_p2, self.ctx_q2, self.ctx_p, self.ctx_q,
+                      self._pinv_T, self._qinv_T, self._p_T_wide), ()
+
+    @classmethod
+    def _tree_unflatten(cls, _aux, children):
+        self = super()._tree_unflatten((), children[:3])
+        (self.ctx_p2, self.ctx_q2, self.ctx_p, self.ctx_q, self._pinv_T,
+         self._qinv_T, self._p_T_wide) = children[3:]
+        return self
+
+    def _half_decrypt(self, c, ctx2, ctx1, inv_T) -> jnp.ndarray:
         """m_r = L_r(c^(r-1) mod r²)·h_r mod r → limbs in ctx1's profile."""
-        u = ctx2.powmod_const_exp(ctx2.reduce(c), r - 1)
+        u = ctx2.powmod_named_exp(ctx2.reduce(c), "r-1")
         # u - 1 via the complement trick (u-1 may have long borrow chains,
         # which the fast lookahead carry does not handle): u + (R^k - 1)
         # mod R^k == u - 1 for u ≥ 1.
@@ -146,28 +199,28 @@ class PaillierMXUPrivate(PaillierMXU):
         u_minus = mm.carry(bn.pad_limbs(u + mm.MASK, 1))[..., :k]
         L = mm.carry(mm.mul_const(u_minus, inv_T))[..., :k]
         # exact division: L = (u-1)/r < r — fits the mod-r context
-        return ctx1.mulmod_const(bn.take_limbs(L, 0, ctx1.prof.n_limbs), hr)
+        return ctx1.mulmod_named(
+            bn.take_limbs(L, 0, ctx1.prof.n_limbs), "h_r"
+        )
 
     def decrypt(self, c: jnp.ndarray) -> jnp.ndarray:
         """Batched CRT decrypt → plaintext limbs mod N (prof_n)."""
-        sk = self.sk
-        p, q = sk.p, sk.q
-        m_p = self._half_decrypt(
-            c, self.ctx_p2, self.ctx_p, p, self.h_p, self._pinv_T
-        )
-        m_q = self._half_decrypt(
-            c, self.ctx_q2, self.ctx_q, q, self.h_q, self._qinv_T
-        )
+        m_p = self._half_decrypt(c, self.ctx_p2, self.ctx_p, self._pinv_T)
+        m_q = self._half_decrypt(c, self.ctx_q2, self.ctx_q, self._qinv_T)
         # t = (m_q - m_p) · p^-1 mod q
         nq = self.ctx_q.prof.n_limbs
         mq_q = self.ctx_q.reduce(bn.take_limbs(m_q, 0, nq))
         mp_q = self.ctx_q.reduce(bn.take_limbs(m_p, 0, nq))
-        t = self.ctx_q.mulmod_const(
-            self.ctx_q.submod(mq_q, mp_q), self.p_inv_mod_q
-        )
+        t = self.ctx_q.mulmod_named(self.ctx_q.submod(mq_q, mp_q), "p_inv")
         # m = m_p + p·t  (< p·q = N; exact, no modular reduction needed)
         pt = mm.carry(mm.mul_const(t, self._p_T_wide))
         n = self.prof_n.n_limbs
         return mm.carry(
             bn.take_limbs(pt, 0, n) + bn.take_limbs(m_p, 0, n)
         )
+
+
+for _cls in (PaillierMXU, PaillierMXUPrivate):
+    jax.tree_util.register_pytree_node(
+        _cls, _cls._tree_flatten, _cls._tree_unflatten
+    )

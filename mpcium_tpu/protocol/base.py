@@ -355,15 +355,14 @@ class BatchBlockMixin:
     B: int
 
     def _bind_row(self, pid: str):
+        """(B, 32) uint8, on the host: an argument of the jitted programs
+        that hash it (no device operation of its own)."""
         import hashlib
 
-        import jax.numpy as jnp
         import numpy as np
 
         h = hashlib.sha256(f"{self.session_id}:{pid}".encode()).digest()
-        return jnp.broadcast_to(
-            jnp.asarray(np.frombuffer(h, dtype=np.uint8)), (self.B, 32)
-        )
+        return np.tile(np.frombuffer(h, dtype=np.uint8), (self.B, 1))
 
     def _parse_block(self, hexstr: str, nbytes: int, pid: str):
         import numpy as np

@@ -35,25 +35,36 @@ All wallets in a batch must share (participants, threshold, epoch) AND
 the quorum's Paillier/ring-Pedersen material (see
 :func:`quorum_material_digest` — the scheduler buckets on it): the engine
 builds one modulus context per party.
+
+Device work: every handler runs the engine's jitted ROUND PROGRAMS
+(``gb.gg18_*``) and nothing else on the device. Wire blocks are parsed
+into numpy byte arrays on the host and handed to a program whole; a
+program returns the next blocks as byte arrays. No ``jnp`` operation
+runs outside a program, so after a batch of a shape has run once a
+served batch asks XLA for nothing. The modulus contexts of a committee
+(Toeplitz constants, comb tables: ~0.6 GB on the device a node) are kept
+across batches in the :class:`ContextCache` the node's scheduler owns.
 """
 from __future__ import annotations
 
 import hashlib
-from typing import Dict, List, Optional, Sequence, Tuple
+import threading
+import time
+from collections import OrderedDict
+from typing import Dict, List, Optional, Sequence
 
-import jax.numpy as jnp
+import jax
 import numpy as np
 
 from ... import wire
 from ...core import bignum as bn
 from ...core import hostmath as hm
 from ...core import secp256k1_jax as sp
-from ...core.bignum import P256
 from ...core.paillier import PaillierPrivateKey, PreParams
 from ...engine import gg18_batch as gb
-from ...engine import pipeline as pl
 from ...ops.paillier_mxu import RAND_BITS
 from ...perf import compile_watch
+from ...utils import log, tracing
 from ..base import (BatchBlockMixin, KeygenShare, PartyBase, ProtocolError,
                     RoundMsg, party_xs)
 
@@ -69,6 +80,24 @@ R6 = "gg18/b/6/va-reveal"
 R7 = "gg18/b/7/ut-commit"
 R8 = "gg18/b/8/ut-reveal"
 R9 = "gg18/b/9/partial"
+
+# The device phases of one signer's batch, in protocol order: handler ->
+# the ``phase:gg18_<name>`` span around its device work (utils/tracing;
+# child of the ``round:`` span of the message that completed the round).
+# The benchmark's readers and OBSERVABILITY.md take the names from here.
+PHASES = {
+    "start": "r1_commit_prove",
+    "_respond": "r2_mta_respond",
+    "_delta": "r3_verify_decrypt",
+    "_decommit_gamma": "r4_pok",
+    "_phase5a": "r5a_R_va_commit",
+    "_phase5b": "r5b_pedersen_pok",
+    "_phase5c": "r5c_verify_ut_commit",
+    "_phase5d": "r5d_reveal",
+    "_partial": "r5e_check_partial",
+    "_finalize": "combine_verify",
+}
+PHASE_SPANS = tuple(f"phase:gg18_{name}" for name in PHASES.values())
 
 
 def quorum_material_digest(share: KeygenShare) -> str:
@@ -103,16 +132,89 @@ def share_owner_key(share: KeygenShare) -> str:
     raise ProtocolError("share self_x not in participant universe")
 
 
-def _nb(prof: bn.LimbProfile) -> int:
-    return -(-prof.n_limbs * prof.bits // 8)
+def _hex(arr) -> str:
+    """A device or host byte block as the wire's hex string."""
+    return np.asarray(arr).tobytes().hex()  # mpcflow: host-ok — wire serialization
 
 
-def _ser(x: jnp.ndarray, prof: bn.LimbProfile) -> str:
-    return np.asarray(bn.limbs_to_bytes_le(x, prof, _nb(prof))).tobytes().hex()  # mpcflow: host-ok — wire serialization
+class _Rows:
+    """Rows [lo, hi) of a device block, cut on the host when the block is
+    read for the wire (no device operation)."""
+
+    def __init__(self, block, lo: int, hi: int):
+        self.block, self.lo, self.hi = block, lo, hi
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.block)[self.lo:self.hi]  # mpcflow: host-ok — wire serialization
 
 
-def _ser_bytes(arr) -> str:
-    return np.asarray(arr).tobytes().hex()
+def _both(mask, B: int):
+    """A 2·B-lane verdict (γ leg, then w leg) → the B sessions'."""
+    mask = np.asarray(mask)  # mpcflow: host-ok — strict-fallback verdicts gate the lanes on host
+    return mask[:B] & mask[B:]
+
+
+def _span_sync(tensors) -> None:
+    """Materialize a phase's device results before its span closes so the
+    interval is honest device time — only when tracing is armed (untraced
+    runs never sync here; engine PhaseTimer discipline)."""
+    if tracing.enabled():
+        jax.block_until_ready(tensors)  # mpcflow: host-ok — trace instrumentation, only when tracing is armed
+
+
+class ContextCache:
+    """The modulus contexts of the committees ONE node signs for, kept
+    across its batches: a context's constants and comb tables take seconds
+    of host work to build and ~200 MB of device memory a ring, and every
+    batch of a committee uses the same ones. The node's batch scheduler
+    owns one and hands it to each party it builds; a party handed none
+    builds its contexts for its one batch, as before.
+
+    What stays resident, and for how long (SECURITY.md, "Key material at
+    rest and in memory"): the node's own private context holds the digits
+    of p−1 and q−1, h_p, h_q and p⁻¹ mod q as device arrays, and the
+    randomizer base y drawn when the context was built (fixed "at key
+    load": ops/paillier_mxu). An entry is keyed by the party it is of, a
+    digest of the committee's Paillier and ring-Pedersen material, the key
+    epoch, and whether it holds the private key, so a reshare (a new epoch)
+    or a re-keyed committee never meets an old entry. Entries leave when
+    least recently used past ``CAP``, when older than ``MAX_AGE_S``, and
+    all of them on ``clear`` (the scheduler's ``close``). A context is
+    complete when it is published (its named combs at the committee's
+    widths are built inside ``build``) and is not written afterwards."""
+
+    CAP = 16          # contexts; a 2-of-3 committee takes three a node
+    MAX_AGE_S = 3600  # a context older than this is built anew
+
+    def __init__(self, clock=time.monotonic) -> None:
+        self._lock = threading.Lock()
+        self._clock = clock
+        self._have: "OrderedDict[tuple, tuple]" = OrderedDict()
+
+    def get(self, key: tuple, build) -> gb.PartyCtx:
+        now = self._clock()
+        with self._lock:
+            held = self._have.get(key)
+            if held is not None and now - held[1] <= self.MAX_AGE_S:
+                self._have.move_to_end(key)
+                return held[0]
+        ctx = build()  # outside the lock: seconds of host work
+        with self._lock:
+            held = self._have.get(key)
+            if held is None or now - held[1] > self.MAX_AGE_S:
+                held = self._have[key] = (ctx, now)
+            self._have.move_to_end(key)
+            while len(self._have) > self.CAP:
+                self._have.popitem(last=False)
+        return held[0]
+
+    def clear(self) -> None:
+        with self._lock:
+            self._have.clear()
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._have)
 
 
 class BatchedECDSASigningParty(BatchBlockMixin, PartyBase):
@@ -121,7 +223,14 @@ class BatchedECDSASigningParty(BatchBlockMixin, PartyBase):
     ``shares``: this node's per-wallet key shares (manifest order —
     identical on every quorum member). ``digests``: the B 32-byte
     transaction digests. All shares must come from one committee
-    generation (same participants/threshold/epoch/aux material)."""
+    generation (same participants/threshold/epoch/aux material).
+    ``cohorts`` is accepted and not used (the scheduler gives both key
+    types' parties one, and the benchmark's older scheme file under
+    ``tests/benchmark/`` passes it): a GG18 batch runs whole (see
+    ``_finalize``). ``metrics``: the node's registry (histogram
+    ``party.ecdsa.phase_s``, counter ``party.ecdsa.mta_responses_total``);
+    none, none kept. ``contexts``: the node's :class:`ContextCache`; none,
+    the contexts are built for this batch and go with it."""
 
     def __init__(
         self,
@@ -133,6 +242,8 @@ class BatchedECDSASigningParty(BatchBlockMixin, PartyBase):
         dom: gb.Domains = gb.Domains(),
         rng=None,
         cohorts: Optional[int] = None,
+        metrics=None,
+        contexts: Optional[ContextCache] = None,
     ):
         import secrets as _secrets
 
@@ -165,27 +276,58 @@ class BatchedECDSASigningParty(BatchBlockMixin, PartyBase):
         for pid in self.party_ids:
             if pid not in u_xs:
                 raise ProtocolError("signer not in keygen universe", pid)
+        if max(u_xs.values()) >= 256:
+            raise ProtocolError("Shamir x beyond the setup program's 8 bits")
 
         aux = first.aux
-        sk = PaillierPrivateKey.from_json(aux["paillier_sk"])
-        rp = {k: int(v) for k, v in aux["preparams"].items()}
-        own_pre = PreParams(
-            paillier=sk, NTilde=rp["ntilde"], h1=rp["h1"], h2=rp["h2"],
-            alpha=0, beta=0, P=0, Q=0,
-        )
-        self.own = gb.PartyCtx(self_id, own_pre, rng=self.rng)
-        self.peers: Dict[str, gb.PartyCtx] = {}
         peer_pk = aux.get("peer_paillier", {})
         peer_rp = aux.get("peer_ring_pedersen", {})
         for pid in self.others():
             if pid not in peer_pk or pid not in peer_rp:
                 raise ProtocolError("missing peer Paillier material", pid)
-            prp = {k: int(v) for k, v in peer_rp[pid].items()}
-            self.peers[pid] = gb.PartyCtx.public(
-                pid, int(peer_pk[pid]), prp["ntilde"], prp["h1"], prp["h2"],
-                rng=self.rng,
+
+        # the widths of the ring-Pedersen combs, one pair for the whole
+        # committee (every NTilde's width is public), so that a context
+        # is complete when it is built and no MtaBatch has to grow it
+        nt_bits = max(
+            [int(aux["preparams"]["ntilde"]).bit_length()]
+            + [int(peer_rp[p]["ntilde"]).bit_length() for p in self.others()]
+        )
+        comb_bits = gb.MtaBatch.ring_comb_bits(dom, nt_bits)
+
+        # a context draws its randomizer base from the system's CSPRNG,
+        # not from this party's rng: it may outlive the party
+        def own_ctx() -> gb.PartyCtx:
+            sk = PaillierPrivateKey.from_json(aux["paillier_sk"])
+            rp = {k: int(v) for k, v in aux["preparams"].items()}
+            pre = PreParams(
+                paillier=sk, NTilde=rp["ntilde"], h1=rp["h1"], h2=rp["h2"],
+                alpha=0, beta=0, P=0, Q=0,
             )
-        self._ctx = {self_id: self.own, **self.peers}
+            ctx = gb.PartyCtx(self_id, pre)
+            ctx.name_ring_combs(*comb_bits)
+            return ctx
+
+        def peer_ctx(pid: str):
+            def build() -> gb.PartyCtx:
+                prp = {k: int(v) for k, v in peer_rp[pid].items()}
+                ctx = gb.PartyCtx.public(
+                    pid, int(peer_pk[pid]), prp["ntilde"], prp["h1"],
+                    prp["h2"],
+                )
+                ctx.name_ring_combs(*comb_bits)
+                return ctx
+            return build
+
+        if contexts is None:
+            contexts = ContextCache()  # this batch's own
+        self.own = contexts.get(
+            ("private", self_id, digest0, first.epoch), own_ctx)
+        self.peers: Dict[str, gb.PartyCtx] = {
+            pid: contexts.get(
+                ("public", pid, digest0, first.epoch), peer_ctx(pid))
+            for pid in self.others()
+        }
         # ordered-pair MtA contexts: out = self as Alice, in = self as Bob
         self.mta_out = {
             j: gb.MtaBatch(self.own, self.peers[j], dom)
@@ -198,85 +340,87 @@ class BatchedECDSASigningParty(BatchBlockMixin, PartyBase):
 
         # quorum Shamir data (shared across the batch: one universe)
         quorum_xs = [u_xs[p] for p in self.party_ids]
-        self._lam = {
+        lam = {
             pid: hm.lagrange_coeff(quorum_xs, u_xs[pid], Q)
             for pid in self.party_ids
         }
-        self._uxs = u_xs
-        w_ints = [self._lam[self_id] * s.share % Q for s in shares]
-        self._w = jnp.asarray(bn.batch_to_limbs(w_ints, P256))
-
-        # public per-wallet data on device: Y and every member's W_j
-        pub_comp = jnp.asarray(
+        self._w = bn.batch_to_limbs(
+            [lam[self_id] * s.share % Q for s in shares], bn.P256
+        )
+        digs = np.stack([
+            np.frombuffer(bytes(d), dtype=np.uint8) for d in digests
+        ])
+        if digs.shape[-1] != 32:
+            raise ProtocolError("digests must be 32 bytes")
+        # public per-wallet data on device: Y, every member's W_j (and
+        # its compressed form), the digests as scalars
+        self.Y, W_pts, W_comps, self._ok, self.m = gb.gg18_setup(
             np.stack([
                 np.frombuffer(s.public_key, dtype=np.uint8) for s in shares
-            ])
-        )
-        self.Y, okY = sp.decompress(pub_comp)
-        C_comp = jnp.asarray(
+            ]),
             np.stack([
                 np.stack([
                     np.frombuffer(c, dtype=np.uint8)
                     for c in s.vss_commitments
                 ])
                 for s in shares
-            ]).transpose(1, 0, 2)  # (t+1, B, 33)
+            ]).transpose(1, 0, 2),  # (t+1, B, 33)
+            digs,
+            sp.scalars_to_bits([u_xs[p] for p in self.party_ids], n_bits=8),
+            sp.scalars_to_bits([lam[p] for p in self.party_ids]),
         )
-        self.W_pts: Dict[str, sp.SecpPointJ] = {}
-        self._ok = okY
-        for pid in self.party_ids:
-            lam_bits = jnp.asarray(
-                sp.scalars_to_bits([self._lam[pid]])[0]
-            )
-            # mpclint: disable=MPS902 — intentional: q executables total (one per quorum member's Shamir x, config-bounded); lam_bits stays traced so the batch dim shares one compile
-            W, okW = gb._blk_W_from_vss(C_comp, u_xs[pid], lam_bits)
-            self.W_pts[pid] = W
-            self._ok = self._ok & okW
-
-        self.ring = sp.scalar_ring()
-        digs = np.stack([
-            np.frombuffer(bytes(d), dtype=np.uint8) for d in digests
-        ])
-        if digs.shape[-1] != 32:
-            raise ProtocolError("digests must be 32 bytes")
-        self.m = self.ring.reduce(
-            bn.bytes_to_limbs_le(jnp.asarray(digs[:, ::-1].copy()), P256, 22)
-        )
-        # counter-phase cohort geometry for the finalize round (the nine
-        # wire rounds stay full-batch: their proofs/rng draws are ordered
-        # per peer, and the wire transcript must not depend on K)
-        self._plan = pl.CohortPlan.for_batch(self.B, cohorts)
+        self.W_pts = dict(zip(self.party_ids, W_pts))
+        self._W_comp = dict(zip(self.party_ids, W_comps))
+        self._binds = {pid: self._bind_row(pid) for pid in self.party_ids}
+        self._m_phase = self._m_mta = None
+        if metrics is not None:
+            self._m_phase = metrics.histogram("party.ecdsa.phase_s")
+            self._m_mta = metrics.counter("party.ecdsa.mta_responses_total")
         self._stage = 0
 
-    # -- serialization helpers ----------------------------------------------
+    # -- helpers -------------------------------------------------------------
 
     # binding row + block parsing come from protocol.base.BatchBlockMixin
     # (shared with batch_dkg: one definition of the security-relevant
     # session+sender binding, so the two cannot drift)
     _parse_bytes = BatchBlockMixin._parse_block
 
-    def _parse_limbs(
-        self, hexstr: str, prof: bn.LimbProfile, pid: str
-    ) -> jnp.ndarray:
-        arr = self._parse_bytes(hexstr, _nb(prof), pid)
-        return bn.bytes_to_limbs_le(jnp.asarray(arr), prof, prof.n_limbs)
+    def _phase(self, handler: str):
+        """The ``phase:gg18_*`` span of a handler's device work, and its
+        seconds into ``party.ecdsa.phase_s``."""
+        return _Phase(self, PHASES[handler])
 
-    def _ser_scalar(self, x: jnp.ndarray) -> str:
-        return _ser_bytes(sp.pack_be_32(x))
+    def _wide(self) -> np.ndarray:
+        """(B, 40) uniform bytes: a scalar mod q once reduced (bias 2^-64)."""
+        return gb.rand_bits(self.B, 320, self.rng)
 
-    def _parse_scalar(self, hexstr: str, pid: str) -> jnp.ndarray:
-        arr = self._parse_bytes(hexstr, 32, pid)
-        return self.ring.reduce(
-            bn.bytes_to_limbs_le(jnp.asarray(arr[:, ::-1].copy()), P256, 22)
-        )
+    def _blocks(self, round_name: str, fields: Dict[str, int]
+                ) -> Dict[str, np.ndarray]:
+        """The peers' blocks of a broadcast round, stacked (q-1, B, n) in
+        ``others()`` order: ``fields`` maps a payload field to its row
+        width in bytes."""
+        payloads = self._round_payloads(round_name)
+        return {
+            f: np.stack([
+                self._parse_bytes(payloads[j][f], n, j)
+                for j in self.others()
+            ])
+            for f, n in fields.items()
+        }
 
-    def _parse_point_block(
-        self, hexstr: str, pid: str
-    ) -> Tuple[jnp.ndarray, sp.SecpPointJ]:
-        comp = self._parse_bytes(hexstr, 33, pid)
-        pts, ok = sp.decompress(jnp.asarray(comp))
-        self._ok = self._ok & ok
-        return jnp.asarray(comp), pts
+    def _peer_binds(self) -> np.ndarray:
+        return np.stack([self._binds[j] for j in self.others()])
+
+    def _settle(self, mta: gb.MtaBatch, agg, strict) -> None:
+        """The host's verdict on a batch-verified proof; a combined check
+        that fails falls back to the strict per-session one (cold: its
+        eager kernels compile on first use), so a bad proof is still
+        attributed to its lane."""
+        if gb.agg_holds(mta.alice, agg):
+            return
+        log.warn("batched proof check failed — strict re-verification",
+                 session=self.session_id)
+        self._ok = self._ok & strict()
 
     # -- round 1 ------------------------------------------------------------
 
@@ -284,53 +428,37 @@ class BatchedECDSASigningParty(BatchBlockMixin, PartyBase):
         B, q = self.B, len(self.party_ids)
         # mpcshape: unbounded-ok — B is pow-2 snapped upstream (scheduler chunks via engine/buckets.floor_bucket; bench via bucket_b)
         self._cw = compile_watch.begin("party.ecdsa", f"B{B}|q{q}")
-        rb = gb.rand_bits
-        self._k = gb._scalar_from_wide_bytes(jnp.asarray(rb(B, 320, self.rng)))
-        self._gamma = gb._scalar_from_wide_bytes(
-            jnp.asarray(rb(B, 320, self.rng))
-        )
-        self._gblind = jnp.asarray(rb(B, 256, self.rng))
-        Gam, Gam_comp, commit = gb._blk_gamma(
-            self._gamma, self._gblind, self._bind_row(self.self_id)
-        )
-        self._Gamma_own = Gam
-        self._Gamma_comp = Gam_comp
-        u_bits = gb.rand_bit_tensor(B, RAND_BITS, self.rng)
-        kp = gb._scalar_to_plain(self.own.pmx, self._k)
-        c_k, _r = self.own.pmx.encrypt(kp, u_bits)
-        self._c_k = c_k
-        self._kp = kp
+        own = self.own
+        with self._phase("start") as ph:
+            self._gblind = gb.rand_bits(B, 256, self.rng)
+            k_raw, gamma_raw = self._wide(), self._wide()
+            u_bits = gb.rand_bit_array(B, RAND_BITS, self.rng)
+            st = gb.gg18_r1_commit(
+                own, k_raw, gamma_raw, self._gblind,
+                self._binds[self.self_id], u_bits,
+            )
+            self._k, self._gamma = st["k"], st["gamma"]
+            self._Gamma_own = st["Gamma"]
+            self._Gamma_comp = st["Gamma_comp"]
+            self._c_k = st["c_k"]
+            proofs = {
+                j: gb.gg18_r1_prove(
+                    self.mta_out[j], st["kp"], st["c_k"], u_bits,
+                    self.mta_out[j].alice_raw(B, self.rng),
+                )
+                for j in self.others()
+            }
+            ph.sync((st["ck"], proofs))
         out = [
             self.broadcast(
-                R1B,
-                {
-                    "gc": _ser_bytes(commit),
-                    "ck": _ser(c_k, self.own.pmx.prof_n2),
-                },
+                R1B, {"gc": _hex(st["commit"]), "ck": _hex(st["ck"])}
             )
         ]
-        self._alice_beta: Dict[Tuple[str, str], jnp.ndarray] = {}
         for j in self.others():
-            mta = self.mta_out[j]
-            Ra = mta.alice_randoms(B, self.rng)
-            T = mta.alice_init(kp, Ra)
-            e = mta.e_limbs(mta.alice_challenge(c_k, T))
-            P = mta.alice_finish(e, kp, Ra, u_bits)
-            nt_j = self.peers[j].ctx_nt.prof
-            out.append(
-                self.unicast(
-                    j,
-                    R1A,
-                    {
-                        "z": _ser(T["z"], nt_j),
-                        "u": _ser(T["u"], self.own.pmx.prof_n2),
-                        "w": _ser(T["w"], nt_j),
-                        "s": _ser(P["s"], self.own.pmx.prof_n),
-                        "s1": _ser(P["s1"], mta.p_s1),
-                        "s2": _ser(P["s2"], mta.p_s2),
-                    },
-                )
-            )
+            out.append(self.unicast(
+                j, R1A, {f: _hex(v) for f, v in proofs[j].items()}
+            ))
+        self._alice_beta: Dict[str, tuple] = {}
         self._stage = 1
         return out
 
@@ -376,307 +504,270 @@ class BatchedECDSASigningParty(BatchBlockMixin, PartyBase):
 
     # -- round 2: Bob side ---------------------------------------------------
 
-    def _peer_ck(self, j: str) -> jnp.ndarray:
-        return self._parse_limbs(
-            self._round_payloads(R1B)[j]["ck"], self.peers[j].pmx.prof_n2, j
-        )
+    # a response's wire fields (per secret; the w leg adds the point U)
+    _RESPONSE = ("cb", "z", "zp", "t", "v", "w", "s", "s1", "s2", "t1", "t2")
 
     def _respond(self) -> List[RoundMsg]:
         B = self.B
         out = []
-        self._peer_c_k: Dict[str, jnp.ndarray] = {}
-        for j in self.others():
-            mta = self.mta_in[j]  # alice = j, bob = self
-            c_a = self._peer_ck(j)
-            self._peer_c_k[j] = c_a
-            p = self._round_payloads(R1A)[j]
-            nt_own = self.own.ctx_nt.prof
-            T = {
-                "z": self._parse_limbs(p["z"], nt_own, j),
-                "u": self._parse_limbs(p["u"], self.peers[j].pmx.prof_n2, j),
-                "w": self._parse_limbs(p["w"], nt_own, j),
-            }
-            P = {
-                "s": self._parse_limbs(p["s"], self.peers[j].pmx.prof_n, j),
-                "s1": self._parse_limbs(p["s1"], mta.p_s1, j),
-                "s2": self._parse_limbs(p["s2"], mta.p_s2, j),
-            }
-            e = mta.e_limbs(mta.alice_challenge(c_a, T))
-            self._ok = self._ok & mta.bob_check_alice(c_a, T, P, e, self.rng)
-            payload = {}
-            for name, secret in (("gamma", self._gamma), ("w", self._w)):
-                Rb = mta.bob_randoms(B, self.rng)
-                b_e = gb._scalar_to_prof(secret, mta.p_e)
-                Tb = mta.bob_respond(c_a, b_e, Rb)
-                extra = ()
-                if name == "w":
-                    alpha_q = gb._mod_q_from_limbs(Rb["alpha"], mta.p_alpha)
-                    _U_pt, U_comp = gb._base_mul_compressed(alpha_q)
-                    X_comp = sp.compress(self.W_pts[self.self_id])
-                    extra = (U_comp, X_comp)
-                    payload["w_U"] = _ser_bytes(U_comp)
-                e_b = mta.e_limbs(mta.bob_challenge(c_a, Tb, extra))
-                Pb = mta.bob_finish(e_b, b_e, Rb)
-                self._alice_beta[(j, name)] = self.ring.negmod(
-                    gb._mod_q_from_limbs(Rb["beta_prime"], mta.p_bp)
+        nb = gb.wire_bytes
+        with self._phase("_respond") as ph:
+            payloads, device = {}, []
+            for j in self.others():
+                mta = self.mta_in[j]  # alice = j, bob = self
+                A = self.peers[j]
+                nt_own = nb(self.own.ctx_nt.prof)
+                p = self._round_payloads(R1A)[j]
+                ck = self._parse_bytes(
+                    self._round_payloads(R1B)[j]["ck"], nb(A.pmx.prof_n2), j
                 )
-                nt_j = self.peers[j].ctx_nt.prof
-                n2_j = self.peers[j].pmx.prof_n2
-                payload.update(
-                    {
-                        f"{name}_cb": _ser(Tb["c_b"], n2_j),
-                        f"{name}_z": _ser(Tb["z"], nt_j),
-                        f"{name}_zp": _ser(Tb["z_p"], nt_j),
-                        f"{name}_t": _ser(Tb["t"], nt_j),
-                        f"{name}_v": _ser(Tb["v"], n2_j),
-                        f"{name}_w": _ser(Tb["w"], nt_j),
-                        f"{name}_s": _ser(Pb["s"], self.peers[j].pmx.prof_n),
-                        f"{name}_s1": _ser(Pb["s1"], mta.p_s1),
-                        f"{name}_s2": _ser(Pb["s2"], mta.p_s2),
-                        f"{name}_t1": _ser(Pb["t1"], mta.p_t1),
-                        f"{name}_t2": _ser(Pb["t2"], mta.p_s2),
-                    }
+                pf = {
+                    "z": self._parse_bytes(p["z"], nt_own, j),
+                    "u": self._parse_bytes(p["u"], nb(A.pmx.prof_n2), j),
+                    "w": self._parse_bytes(p["w"], nt_own, j),
+                    "s": self._parse_bytes(p["s"], nb(A.pmx.prof_n), j),
+                    "s1": self._parse_bytes(p["s1"], nb(mta.p_s1), j),
+                    "s2": self._parse_bytes(p["s2"], nb(mta.p_s2), j),
+                }
+                rho_bits = gb.rand_bit_array(B, gb.RHO_BITS, self.rng)
+                c_a, self._ok, agg, (T, P, e) = gb.gg18_r2_verify(
+                    mta, self._ok, ck, pf, rho_bits
                 )
-            out.append(self.unicast(j, R2, payload))
+                self._settle(
+                    mta, agg,
+                    lambda: mta.bob_check_alice_strict(c_a, T, P, e),
+                )
+                # both secrets' responses as one 2·B-lane batch: lanes
+                # [0, B) the γ leg, [B, 2B) the w leg
+                blocks, U_comp, betas = gb.gg18_r2_respond(
+                    mta, c_a, self._gamma, self._w,
+                    mta.bob_raw(2 * B, self.rng),
+                    self._W_comp[self.self_id],
+                )
+                device.append((blocks, U_comp))
+                payload = {"w_U": U_comp}
+                for f, v in blocks.items():
+                    payload[f"gamma_{f}"] = _Rows(v, 0, B)
+                    payload[f"w_{f}"] = _Rows(v, B, 2 * B)
+                self._alice_beta[j] = betas
+                payloads[j] = payload
+            ph.sync(device)
+        if self._m_mta is not None:
+            self._m_mta.inc(2 * len(payloads) * B)
+        for j, payload in payloads.items():
+            out.append(self.unicast(
+                j, R2, {f: _hex(v) for f, v in payload.items()}
+            ))
         return out
 
     # -- round 3: Alice verifies + decrypts, broadcasts δ_i ------------------
 
     def _delta(self) -> RoundMsg:
-        ring = self.ring
-        alpha: Dict[Tuple[str, str], jnp.ndarray] = {}
-        for j in self.others():
-            mta = self.mta_out[j]
-            p = self._round_payloads(R2)[j]
-            nt_own = self.own.ctx_nt.prof
-            n2_own = self.own.pmx.prof_n2
-            for name in ("gamma", "w"):
-                Tb = {
-                    "c_b": self._parse_limbs(p[f"{name}_cb"], n2_own, j),
-                    "z": self._parse_limbs(p[f"{name}_z"], nt_own, j),
-                    "z_p": self._parse_limbs(p[f"{name}_zp"], nt_own, j),
-                    "t": self._parse_limbs(p[f"{name}_t"], nt_own, j),
-                    "v": self._parse_limbs(p[f"{name}_v"], n2_own, j),
-                    "w": self._parse_limbs(p[f"{name}_w"], nt_own, j),
+        nb = gb.wire_bytes
+        own = self.own
+        widths = {
+            "cb": nb(own.pmx.prof_n2), "z": nb(own.ctx_nt.prof),
+            "zp": nb(own.ctx_nt.prof), "t": nb(own.ctx_nt.prof),
+            "v": nb(own.pmx.prof_n2), "w": nb(own.ctx_nt.prof),
+            "s": nb(own.pmx.prof_n),
+        }
+        with self._phase("_delta") as ph:
+            alphas = []
+            for j in self.others():
+                mta = self.mta_out[j]
+                p = self._round_payloads(R2)[j]
+                w_j = dict(widths, s1=nb(mta.p_s1), s2=nb(mta.p_s2),
+                           t1=nb(mta.p_t1), t2=nb(mta.p_s2))
+                # the peer's two responses as one 2·B-lane batch
+                rs = {
+                    f: np.concatenate([
+                        self._parse_bytes(p[f"{name}_{f}"], w_j[f], j)
+                        for name in ("gamma", "w")
+                    ])
+                    for f in self._RESPONSE
                 }
-                Pb = {
-                    "s": self._parse_limbs(p[f"{name}_s"], self.own.pmx.prof_n, j),
-                    "s1": self._parse_limbs(p[f"{name}_s1"], mta.p_s1, j),
-                    "s2": self._parse_limbs(p[f"{name}_s2"], mta.p_s2, j),
-                    "t1": self._parse_limbs(p[f"{name}_t1"], mta.p_t1, j),
-                    "t2": self._parse_limbs(p[f"{name}_t2"], mta.p_s2, j),
-                }
-                extra = ()
-                if name == "w":
-                    U_comp, U_pt = self._parse_point_block(p["w_U"], j)
-                    X_comp = sp.compress(self.W_pts[j])
-                    extra = (U_comp, X_comp)
-                e_b = mta.e_limbs(mta.bob_challenge(self._c_k, Tb, extra))
-                self._ok = self._ok & mta.alice_check_bob(
-                    self._c_k, Tb, Pb, e_b, self.rng
+                rho_bits = gb.rand_bit_array(
+                    2 * self.B, gb.RHO_BITS, self.rng
                 )
-                if name == "w":
-                    self._ok = self._ok & gb._withcheck_curve(
-                        gb._mod_q_from_limbs(Pb["s1"], mta.p_s1),
-                        gb._mod_q_from_limbs(e_b, mta.p_e),
-                        U_pt,
-                        self.W_pts[j],
-                    )
-                alpha[(j, name)] = mta.alice_decrypt_share(Tb["c_b"])
-
-        d = ring.mulmod(self._k, self._gamma)
-        s_ = ring.mulmod(self._k, self._w)
-        for j in self.others():
-            d = ring.addmod(
-                d, ring.addmod(alpha[(j, "gamma")], self._alice_beta[(j, "gamma")])
+                self._ok, agg, legs, (c2, Tb, Pb, e_b) = gb.gg18_r3_verify(
+                    mta, self._ok, self._c_k, rs,
+                    self._parse_bytes(p["w_U"], 33, j), rho_bits,
+                    self.W_pts[j], self._W_comp[j],
+                )
+                self._settle(
+                    mta, agg,
+                    lambda: _both(mta.alice_check_bob_strict(
+                        c2, Tb, Pb, e_b), self.B),
+                )
+                alphas.append(legs)
+            self._delta_own, self._sigma_own, d_block = gb.gg18_r3_delta(
+                self._k, self._gamma, self._w, tuple(alphas),
+                tuple(self._alice_beta[j] for j in self.others()),
             )
-            s_ = ring.addmod(
-                s_, ring.addmod(alpha[(j, "w")], self._alice_beta[(j, "w")])
-            )
-        self._delta_own = d
-        self._sigma_own = s_
-        return self.broadcast(R3, {"d": self._ser_scalar(d)})
+            ph.sync(d_block)
+        return self.broadcast(R3, {"d": _hex(d_block)})
 
     # -- round 4: Γ decommit + Schnorr PoK -----------------------------------
 
     def _decommit_gamma(self) -> RoundMsg:
-        kpok = gb._scalar_from_wide_bytes(
-            jnp.asarray(gb.rand_bits(self.B, 320, self.rng))
-        )
-        A_comp, s_pok = gb._blk_schnorr_prove(
-            kpok, self._gamma, self._Gamma_comp, self._bind_row(self.self_id)
-        )
+        with self._phase("_decommit_gamma") as ph:
+            A_comp, s_pok = gb.gg18_r4_pok(
+                self._wide(), self._gamma, self._Gamma_comp,
+                self._binds[self.self_id],
+            )
+            ph.sync((A_comp, s_pok))
         return self.broadcast(
             R4,
             {
-                "G": _ser_bytes(self._Gamma_comp),
-                "blind": _ser_bytes(self._gblind),
-                "A": _ser_bytes(A_comp),
-                "spok": self._ser_scalar(s_pok),
+                "G": _hex(self._Gamma_comp),
+                "blind": _hex(self._gblind),
+                "A": _hex(A_comp),
+                "spok": _hex(s_pok),
             },
         )
 
     # -- round 5A ------------------------------------------------------------
 
     def _phase5a(self) -> RoundMsg:
-        ring = self.ring
-        delta = self._delta_own
-        Gamma_sum = self._Gamma_own
-        commits = self._round_payloads(R1B)
-        for j in self.others():
-            p = self._round_payloads(R4)[j]
-            G_comp, G_pt = self._parse_point_block(p["G"], j)
-            blind = jnp.asarray(self._parse_bytes(p["blind"], 32, j))
-            commit = jnp.asarray(self._parse_bytes(commits[j]["gc"], 32, j))
-            self._ok = self._ok & gb._blk_gamma_check(
-                blind, G_comp, self._bind_row(j), commit
+        with self._phase("_phase5a") as ph:
+            peers = self._blocks(
+                R4, {"G": 33, "blind": 32, "A": 33, "spok": 32}
             )
-            A_comp = jnp.asarray(self._parse_bytes(p["A"], 33, j))
-            s_pok = self._parse_scalar(p["spok"], j)
-            self._ok = self._ok & gb._blk_schnorr_verify(
-                A_comp, s_pok, G_pt, G_comp, self._bind_row(j)
+            peers["gc"] = self._blocks(R1B, {"gc": 32})["gc"]
+            peers["d"] = self._blocks(R3, {"d": 32})["d"]
+            peers["bind"] = self._peer_binds()
+            raw = {x: self._wide() for x in ("li", "rho", "ka", "kb")}
+            self._va_blind = gb.rand_bits(self.B, 256, self.rng)
+            ok, delta, Gamma_sum = gb.gg18_r5a_verify(
+                self._ok, self._delta_own, self._Gamma_own, peers
             )
-            delta = ring.addmod(
-                delta, self._parse_scalar(self._round_payloads(R3)[j]["d"], j)
+            st = gb.gg18_r5a_commit(
+                ok, delta, Gamma_sum, self.m, self._k, self._sigma_own,
+                raw, self._va_blind, self._binds[self.self_id],
             )
-            Gamma_sum = gb._blk_point_add(Gamma_sum, G_pt)
-        ok_R, R_pt, r, rec = gb._blk_R(delta, Gamma_sum)
-        self._ok = self._ok & ok_R
-        self._R_pt, self._r, self._rec = R_pt, r, rec
-
-        rb = gb.rand_bits
-        B = self.B
-        self._li = gb._scalar_from_wide_bytes(jnp.asarray(rb(B, 320, self.rng)))
-        self._rho = gb._scalar_from_wide_bytes(jnp.asarray(rb(B, 320, self.rng)))
-        self._ka = gb._scalar_from_wide_bytes(jnp.asarray(rb(B, 320, self.rng)))
-        self._kb = gb._scalar_from_wide_bytes(jnp.asarray(rb(B, 320, self.rng)))
-        self._va_blind = jnp.asarray(rb(B, 256, self.rng))
-        si, Vi, Ai, vc, ac, cmt = gb._blk_va(
-            self.m, r, self._k, self._sigma_own, self._li, self._rho,
-            R_pt, self._va_blind, self._bind_row(self.self_id),
-        )
-        self._s_own, self._V_own, self._A_own = si, Vi, Ai
-        self._vc, self._ac = vc, ac
-        return self.broadcast(R5, {"c": _ser_bytes(cmt)})
+            self._ok = st["ok"]
+            self._R_pt, self._r, self._rec = st["R"], st["r"], st["rec"]
+            self._li, self._rho = st["li"], st["rho"]
+            self._ka, self._kb = st["ka"], st["kb"]
+            self._s_own, self._V_own, self._A_own = st["s"], st["V"], st["A"]
+            self._vc, self._ac = st["vc"], st["ac"]
+            ph.sync(st["commit"])
+        return self.broadcast(R5, {"c": _hex(st["commit"])})
 
     # -- round 5B ------------------------------------------------------------
 
     def _phase5b(self) -> RoundMsg:
-        Apok, sa, sb = gb._blk_pedersen_prove(
-            self._ka, self._kb, self._s_own, self._li, self._R_pt,
-            self._vc, self._ac, self._bind_row(self.self_id),
-        )
+        with self._phase("_phase5b") as ph:
+            Apok, sa, sb = gb.gg18_r5b(
+                self._ka, self._kb, self._s_own, self._li, self._R_pt,
+                self._vc, self._ac, self._binds[self.self_id],
+            )
+            ph.sync((Apok, sa, sb))
         return self.broadcast(
             R6,
             {
-                "vc": _ser_bytes(self._vc),
-                "ac": _ser_bytes(self._ac),
-                "blind": _ser_bytes(self._va_blind),
-                "apok": _ser_bytes(Apok),
-                "sa": self._ser_scalar(sa),
-                "sb": self._ser_scalar(sb),
+                "vc": _hex(self._vc),
+                "ac": _hex(self._ac),
+                "blind": _hex(self._va_blind),
+                "apok": _hex(Apok),
+                "sa": _hex(sa),
+                "sb": _hex(sb),
             },
         )
 
     # -- round 5C ------------------------------------------------------------
 
     def _phase5c(self) -> RoundMsg:
-        V_sum, A_sum = self._V_own, self._A_own
-        for j in self.others():
-            p = self._round_payloads(R6)[j]
-            vc, V_pt = self._parse_point_block(p["vc"], j)
-            ac, A_pt = self._parse_point_block(p["ac"], j)
-            blind = jnp.asarray(self._parse_bytes(p["blind"], 32, j))
-            commit = jnp.asarray(
-                self._parse_bytes(self._round_payloads(R5)[j]["c"], 32, j)
+        with self._phase("_phase5c") as ph:
+            peers = self._blocks(
+                R6, {"vc": 33, "ac": 33, "blind": 32, "apok": 33,
+                     "sa": 32, "sb": 32},
             )
-            self._ok = self._ok & gb._blk_va_check(
-                blind, vc, ac, self._bind_row(j), commit
+            peers["c"] = self._blocks(R5, {"c": 32})["c"]
+            peers["bind"] = self._peer_binds()
+            self._ut_blind = gb.rand_bits(self.B, 256, self.rng)
+            self._ok, V_sum, A_sum = gb.gg18_r5c_verify(
+                self._ok, self._V_own, self._A_own, self._R_pt, peers
             )
-            apok = jnp.asarray(self._parse_bytes(p["apok"], 33, j))
-            self._ok = self._ok & gb._blk_pedersen_verify(
-                apok, self._parse_scalar(p["sa"], j),
-                self._parse_scalar(p["sb"], j),
-                V_pt, self._R_pt, vc, ac, self._bind_row(j),
+            st = gb.gg18_r5c_commit(
+                V_sum, A_sum, self.m, self._r, self.Y, self._rho,
+                self._li, self._ut_blind, self._binds[self.self_id],
             )
-            V_sum = gb._blk_point_add(V_sum, V_pt)
-            A_sum = gb._blk_point_add(A_sum, A_pt)
-        V = gb._blk_V(V_sum, self.m, self._r, self.Y)
-        self._A_sum = A_sum
-        self._ut_blind = jnp.asarray(gb.rand_bits(self.B, 256, self.rng))
-        Ui, Ti, uc, tc, cmt = gb._blk_ut(
-            self._rho, self._li, V, A_sum, self._ut_blind,
-            self._bind_row(self.self_id),
-        )
-        self._U_own, self._T_own = Ui, Ti
-        self._uc, self._tc = uc, tc
-        return self.broadcast(R7, {"c": _ser_bytes(cmt)})
+            self._U_own, self._T_own = st["U"], st["T"]
+            self._uc, self._tc = st["uc"], st["tc"]
+            ph.sync(st["commit"])
+        return self.broadcast(R7, {"c": _hex(st["commit"])})
 
     # -- round 5D ------------------------------------------------------------
 
     def _phase5d(self) -> RoundMsg:
-        return self.broadcast(
-            R8,
-            {
-                "uc": _ser_bytes(self._uc),
-                "tc": _ser_bytes(self._tc),
-                "blind": _ser_bytes(self._ut_blind),
-            },
-        )
+        # a reveal: no program runs, the span holds the blocks' way to
+        # the host
+        with self._phase("_phase5d"):
+            payload = {
+                "uc": _hex(self._uc),
+                "tc": _hex(self._tc),
+                "blind": _hex(self._ut_blind),
+            }
+        return self.broadcast(R8, payload)
 
     # -- round 5E ------------------------------------------------------------
 
     def _partial(self) -> RoundMsg:
-        U_s, T_s = self._U_own, self._T_own
-        for j in self.others():
-            p = self._round_payloads(R8)[j]
-            uc, U_pt = self._parse_point_block(p["uc"], j)
-            tc, T_pt = self._parse_point_block(p["tc"], j)
-            blind = jnp.asarray(self._parse_bytes(p["blind"], 32, j))
-            commit = jnp.asarray(
-                self._parse_bytes(self._round_payloads(R7)[j]["c"], 32, j)
+        with self._phase("_partial") as ph:
+            peers = self._blocks(R8, {"uc": 33, "tc": 33, "blind": 32})
+            peers["c"] = self._blocks(R7, {"c": 32})["c"]
+            peers["bind"] = self._peer_binds()
+            self._ok, s_block = gb.gg18_r5e(
+                self._ok, self._U_own, self._T_own, self._s_own, peers
             )
-            self._ok = self._ok & gb._blk_ut_check(
-                blind, uc, tc, self._bind_row(j), commit
-            )
-            U_s = gb._blk_point_add(U_s, U_pt)
-            T_s = gb._blk_point_add(T_s, T_pt)
-        self._ok = self._ok & gb._blk_point_eq(U_s, T_s)
-        return self.broadcast(R9, {"s": self._ser_scalar(self._s_own)})
+            ph.sync(s_block)
+        return self.broadcast(R9, {"s": _hex(s_block)})
 
     def _finalize(self) -> None:
-        s = self._s_own
-        for j in self.others():
-            s = self.ring.addmod(
-                s, self._parse_scalar(self._round_payloads(R9)[j]["s"], j)
+        # One program over the whole batch. (The counter-phase cohorts of
+        # engine/pipeline overlapped one cohort's signature egress with
+        # the next one's verification; with the egress four small arrays
+        # there is nothing left to overlap, and K programs to compile.)
+        with self._phase("_finalize") as ph:
+            r, s, rec, ok = gb.gg18_final(
+                self._ok, self._s_own, self._blocks(R9, {"s": 32})["s"],
+                self.m, self._r, self._rec, self.Y,
             )
-
-        # combine + verify as the engine's DONATED round step, cohorted:
-        # cohort A's signature egress (host byte packing) overlaps cohort
-        # B's _step_final dispatch (engine/pipeline counter-phase model)
-        def make_job(ci: int, sl: slice):
-            def job():
-                st = {
-                    "s": s[sl], "m": self.m[sl], "r": self._r[sl],
-                    "rec": self._rec[sl], "ok": self._ok[sl],
-                }
-                st = gb._step_final(st, gb._slice_pt(self.Y, sl))
-                egress = yield (
-                    "sig_egress",
-                    lambda: gb._sig_egress(
-                        st["r"], st["s"], st["rec"], st["ok"]
-                    ),
-                )
-                return egress
-
-            return job
-
-        outs = pl.run_counter_phase(
-            [make_job(ci, sl) for ci, sl in enumerate(self._plan.slices())]
-        )
+            ph.sync(ok)
         self.result = {
-            key: pl.merge_rows([o[key] for o in outs])
-            for key in ("r", "s", "recovery", "ok")
+            "r": np.asarray(r),  # mpcflow: host-ok — signature egress
+            "s": np.asarray(s),  # mpcflow: host-ok — signature egress
+            "recovery": np.asarray(rec),  # mpcflow: host-ok — signature egress
+            "ok": np.asarray(ok),  # mpcflow: host-ok — per-wallet verdicts, egress with the signatures
         }
         self.done = True
         compile_watch.finish(self._cw)
+
+
+class _Phase:
+    """A handler's ``phase:gg18_<name>`` span (attributes ``batch``, ``n``,
+    ``cohort``; a child of the ``round:`` span open on this thread), ended
+    by ``sync`` of the handler's device results only while tracing is
+    armed, and its seconds into the node's ``party.ecdsa.phase_s``."""
+
+    def __init__(self, party: BatchedECDSASigningParty, name: str):
+        self._party = party
+        self._name = f"phase:gg18_{name}"
+
+    def __enter__(self) -> "_Phase":
+        party = self._party
+        self._t0 = tracing.now_ns()
+        self._span = tracing.span(
+            self._name, batch=party.session_id.removeprefix("bsign:"),
+            n=party.B, cohort=0,
+        )
+        self._span.__enter__()
+        return self
+
+    sync = staticmethod(_span_sync)
+
+    def __exit__(self, *exc) -> None:
+        self._span.__exit__(*exc)
+        if self._party._m_phase is not None:
+            self._party._m_phase.observe((tracing.now_ns() - self._t0) / 1e9)

@@ -14,6 +14,7 @@ pre-warmer, the scripts):
 from __future__ import annotations
 
 import os
+import re
 from typing import Optional
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
@@ -34,6 +35,16 @@ def configure(
     so every warmed executable lands on disk)."""
     import jax
 
+    # File names in a program's locations, less the checkout's own path: a
+    # Pallas kernel travels inside its program as a serialized module WITH
+    # its locations, which the cache's key therefore covers (the key drops
+    # the debug information of the program around it, not of what a custom
+    # call carries), so the same kernel in another checkout never hit
+    # (PERF.md, PR 29: ten GG18 programs recompiled in every checkout).
+    jax.config.update(
+        "jax_hlo_source_file_canonicalization_regex",
+        "^" + re.escape(_CHECKOUT + os.sep),
+    )
     if not os.environ.get(ENV_VAR):
         cache_dir = explicit_dir or default_dir()
         os.makedirs(cache_dir, exist_ok=True)

@@ -71,3 +71,19 @@ def test_only_the_helper_sets_the_cache_dir():
         assert "host_fingerprint" not in src, name
     assert "ALLOW_MULTIPLE_LIBTPU_LOAD" not in "".join(
         p.read_text() for p in _program_sources())
+
+
+def test_locations_do_not_carry_the_checkouts_path(restore_cache_config):
+    """A Pallas kernel travels in its program as a serialized module with
+    its locations, which the persistent cache's key covers: with the
+    checkout's path cut from file names the same program has the same
+    text in every checkout (PR 29: ten GG18 programs recompiled in each)."""
+    was = jax.config.jax_hlo_source_file_canonicalization_regex
+    try:
+        jax_cache.configure()
+        text = jax.jit(lambda x: x * 2 + 1).lower(1.0).as_text(
+            debug_info=True)
+    finally:
+        jax.config.update("jax_hlo_source_file_canonicalization_regex", was)
+    assert "tests/test_jax_cache.py" in text
+    assert str(ROOT) not in text
