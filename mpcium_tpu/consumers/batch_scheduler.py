@@ -427,7 +427,7 @@ class BatchSigningScheduler:
             if self._gg18_contexts is None:
                 from ..protocol.ecdsa.batch_signing import ContextCache
 
-                self._gg18_contexts = ContextCache()
+                self._gg18_contexts = ContextCache(metrics=self.metrics)
             return self._gg18_contexts
 
     def close(self) -> None:

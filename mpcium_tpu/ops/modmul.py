@@ -141,10 +141,9 @@ def _const_matrices(
 
 def ints_to_limbs(vals, prof: bn.LimbProfile) -> np.ndarray:
     """Bulk python-int → limb conversion via byte packing (numpy-speed;
-    bn.to_limbs is a per-limb python loop — too slow for comb tables).
-    A limb of up to 17 bits lies within three consecutive bytes: it is
-    read out of that 24-bit window (no per-bit temporaries: a comb table
-    is ~90k values of ~300 limbs)."""
+    bn.to_limbs is a per-limb python loop). A limb of up to 17 bits lies
+    within three consecutive bytes: it is read out of that 24-bit window
+    (no per-bit temporaries)."""
     assert prof.bits <= 17
     nbytes = -(-prof.bits * prof.n_limbs // 8)
     raw = np.frombuffer(
@@ -582,6 +581,33 @@ def _k_powmod_fb(tbl, ebits, T_mu, T_m, comp, occ: int, n: int):
     return acc
 
 
+# Windows a comb-build program takes at once (its lane count): a table's
+# windows go through in chunks of the smallest of these that holds them
+# all, else of the largest, so one executable a modulus width builds every
+# large table whatever its window count, and a small table (a test's, a
+# short exponent's) does not pay for 128 lanes.
+_COMB_LANES = (8, 32, 128)
+
+
+@functools.partial(jax.jit, static_argnames=("occ", "n"))
+def _k_comb_rows(bases, T_mu, T_m, comp, occ: int, n: int):
+    """The rows of comb windows: bases (L, n) canonical residues →
+    (L, 2^COMB_W, n) with row w = bases^w mod m, each from the one before
+    it, the L windows as the lanes of one mulmod a step."""
+
+    def step(acc, _):
+        return _mm(acc, bases, T_mu, T_m, comp, occ, n), acc
+
+    _, rows = lax.scan(step, _one_like(bases, n), None, length=1 << COMB_W)
+    return jnp.moveaxis(rows, 0, 1)
+
+
+@functools.partial(jax.jit, static_argnames=("nw",))
+def _k_comb_take(chunks, nw: int):
+    """The first ``nw`` windows of a table built in chunks."""
+    return jnp.concatenate(chunks)[:nw]
+
+
 # ---------------------------------------------------------------------------
 # the modular context
 # ---------------------------------------------------------------------------
@@ -795,14 +821,14 @@ class MXUBarrett:
 
     def powmod_fixed_base(self, base: int, ebits: jnp.ndarray) -> jnp.ndarray:
         """base^e mod m, python-int base, per-element exponent bits.
-        Host-precomputed comb tables base^(2^(w·i) · d): ONE mulmod per
-        w-bit window, no squarings (the ring-Pedersen commitment
-        workhorse). Window width COMB_W (default 8): halving the mulmod
-        count vs w=4 at the price of 2^w-row tables — ~100 MB per
-        (base, 2048-bit modulus) for a 2400-bit exponent in the int32
-        limb layout (300 windows x 256 rows x 320 limbs x 4 B),
-        device-resident once per process; budget ~200 MB per
-        counterparty NTilde (h1+h2) when sizing HBM."""
+        Comb tables base^(2^(w·i) · d), built once on the device
+        (:meth:`_comb_table`): ONE mulmod per w-bit window, no squarings
+        (the ring-Pedersen commitment workhorse). Window width COMB_W
+        (default 8): halving the mulmod count vs w=4 at the price of
+        2^w-row tables — ~100 MB per (base, 2048-bit modulus) for a
+        2400-bit exponent in the int32 limb layout (300 windows x 256
+        rows x 320 limbs x 4 B), device-resident once per process; budget
+        ~200 MB per counterparty NTilde (h1+h2) when sizing HBM."""
         nw = -(-ebits.shape[-1] // COMB_W)
         return self._powmod_comb(self._comb_table(base, nw), ebits)
 
@@ -821,27 +847,30 @@ class MXUBarrett:
         )
 
     def _comb_table(self, base: int, nw: int) -> jnp.ndarray:
-        wbits = COMB_W
-        key = (base % self.modulus, nw, wbits)
+        """tbl[i, w] = base^(w·2^(COMB_W·i)) mod m, (nw, 2^COMB_W, n_limbs)
+        canonical limbs, made on the device: the host computes the nw
+        window bases b_i = b_(i-1)^(2^COMB_W) (short and sequential), the
+        rows of every window are :func:`_k_comb_rows`' (the table never
+        exists on the host and is never transferred)."""
+        key = (base % self.modulus, nw, COMB_W)
         tbl = self._fb_tables.get(key)
         if tbl is None:
-            # incremental build: b_i = base^(2^(w·i)) by squaring, row
-            # entries by repeated multiply - O(nw·2^w) modmuls, not modexps
-            m = self.modulus
-            rows = 1 << wbits
-            vals = []
-            b_i = base % m
-            for i in range(nw):
-                acc = 1
-                for w in range(rows):
-                    vals.append(acc)
-                    acc = acc * b_i % m
-                b_i = pow(b_i, rows, m)
-            tbl = jnp.asarray(
-                ints_to_limbs(vals, self.prof).reshape(
-                    nw, rows, self.prof.n_limbs
+            m, n = self.modulus, self.prof.n_limbs
+            b_i, bases = base % m, []
+            for _ in range(nw):
+                bases.append(b_i)
+                b_i = pow(b_i, 1 << COMB_W, m)
+            lanes = next((k for k in _COMB_LANES if nw <= k), _COMB_LANES[-1])
+            limbs = np.zeros((-(-nw // lanes) * lanes, n), np.int32)
+            limbs[:nw] = ints_to_limbs(bases, self.prof)
+            chunks = tuple(
+                _k_comb_rows(
+                    jnp.asarray(limbs[at:at + lanes]), self._T_mu, self._T_m,
+                    self._comp, self.occ, n,
                 )
+                for at in range(0, len(limbs), lanes)
             )
+            tbl = chunks[0] if nw == lanes else _k_comb_take(chunks, nw)
             self._fb_tables[key] = tbl
             size = int(tbl.nbytes)  # mpcflow: host-ok — a size from the shape, no transfer
             bits = self.modulus.bit_length()  # mpcflow: declassified — a modulus' width is public

@@ -42,8 +42,9 @@ into numpy byte arrays on the host and handed to a program whole; a
 program returns the next blocks as byte arrays. No ``jnp`` operation
 runs outside a program, so after a batch of a shape has run once a
 served batch asks XLA for nothing. The modulus contexts of a committee
-(Toeplitz constants, comb tables: ~0.6 GB on the device a node) are kept
-across batches in the :class:`ContextCache` the node's scheduler owns.
+(Toeplitz constants, comb tables: ~0.6 GB on the device a node, the
+tables made there by ops/modmul's ``_k_comb_rows``) are kept across
+batches in the :class:`ContextCache` the node's scheduler owns.
 """
 from __future__ import annotations
 
@@ -164,11 +165,12 @@ def _span_sync(tensors) -> None:
 
 class ContextCache:
     """The modulus contexts of the committees ONE node signs for, kept
-    across its batches: a context's constants and comb tables take seconds
-    of host work to build and ~200 MB of device memory a ring, and every
-    batch of a committee uses the same ones. The node's batch scheduler
-    owns one and hands it to each party it builds; a party handed none
-    builds its contexts for its one batch, as before.
+    across its batches: a context's constants take a second of host work,
+    its comb tables ~180 thousand modular products on the device and ~240
+    MB of its memory, and every batch of a committee uses the same ones.
+    The node's batch scheduler owns one and hands it to each party it
+    builds; a party handed none builds its contexts for its one batch, as
+    before.
 
     What stays resident, and for how long (SECURITY.md, "Key material at
     rest and in memory"): the node's own private context holds the digits
@@ -181,15 +183,28 @@ class ContextCache:
     least recently used past ``CAP``, when older than ``MAX_AGE_S``, and
     all of them on ``clear`` (the scheduler's ``close``). A context is
     complete when it is published (its named combs at the committee's
-    widths are built inside ``build``) and is not written afterwards."""
+    widths are built inside ``build``, on the device, and waited for) and
+    is not written afterwards.
+
+    ``metrics``: the node's registry (the scheduler hands its own):
+    counters ``party.ecdsa.context_hits_total`` and
+    ``party.ecdsa.context_misses_total``, histogram
+    ``party.ecdsa.context_build_s`` (one observation a context built, the
+    whole of ``build``); none, nothing is counted."""
 
     CAP = 16          # contexts; a 2-of-3 committee takes three a node
     MAX_AGE_S = 3600  # a context older than this is built anew
 
-    def __init__(self, clock=time.monotonic) -> None:
+    def __init__(self, clock=time.monotonic, metrics=None) -> None:
         self._lock = threading.Lock()
         self._clock = clock
         self._have: "OrderedDict[tuple, tuple]" = OrderedDict()
+        self._m_hits = self._m_misses = self._m_build = None
+        if metrics is not None:
+            self._m_hits = metrics.counter("party.ecdsa.context_hits_total")
+            self._m_misses = metrics.counter(
+                "party.ecdsa.context_misses_total")
+            self._m_build = metrics.histogram("party.ecdsa.context_build_s")
 
     def get(self, key: tuple, build) -> gb.PartyCtx:
         now = self._clock()
@@ -197,8 +212,15 @@ class ContextCache:
             held = self._have.get(key)
             if held is not None and now - held[1] <= self.MAX_AGE_S:
                 self._have.move_to_end(key)
+                if self._m_hits is not None:
+                    self._m_hits.inc()
                 return held[0]
-        ctx = build()  # outside the lock: seconds of host work
+        # outside the lock: a second of host work, then the device's
+        t0 = time.perf_counter()
+        ctx = jax.block_until_ready(build())  # mpcflow: host-ok — a context is published whole; no value is read
+        if self._m_build is not None:
+            self._m_misses.inc()
+            self._m_build.observe(time.perf_counter() - t0)
         with self._lock:
             held = self._have.get(key)
             if held is None or now - held[1] > self.MAX_AGE_S:

@@ -305,3 +305,36 @@ def test_a_cached_context_ages_out_and_the_cap_holds():
     for i in range(bs.ContextCache.CAP + 4):
         cache.get(("public", f"p{i}", "d", 0), build(i))
     assert len(cache) == bs.ContextCache.CAP
+
+
+def test_the_cache_counts_hits_misses_and_builds():
+    """With the node's registry: a miss and one ``context_build_s``
+    observation a context built (an expired one is built, and counted,
+    again), a hit a context found; with no registry nothing is counted."""
+    from mpcium_tpu.utils.metrics import MetricsRegistry
+
+    now = [0.0]
+    reg = MetricsRegistry()
+    cache = bs.ContextCache(clock=lambda: now[0], metrics=reg)
+
+    def read():
+        snap = reg.snapshot()
+        return (snap["counters"]["party.ecdsa.context_misses_total"],
+                snap["counters"]["party.ecdsa.context_hits_total"],
+                snap["histograms"]["party.ecdsa.context_build_s"]["count"])
+
+    key = ("private", "n0", "d", 0)
+    a = cache.get(key, object)
+    assert read() == (1, 0, 1)
+    assert cache.get(key, object) is a and cache.get(key, object) is a
+    assert read() == (1, 2, 1)
+    cache.get(("public", "n1", "d", 0), object)
+    assert read() == (2, 2, 2)
+    now[0] = bs.ContextCache.MAX_AGE_S + 1
+    assert cache.get(key, object) is not a
+    assert read() == (3, 2, 3)
+    assert cache.get(key, object) is not a
+    assert read() == (3, 3, 3)
+    quiet = bs.ContextCache(clock=lambda: now[0])
+    assert quiet.get(key, object) is quiet.get(key, object)
+    assert read() == (3, 3, 3)
