@@ -2,7 +2,7 @@
 
 PY ?= python
 
-.PHONY: install lint check shapecheck warmcheck claimscheck prewarm trace-check perfcheck perf-tests test test-all bench tpu-round broker chaos soak soak-tests setup-identities setup-initiator clean
+.PHONY: install lint check shapecheck warmcheck prewarm trace-check test test-all bench broker chaos soak soak-tests setup-identities setup-initiator clean
 
 install:
 	pip install -e . --no-build-isolation --no-deps
@@ -22,13 +22,10 @@ lint:
 
 # the one-pass static gate alone (mpclint + mpcflow + mpcshape +
 # budget/surface drift, shared AST parse) — what CI calls between edit
-# and test; the trace gate rides along (--no-sweep: the sweep just
-# ran), and perfcheck (statistical micro-bench regression gate, <30 s,
-# CPU-safe) closes it
+# and test; the trace gate rides along (--no-sweep: the sweep just ran)
 check:
 	$(PY) scripts/check_all.py
 	$(PY) scripts/trace_check.py --no-sweep
-	$(PY) scripts/perfcheck.py
 
 # compile-surface gate alone (STATIC_ANALYSIS.md "Compile surface"):
 # MPS9xx rules + COMPILE_SURFACE.json drift. Run
@@ -43,30 +40,11 @@ shapecheck:
 warmcheck:
 	$(PY) scripts/prewarm.py --check
 
-# claims drift gate alone (OBSERVABILITY.md "Claims & campaigns"): the
-# committed CLAIMS.json/CLAIMS.md must match a fresh evaluation of the
-# artifact corpus — 0 unknown metrics, 0 untracked ROADMAP headlines.
-# Regenerate after adding an artifact or a claim with
-# scripts/claimscheck.py --regen. Also folded into check_all.
-claimscheck:
-	$(PY) scripts/claimscheck.py
-
 # fill the XLA persistent cache for this host's serving set (the same
 # pass the daemon runs at boot with warm_enabled; see scripts/prewarm.py
 # for scheme/bucket/budget flags)
 prewarm:
 	$(PY) scripts/prewarm.py
-
-# statistical perf-regression gate alone (PERFORMANCE.md "perf
-# observatory"): micro-benches vs the committed PERF_baseline_micro.json
-# under a Mann-Whitney + effect-floor + bootstrap-CI triple gate.
-# --update-baseline re-anchors after an intentional perf change;
-# --regen-history rebuilds PERF_history.jsonl + PERFORMANCE_dashboard.md
-perfcheck:
-	$(PY) scripts/perfcheck.py
-
-perf-tests:
-	$(PY) -m pytest tests/ -m perf -q
 
 # mpctrace gate alone (OBSERVABILITY.md): committed TRACE_sample.json
 # validates + covers every instrumented layer, and a traced protocol
@@ -97,12 +75,6 @@ test-all:
 bench:
 	$(PY) bench.py
 
-# the ROADMAP item-1 round as one resumable command (claims ledger +
-# campaign runner). In a live TPU window: `make tpu-round`; anywhere:
-# `python scripts/tpu_round.py --rehearse` proves the harness on CPU.
-tpu-round:
-	$(PY) scripts/tpu_round.py
-
 # chaos drills (ISSUE 3): the full catalog, JSON reports, non-zero exit
 # on any missed expected outcome; reproduce a failure with --seed
 chaos:
@@ -112,8 +84,7 @@ chaos-tests:
 	$(PY) -m pytest tests/ -m chaos -q
 
 # SLO load soak (ISSUE 6): bursty mixed traffic + batch-chaos fault plan,
-# accounting invariant enforced (non-zero exit on any silent drop);
-# committed reports (SOAK_*.json) come from this entry point
+# accounting invariant enforced (non-zero exit on any silent drop)
 soak:
 	$(PY) scripts/load_soak.py --out SOAK_local.json
 

@@ -11,7 +11,7 @@ through the complete 9-round protocol — MtA with range proofs, phase-5
 commit–reveal, final in-protocol ECDSA verification — with all hashing and
 bignum work on device (engine.gg18_batch on ops.modmul MXU kernels).
 
-Robustness (the round-4 lesson — BENCH_r04.json was rc=124 with nothing
+Robustness (the round-4 lesson — a run ended rc=124 with nothing
 printed):
   * The process that measures is the one that asks for the device: with
     no TPU the bench prints why and exits non-zero. It never carries on
@@ -227,7 +227,7 @@ def _arm_process_watchdog(platform: str, deadline: float) -> None:
     stdout but not our GIL. The round-5 lesson — a wedged remote-compile
     call can sit in native code HOLDING the GIL for the entire driver
     budget, so no Python thread (watchdog or signal handler) ever runs
-    again; BENCH_r04-style rc=124-with-empty-stdout recurred at B=8192
+    again; round 4's rc=124-with-empty-stdout recurred at B=8192
     despite the thread watchdog. The child needs nothing from this
     process after the fork: it sleeps, checks the sentinel file the
     parent writes after the flagship line, and otherwise emits the
@@ -379,7 +379,7 @@ def main() -> None:
         "measured_at": time.strftime("%Y-%m-%dT%H:%M:%S", time.gmtime()),
     }
     # env fingerprint + compile ledger: which machine/toolchain/knob set
-    # produced this number (the perf ledger's grouping key) and what the
+    # produced this number and what the
     # warmup actually compiled vs deserialized from the persistent cache
     from mpcium_tpu.perf import compile_watch
     from mpcium_tpu.perf.envfp import env_fingerprint
@@ -533,8 +533,7 @@ def _parse_last_metric_line(stdout: bytes) -> dict | None:
 def _b_sweep_entry(bsz: int, timeout_s: float) -> object:
     """One sweep point: re-exec this bench in a subprocess at batch bsz.
     Returns the measured sigs/sec (float) or a structured DNF dict —
-    {"dnf": True, "reason": ...} — the only two shapes the perf ledger
-    accepts without flagging the entry."""
+    {"dnf": True, "reason": ...}: never a missing key or a prose string."""
     env = dict(os.environ)
     env.pop("MPCIUM_BENCH_B_SWEEP", None)  # no recursive sweeps
     env["MPCIUM_BENCH_B"] = str(bsz)
@@ -581,8 +580,8 @@ def _b_sweep_entry(bsz: int, timeout_s: float) -> object:
     return _dnf(f"rc={r.returncode} with non-positive value {value!r}")
 
 
-# Default sweep on TPU when MPCIUM_BENCH_B_SWEEP is unset: the ladder the
-# perf ledger tracks round over round, now topped by the 16384 bucket
+# Default sweep on TPU when MPCIUM_BENCH_B_SWEEP is unset: the ladder
+# measured round over round, now topped by the 16384 bucket
 # (ISSUE 17). A size that wedges or times out lands as a structured DNF
 # via _b_sweep_entry — never a missing key or a bare prose string.
 DEFAULT_B_SWEEP = "1024,4096,8192,16384"

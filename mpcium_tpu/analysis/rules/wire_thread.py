@@ -20,12 +20,8 @@ from __future__ import annotations
 import ast
 from typing import Iterator, List, Optional, Set
 
+from ...utils.annotations import REGISTERED_THREAD_PREFIXES
 from ..core import Finding, LintContext, ParsedFile, Rule, dotted_name, self_attr
-
-try:  # the registry lives in product code so runtime can use it too
-    from mpcium_tpu.utils.annotations import REGISTERED_THREAD_PREFIXES
-except Exception:  # pragma: no cover - analysis usable standalone
-    REGISTERED_THREAD_PREFIXES = ("ot-host",)
 
 _WIRE_FILE = "mpcium_tpu/wire.py"
 _THREAD_CTORS = {"threading.Thread", "Thread", "threading.Timer", "Timer"}

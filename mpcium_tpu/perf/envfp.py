@@ -1,12 +1,10 @@
 """Environment fingerprints: which machine/toolchain produced a number.
 
-The r05 stale-fallback confusion — a CPU-degraded bench record sitting
-in the official round slot with the chip number only under
-``last_tpu_measurement`` — happened because records carried no durable
-statement of WHERE they were measured. Every bench/soak record now
-stamps ``env_fingerprint()`` and the perf ledger groups trends by
-``fingerprint_key``, so a degraded run is structurally incapable of
-averaging into a chip trend.
+A CPU-degraded bench record once sat in a round's slot with the chip
+number only under ``last_tpu_measurement``, because records carried no
+durable statement of WHERE they were measured. Every ``bench.py`` and
+soak record stamps ``env_fingerprint()``, and the warm manifest keys
+its cached executables by ``host_fingerprint()``.
 
 Deliberately import-light: no jax import at module scope, and device
 facts are read only from an already-initialized jax (``sys.modules``),
@@ -112,19 +110,3 @@ def env_fingerprint() -> Dict[str, object]:
     }
     fp.update(device_facts())
     return fp
-
-
-def fingerprint_key(env: Optional[Dict[str, object]],
-                    platform_hint: Optional[str] = None) -> str:
-    """The ledger's grouping key: ``<platform>/<host>[/<n>x<kind>]``.
-    Records without a stamp (pre-observatory artifacts) group under
-    ``<platform-hint>/unstamped`` so they can never blend into a stamped
-    trend."""
-    if not env:
-        return f"{platform_hint or 'unknown'}/unstamped"
-    platform = str(env.get("platform") or platform_hint or "unknown")
-    host = str(env.get("host") or "unknown")
-    key = f"{platform}/{host}"
-    if env.get("device_count"):
-        key += f"/{env['device_count']}x{env.get('device_kind', '?')}"
-    return key

@@ -16,7 +16,7 @@ serve is refused LOUDLY". This module is that claim's harness:
   and latency is measured from the ORIGINAL submission;
 - the report closes the books: ``submitted == succeeded + shed + failed``
   with ``pending == 0`` is the no-silent-drops invariant the smoke test
-  and the committed SOAK_*.json runs assert.
+  and ``scripts/load_soak.py`` assert.
 
 Run via ``scripts/load_soak.py`` (or ``make soak``).
 """

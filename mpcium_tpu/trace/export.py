@@ -21,13 +21,9 @@ TRACE_FORMAT = "chrome-trace-events"
 def chrome_trace(
     per_node: Dict[str, Tuple[List[dict], int]],
     meta: Optional[dict] = None,
-    extra_events: Optional[List[dict]] = None,
 ) -> dict:
     """Build the Chrome trace document from ``{node: (spans, dropped)}``
-    (the shape ``recorder.snapshot_all`` returns). ``extra_events`` are
-    pre-built Chrome events appended verbatim — the perf ledger's
-    counter track (``perf.report.counter_track``) rides in here so the
-    bench trajectory lands in the same Perfetto document."""
+    (the shape ``recorder.snapshot_all`` returns)."""
     events: List[dict] = []
     pid_of: Dict[str, int] = {}
     tid_of: Dict[Tuple[str, str], int] = {}
@@ -72,9 +68,6 @@ def chrome_trace(
                 "ts": ts_us, "dur": max(0.0, (s["t1_ns"] - s["t0_ns"]) / 1e3),
                 "args": args,
             })
-
-    if extra_events:
-        events.extend(extra_events)
 
     other = {
         "format": TRACE_FORMAT,

@@ -42,10 +42,14 @@ class Secret(Generic[_V]):
     def __class_getitem__(cls, item):
         return item
 
-# thread-name prefixes the tests' conftest leak-checker treats as
-# process-lifetime singletons; MPL502 accepts threads named under them
-# as "registered" (tests/conftest.py no_leaked_nondaemon_threads)
-REGISTERED_THREAD_PREFIXES: Tuple[str, ...] = ("ot-host",)
+# thread-name prefixes of the process-lifetime singletons: the OT
+# pipeline's host worker pool (mta_ot._host_pool) and the cohort
+# pipeline's host worker (engine/pipeline._host_pool), created lazily
+# once per process and alive until interpreter exit by design. The one
+# list: MPL502 accepts threads named under them as "registered", and the
+# tests' leak checks (tests/conftest.py no_leaked_nondaemon_threads,
+# tests/test_load_soak.py) exempt them
+REGISTERED_THREAD_PREFIXES: Tuple[str, ...] = ("ot-host", "pipe-host")
 
 
 def locked_by(lock: str, *fields: str) -> Callable[[T], T]:

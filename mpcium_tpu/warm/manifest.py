@@ -18,9 +18,9 @@ signatures a node will ever request in serving:
   construction. A knob dim with no configured values is a **gap**,
   reported loudly (``coverage_check`` / ``make warmcheck``), never
   silently skipped;
-- entries are ordered hot-first by observed traffic
-  (``COMPILE_LEDGER.json`` + ``PERF_history.jsonl``), then cheap-first
-  (small B) so a budget-cut pre-warm covers the most value.
+- entries are ordered hot-first by observed traffic (the node's own
+  ``COMPILE_LEDGER.json``), then cheap-first (small B) so a budget-cut
+  pre-warm covers the most value.
 
 The manifest is keyed by the ``perf/envfp.py`` host fingerprint plus
 jax/jaxlib versions: compiled artifacts are machine-feature- and
@@ -161,60 +161,24 @@ def key_matches(stored: Optional[Dict[str, object]],
 # -- traffic priority --------------------------------------------------------
 
 
-def traffic_weights(ledger_entries: Sequence[dict] = (),
-                    history_records: Sequence[dict] = ()
-                    ) -> Dict[Tuple[str, str], float]:
-    """Observed-traffic weight per (engine, shape). Ledger entries are
-    exact signatures (weight 1 each); perf-history bench records vote
-    for their scheme's engines at the recorded batch bucket."""
-    w: Dict[Tuple[str, str], float] = {}
-    for e in ledger_entries:
-        eng, shape = e.get("engine"), e.get("shape")
-        if isinstance(eng, str) and isinstance(shape, str):
-            k = (eng, shape)
-            w[k] = w.get(k, 0.0) + 1.0
-    hot_b: Dict[int, float] = {}
-    for r in history_records:
-        ctx = r.get("context") or {}
-        for key in ("batch", "ed25519_batch", "gg18_ot_mta_batch",
-                    "dkg_batch", "reshare_batch"):
-            b = ctx.get(key)
-            if isinstance(b, int) and b > 0:
-                hot_b[b] = hot_b.get(b, 0.0) + 0.5
-    for b, v in hot_b.items():
-        w[("__B__", str(b))] = v
-    return w
-
-
-def load_traffic(ledger_path: Optional[str] = None,
-                 history_path: Optional[str] = None
+def load_traffic(ledger_path: Optional[str] = None
                  ) -> Dict[Tuple[str, str], float]:
-    """Best-effort read of the committed/on-host traffic artifacts.
-    Missing or malformed files simply contribute no weight."""
+    """Observed-traffic weight per (engine, shape): one for every entry
+    of the node's own compile ledger, which records exact signatures.
+    Best-effort: a missing or malformed file contributes no weight."""
     entries: List[dict] = []
-    records: List[dict] = []
     if ledger_path:
         try:
             with open(ledger_path) as f:
-                doc = json.load(f)
-            entries = list(doc.get("entries") or [])
+                entries = list(json.load(f).get("entries") or [])
         except (OSError, ValueError):
             pass
-    if history_path:
-        try:
-            with open(history_path) as f:
-                lines = f.readlines()
-        except OSError:
-            lines = []
-        for line in lines:
-            line = line.strip()
-            if not line:
-                continue
-            try:
-                records.append(json.loads(line))
-            except ValueError:
-                continue  # one bad JSONL line must not erase the rest
-    return traffic_weights(entries, records)
+    w: Dict[Tuple[str, str], float] = {}
+    for e in entries:
+        eng, shape = e.get("engine"), e.get("shape")
+        if isinstance(eng, str) and isinstance(shape, str):
+            w[(eng, shape)] = w.get((eng, shape), 0.0) + 1.0
+    return w
 
 
 # -- enumeration -------------------------------------------------------------
@@ -325,7 +289,6 @@ def build_manifest(surface: Dict[str, object],
                 d = dict(zip(names, combo))
                 b = int(d.get("B", "1"))
                 prio = traffic.get((engine, shape), 0.0)
-                prio += traffic.get(("__B__", str(b)), 0.0)
                 entries.append(WarmEntry(
                     engine=engine, shape=shape, B=b, scheme=scheme,
                     dims=d, priority=prio,

@@ -350,7 +350,7 @@ def prewarm_for_daemon(cfg, node_name: str) -> Optional[dict]:
             s.strip() for s in cfg.warm_schemes.split(",") if s.strip()
         ) or None
         traffic = wm.load_traffic(
-            os.path.join(db_dir, compile_watch.LEDGER_BASENAME), None
+            os.path.join(db_dir, compile_watch.LEDGER_BASENAME)
         )
         manifest = wm.build_manifest(
             surface, knobs, schemes=schemes, max_b=cfg.warm_max_b,

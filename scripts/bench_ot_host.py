@@ -21,8 +21,7 @@ extension sub-stage — PRG expansion, packed bit-transpose, pad
 hashing — timed on the host/native path and on the ops.hash_suite
 device kernels (warm, post-compile), outputs asserted bit-identical,
 and the comparison emitted in the same JSON record under
-ot_host_*/ot_device_* keys so the perf ledger (PERF_history.jsonl)
-tracks the crossover. JAX is only imported in this mode; the default
+ot_host_*/ot_device_* keys. JAX is only imported in this mode; the default
 host-only run stays JAX-free.
 
 Usage: python scripts/bench_ot_host.py [--m 1048576] [--threads 4]

@@ -1,10 +1,10 @@
 #!/usr/bin/env python
 """Load-soak CLI (ISSUE 6): bursty mixed traffic against an in-process
 cluster running the SLO scheduler, with a chaos fault plan active, and a
-committed JSON report of what the cluster actually served.
+JSON report of what the cluster actually served.
 
     python scripts/load_soak.py                         # default soak
-    python scripts/load_soak.py --out SOAK_r01.json     # committed report
+    python scripts/load_soak.py --out SOAK_local.json   # keep the report
     python scripts/load_soak.py --signs 256 --burst 32 --chaos batch-chaos
     python scripts/load_soak.py --chaos ""              # faults off
 

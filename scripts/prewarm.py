@@ -43,8 +43,7 @@ def _build(args):
             int(b) for b in args.buckets.split(",") if b.strip()
         )
     traffic = wm.load_traffic(
-        args.ledger or os.path.join(str(_ROOT), "COMPILE_LEDGER.json"),
-        args.history or os.path.join(str(_ROOT), "PERF_history.jsonl"),
+        args.ledger or os.path.join(str(_ROOT), "COMPILE_LEDGER.json")
     )
     return wm.build_manifest(
         surface, knobs, buckets=buckets, schemes=schemes,
@@ -70,8 +69,6 @@ def main(argv=None) -> int:
                         "wins over both)")
     p.add_argument("--ledger", default="",
                    help="COMPILE_LEDGER.json for traffic priority")
-    p.add_argument("--history", default="",
-                   help="PERF_history.jsonl for traffic priority")
     p.add_argument("--out", default="",
                    help="report dir for WARM_MANIFEST.json "
                         "(default: the cache dir)")

@@ -104,11 +104,8 @@ def no_leaked_nondaemon_threads():
     import threading
     import time
 
-    # process-lifetime singletons are not leaks: the OT pipeline's host
-    # worker pool (mta_ot._host_pool) and the cohort pipeline's host
-    # worker (engine/pipeline._host_pool) are created lazily once per
-    # process and live until interpreter exit by design
-    _SINGLETONS = ("ot-host", "pipe-host")
+    # process-lifetime singletons are not leaks
+    from mpcium_tpu.utils.annotations import REGISTERED_THREAD_PREFIXES
 
     baseline = set(threading.enumerate())
     yield
@@ -119,7 +116,7 @@ def no_leaked_nondaemon_threads():
         leaked = [
             t for t in threading.enumerate()
             if t not in baseline and t.is_alive() and not t.daemon
-            and not t.name.startswith(_SINGLETONS)
+            and not t.name.startswith(REGISTERED_THREAD_PREFIXES)
         ]
         if not leaked:
             return

@@ -5,8 +5,6 @@ import secrets
 import numpy as np
 import pytest
 
-pytestmark = pytest.mark.slow
-
 from mpcium_tpu.core import hostmath as hm
 from mpcium_tpu.engine import eddsa_batch as eb
 from mpcium_tpu.protocol.base import ProtocolError
@@ -14,6 +12,7 @@ from mpcium_tpu.protocol.eddsa.batch_signing import BatchedEDDSASigningParty
 from mpcium_tpu.protocol.runner import run_protocol
 
 
+@pytest.mark.slow  # 54 s: three parties compile the full engine
 def test_three_party_batch_signs_and_verifies():
     ids = ["n0", "n1", "n2"]
     B = 5

@@ -108,6 +108,43 @@ def test_health_payload_tracks_compile_warming_to_ready():
         compile_watch.reset()
 
 
+def test_node_health_carries_compile_and_no_claims():
+    """A real EventConsumer's beat: the payload and the ``.prom`` sidecar
+    carry the node's own compile state and nothing of a measurement
+    ledger (a signing node publishes what it does, not what a ROADMAP
+    owes)."""
+    from types import SimpleNamespace
+
+    from mpcium_tpu.consumers.event_consumer import EventConsumer
+    from mpcium_tpu.perf import compile_watch
+    from mpcium_tpu.transport.loopback import LoopbackFabric
+
+    compile_watch.reset()
+    fabric = LoopbackFabric()
+    try:
+        consumer = EventConsumer(
+            SimpleNamespace(node_id="node0", session_wal=None),
+            fabric.transport(),
+        )
+        kv = MemoryKV()
+        snap = publish_health(consumer, kv, "node0")
+        assert snap["node"] == "node0"
+        assert snap["compile"]["state"] == "ready"
+        assert "claims" not in snap
+        stored = json.loads(kv.get("health/node0"))
+        assert "compile" in stored and "claims" not in stored
+        gauges = stored["metrics"]["gauges"]
+        assert gauges["compile.ready"] == 1.0
+        assert not [g for g in gauges if g.startswith("claims.")]
+        prom = kv.get("health/node0.prom").decode()
+        assert 'compile_ready{node="node0"} 1.0' in prom
+        assert not [ln for ln in prom.splitlines()
+                    if ln.startswith(("claims_", "# TYPE claims_"))]
+    finally:
+        fabric.close()
+        compile_watch.reset()
+
+
 def test_health_loop_survives_kv_put_raise():
     consumer = _StubConsumer()
     stop = threading.Event()

@@ -5,8 +5,6 @@ from pathlib import Path
 
 import pytest
 
-pytestmark = pytest.mark.slow
-
 from mpcium_tpu.core import hostmath as hm
 from mpcium_tpu.core import paillier as pl
 from mpcium_tpu.protocol.ecdsa import mta, zk

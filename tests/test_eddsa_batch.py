@@ -6,8 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytestmark = pytest.mark.slow
-
 from mpcium_tpu.core import bignum as bn
 from mpcium_tpu.core import ed25519_jax as ed
 from mpcium_tpu.core import hostmath as hm
@@ -35,6 +33,7 @@ def test_limbs_to_bits():
         assert got == v
 
 
+@pytest.mark.slow  # 22 s alone on XLA:CPU: the decompress compile at this width
 def test_decompress_valid_points():
     pts = [hm.ed_mul(k, hm.ED_B) for k in (1, 2, 3, 12345, hm.ED_L - 1)]
     enc = np.stack(
@@ -65,6 +64,7 @@ def test_nonce_commitments_match_host():
         assert np.asarray(R_comp)[i].tobytes() == expect
 
 
+@pytest.mark.slow  # 48 s a quorum alone: the whole engine compiles
 @pytest.mark.parametrize("q,t", [(3, 2), (2, 1)])
 def test_batched_cosigning_end_to_end(q, t):
     B = 8
@@ -83,6 +83,7 @@ def test_batched_cosigning_end_to_end(q, t):
         assert hm.ed25519_verify(pub, messages[i], sigs[i].tobytes())
 
 
+@pytest.mark.slow  # 55 s: the verify kernel compiles
 def test_batched_verify_rejects_wrong_message():
     B = 4
     universe = ["a", "b", "c"]

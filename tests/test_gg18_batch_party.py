@@ -14,13 +14,12 @@ import secrets
 
 import pytest
 
-pytestmark = pytest.mark.slow
-
 from conftest import run_isolated
 
 _INNER = os.environ.get("MPCIUM_GG18_PARTY_INNER")
 
 
+@pytest.mark.slow  # the whole GG18 party compiles on XLA:CPU: over eight minutes
 def test_two_party_batch_isolated():
     if _INNER:
         pytest.skip("wrapper entry; inner run executes the real test")
@@ -48,6 +47,7 @@ def small_preparams():
     return load_test_preparams(bits=1024)
 
 
+@pytest.mark.slow  # the wrapper above runs it
 @pytest.mark.skipif(not _INNER, reason="runs via the subprocess wrapper")
 def test_two_party_batch_signs_and_verifies(small_preparams):
     ids = ["node0", "node1"]

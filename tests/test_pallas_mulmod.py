@@ -18,8 +18,6 @@ from mpcium_tpu.core import bignum as bn
 from mpcium_tpu.ops import modmul as mm
 from mpcium_tpu.ops import pallas_mulmod as pmm
 
-pytestmark = pytest.mark.slow  # interpret-mode runs ~10 s per width
-
 
 def _rand_mod(bits: int) -> int:
     return secrets.randbits(bits) | (1 << (bits - 1)) | 1

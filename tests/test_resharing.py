@@ -5,8 +5,6 @@ from pathlib import Path
 
 import pytest
 
-pytestmark = pytest.mark.slow
-
 from mpcium_tpu.core import hostmath as hm
 from mpcium_tpu.core import paillier as pl
 from mpcium_tpu.protocol.base import ProtocolError
@@ -162,6 +160,7 @@ def ecdsa_setup():
     return preparams, {pid: p.result for pid, p in parties.items()}
 
 
+@pytest.mark.slow  # GG18 keygen in its fixture, then a reshare and a sign: over two minutes
 def test_ecdsa_reshare_and_sign(ecdsa_setup):
     preparams, wallets = ecdsa_setup
     ids = sorted(wallets)

@@ -9,8 +9,6 @@ import threading
 import jax
 import pytest
 
-pytestmark = pytest.mark.slow
-
 from mpcium_tpu import wire
 from mpcium_tpu.cluster import LocalCluster, load_test_preparams
 from mpcium_tpu.core import hostmath as hm
@@ -50,6 +48,7 @@ def test_to_dev_actually_shards(armed_mesh):
     assert z.sharding.spec[0] is None
 
 
+@pytest.mark.slow  # 63 s: a cluster signs through the sharded engine
 def test_batched_signing_through_consumers_on_mesh(armed_mesh, tmp_path):
     c = LocalCluster(
         n_nodes=3,

@@ -4,8 +4,6 @@ import time
 
 import pytest
 
-pytestmark = pytest.mark.slow
-
 from mpcium_tpu.transport.api import Permanent, QueueConfig, TransportError
 from mpcium_tpu.transport.tcp import BrokerServer, TcpClient, tcp_transport
 
@@ -95,6 +93,7 @@ def test_reply_wrapper(broker):
     t2.client.close()
 
 
+@pytest.mark.slow  # a three-node cluster with ECDSA keygen: over a minute
 def test_full_cluster_over_tcp(tmp_path):
     """A 3-node MPC cluster across the TCP bus: wallet + EdDSA sign."""
     from mpcium_tpu import wire

@@ -87,32 +87,36 @@ def test_scheme_and_bucket_filters(surface, knobs):
 
 
 def test_traffic_prioritizes_hot_shapes(surface, knobs):
-    traffic = {("eddsa.sign", "B4096|q2"): 10.0, ("__B__", "64"): 1.0}
+    traffic = {("eddsa.sign", "B4096|q2"): 10.0,
+               ("eddsa.sign", "B64|q2"): 1.0}
     man = wm.build_manifest(surface, knobs, schemes=("eddsa",),
                             traffic=traffic)
     shapes = [e["shape"] for e in man["entries"]]
-    assert shapes[0] == "B4096|q2"  # exact ledger match outranks all
-    assert shapes[1] == "B64|q2"    # bench-history batch size next
+    assert shapes[0] == "B4096|q2"  # the signature compiled most often
+    assert shapes[1] == "B64|q2"    # then the next hottest
     # cold shapes keep the deterministic small-B-first order
     assert shapes[2] == "B1|q2"
 
 
-def test_traffic_weights_from_ledger_and_history(tmp_path):
+def test_traffic_weights_from_ledger(tmp_path, surface, knobs):
     ledger = tmp_path / "COMPILE_LEDGER.json"
     ledger.write_text(json.dumps({"entries": [
         {"engine": "eddsa.sign", "shape": "B2|q2"},
         {"engine": "eddsa.sign", "shape": "B2|q2"},
+        {"engine": "eddsa.sign", "shape": "B16|q2"},
+        {"engine": 7, "shape": None},  # malformed entries carry no weight
     ]}))
-    history = tmp_path / "PERF_history.jsonl"
-    history.write_text(
-        json.dumps({"context": {"ed25519_batch": 4096}}) + "\n"
-        + "not json\n"
-    )
-    t = wm.load_traffic(str(ledger), str(history))
-    assert t[("eddsa.sign", "B2|q2")] == 2.0
-    assert t[("__B__", "4096")] == 0.5
-    # missing files are silently empty — a fresh node has no traffic yet
-    assert wm.load_traffic(str(tmp_path / "nope"), None) == {}
+    t = wm.load_traffic(str(ledger))
+    assert t == {("eddsa.sign", "B2|q2"): 2.0, ("eddsa.sign", "B16|q2"): 1.0}
+    # the work-list's order comes from that ledger alone
+    man = wm.build_manifest(surface, knobs, schemes=("eddsa",), traffic=t)
+    assert [e["shape"] for e in man["entries"]][:3] == [
+        "B2|q2", "B16|q2", "B1|q2"]
+    # a missing or malformed file is silently empty — a fresh node has
+    # no traffic yet
+    assert wm.load_traffic(str(tmp_path / "nope")) == {}
+    ledger.write_text("not json")
+    assert wm.load_traffic(str(ledger)) == {}
 
 
 def test_coverage_check_clean_on_committed_surface(surface, knobs):
