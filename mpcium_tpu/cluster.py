@@ -30,6 +30,7 @@ from .trace import recorder as _trace_recorder
 from .trace import snapshot_chrome as _trace_snapshot_chrome
 from .transport.loopback import LoopbackFabric
 from .utils import log
+from .utils.metrics import MetricsRegistry
 
 
 class _NotMine(Exception):
@@ -238,7 +239,9 @@ class LocalCluster(SyncOps):
             self._ident_dir, nid, self._peers,
             initiator_pubkey=self.initiator.public_bytes,
         )
-        kv = EncryptedFileKV(self.root / "db" / nid, self._store_password)
+        metrics = MetricsRegistry()  # the node's: its store's books too
+        kv = EncryptedFileKV(self.root / "db" / nid, self._store_password,
+                             metrics=metrics)
         wal = None
         if self._session_wal:
             from .store.session_wal import SessionWALStore
@@ -266,6 +269,7 @@ class LocalCluster(SyncOps):
             node, transport,
             batch_signing=self._batch_signing,
             batch_window_s=self._batch_window_s,
+            metrics=metrics,
             **self._ec_kw,
         )
         ec.run()
@@ -381,6 +385,7 @@ class LocalCluster(SyncOps):
             sc.close()
         for node in self.nodes.values():
             node.registry.resign()
+            node.kvstore.close()
         for ft in list(self.fault_transports.values()) + \
                 self._retired_fault_transports:
             ft.close()

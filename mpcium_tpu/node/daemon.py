@@ -25,6 +25,7 @@ from ..store.kvstore import EncryptedFileKV, FileKV
 from ..trace import arm as trace_arm
 from ..transport.tcp import tcp_transport
 from ..utils import log
+from ..utils.metrics import MetricsRegistry
 from .node import Node
 
 
@@ -152,7 +153,9 @@ def run_node(
     if name not in peers:
         raise SystemExit(f"node {name!r} not in peer set {sorted(peers)}")
 
-    share_store = EncryptedFileKV(Path(cfg.db_dir) / name, cfg.badger_password)
+    metrics = MetricsRegistry()  # the node's: its store's books too
+    share_store = EncryptedFileKV(Path(cfg.db_dir) / name, cfg.badger_password,
+                                  metrics=metrics)
     # crash-recovery WAL (default off): journals live sessions under the
     # share store's AEAD so a SIGKILL'd node resumes mid-round after restart
     session_wal = None
@@ -194,6 +197,7 @@ def run_node(
         node, transport,
         batch_signing=cfg.batch_signing,
         batch_window_s=cfg.batch_window_s,
+        metrics=metrics,
     )
     consumer.run()
     TimeoutConsumer(transport).run()

@@ -155,13 +155,18 @@ class PeerRegistry:
         now = set()
         seen_pids = set()
         # one network round-trip when the KV supports prefix scans
-        # (BrokerKV); keys()+get() per peer otherwise (FileKV/MemoryKV)
+        # (BrokerKV); otherwise (FileKV/MemoryKV) one get() per peer, and
+        # no keys(): their listing walks every key the control plane
+        # holds, a keyinfo entry a wallet among them, and with 131,072
+        # wallets three nodes polling at 20 Hz kept the interpreter for
+        # most of every second (PERF.md, PR 35)
         scan = getattr(self.kv, "scan", None)
         if scan is not None:
             entries = scan(READY_PREFIX).items()
         else:
             entries = [
-                (k, self.kv.get(k)) for k in self.kv.keys(READY_PREFIX)
+                (k, self.kv.get(k))
+                for k in (READY_PREFIX + pid for pid in self.peer_ids)
             ]
         for k, raw in entries:
             pid = k[len(READY_PREFIX):]

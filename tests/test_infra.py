@@ -273,6 +273,33 @@ def test_registry_ready_flow():
     assert regs["n0"].ready_peers() == ["n0", "n1"]
 
 
+@pytest.mark.parametrize("make_kv", [MemoryKV, FileKV], ids=["memory", "file"])
+def test_registry_poll_asks_for_its_peers_keys_and_lists_nothing(
+        tmp_path, make_kv):
+    """A poll costs a get a peer whatever else the control plane holds (a
+    keyinfo entry a wallet: listing them all, 60 times a second over three
+    nodes, kept the interpreter busy at 131,072 wallets; PERF.md, PR 35).
+    A stranger's ``ready/`` key is no peer."""
+    kv = make_kv(tmp_path) if make_kv is FileKV else make_kv()
+    ids = ["n0", "n1", "n2"]
+    regs = {n: PeerRegistry(n, ids, kv, poll_interval_s=30.0) for n in ids}
+    for n in ids:
+        regs[n].ready()
+    kv.put("ready/stranger", kv.get("ready/n1"))
+    for w in range(50):
+        kv.put(f"threshold_keyinfo/eddsa:w{w}", b"{}")
+    asked = []
+    kv.keys = lambda prefix="": asked.append(("keys", prefix)) or []
+    real_get = kv.get
+    kv.get = lambda key: asked.append(("get", key)) or real_get(key)
+    regs["n0"]._poll_once()
+    assert sorted(asked) == [("get", f"ready/{n}") for n in ids]
+    assert regs["n0"].all_ready() and regs["n0"].ready_peers() == ids
+    regs["n2"].resign()
+    regs["n0"]._poll_once()
+    assert regs["n0"].ready_peers() == ["n0", "n1"]
+
+
 def test_remote_cluster_loads_key_before_connecting(tmp_path):
     """A missing initiator key must fail BEFORE any broker connection is
     attempted (no leaked authenticated connection + reader thread): with
