@@ -3,14 +3,27 @@
 Points are extended twisted-Edwards coordinates (X:Y:Z:T) with x·y = T·Z,
 each coordinate a 22-limb int32 tensor with arbitrary leading batch shape.
 The unified addition law is *complete* on the curve (a = -1, d non-square):
-no branches, identical code for add/double — exactly what XLA wants
-(SURVEY.md §7: compiler-friendly control flow, static shapes).
+no branches, valid for doubling, the identity and the points of small
+order — exactly what XLA wants (SURVEY.md §7: compiler-friendly control
+flow, static shapes). Doubling has a formula of its own (dbl-2008-hwcd),
+also valid for every point.
 
-Hot-path design: EdDSA keygen/signing is dominated by fixed-base scalar
-multiplications (nonce commitments R_i = r_i·B — reference round structure in
-pkg/mpc/eddsa_rounds.go). Fixed-base mults use a precomputed table of
-B·2^i constants (half the field-muls of double-and-add); variable-base mults
-(verification) use the double-and-add ladder with completeness-based selects.
+Hot-path design: a ladder's time on the chip is its count of field
+operations run one after another (each ends in sequential carry scans over
+the limbs), not their width. So (1) the independent field operations of a
+formula run as ONE operation over a leading stack axis: an addition is
+four field operations in a row (two stacked products, two stacked sums), a
+doubling three; (2) both ladders take 4-bit windows. Fixed-base k·B (nonce
+commitments, keygen) is one addition a window from a constant table of
+d·16^i·B, no doublings: 64 additions for 256 bits. Variable-base k·P
+(verification) builds the lane's table 0·P … 15·P by 14 additions, then
+takes four doublings and one table addition a window. Table entries are
+kept in the form the addition consumes (Y−X, Y+X, 2d·T, 2Z; the constant
+table's Z = 1 is implied). A window's entry is read by a one-hot sum over
+the sixteen entries: selects only, so no address depends on a digit of a
+secret scalar (SECURITY.md). Scalars of at most 16 bits (DKG's
+x-coordinates) keep the bit-serial ladder: there the lane's table would
+cost more than the windows save.
 """
 from __future__ import annotations
 
@@ -75,27 +88,70 @@ def to_host(p: EdPointJ) -> list:
 
 
 @functools.lru_cache(maxsize=None)
-def _d2_limbs() -> np.ndarray:
+def _kp_limbs() -> np.ndarray:
+    """K·p ≥ 2^264 (the field's borrow-free subtraction offset) as 22
+    limbs, the top one above the radix: added to a limb sum that
+    subtracts one field element, it keeps the total non-negative."""
+    kp = ed25519_field().kp_limbs.copy()
+    kp[-2] += kp[-1] << PROF.bits
+    return kp[:-1]
+
+
+def _sums(*rows: jnp.ndarray) -> jnp.ndarray:
+    """Sums and differences of field elements, each row given as its raw
+    limb sum (non-negative total below 2^276), reduced as ONE field
+    operation over a leading stack axis: one carry, one fold."""
     F = ed25519_field()
-    return bn.to_limbs(2 * hm.ED_D % hm.ED_P, PROF)
+    return F.fold(bn.carry(bn.pad_limbs(jnp.stack(rows), 1), PROF))
+
+
+def _muls(xs, ys) -> jnp.ndarray:
+    """The products xs[i]·ys[i] as ONE field multiplication over a
+    leading stack axis."""
+    return ed25519_field().mul(jnp.stack(xs), jnp.stack(ys))
+
+
+def _to_cached(p: EdPointJ) -> jnp.ndarray:
+    """The form an addition wants of its second operand: the stack
+    (Y−X, Y+X, 2Z, 2d·T), shaped (4, ..., 22)."""
+    F = ed25519_field()
+    s = _sums(p.Y - p.X + _kp_limbs(), p.Y + p.X, 2 * p.Z)
+    t2d = F.mul(p.T, F.const(2 * hm.ED_D, p.T.shape[:-1]))
+    return jnp.concatenate([s, t2d[None]])
+
+
+def _add_cached(a: EdPointJ, c: jnp.ndarray) -> EdPointJ:
+    """a + the point whose cached form (:func:`_to_cached`) is c. Unified
+    and complete (HWCD08 'add-2008-hwcd-3'): any operand may be the
+    identity, a + a is right too. c of three rows (Y−X, Y+X, 2d·T) is an
+    affine point, Z = 1 implied: one product fewer."""
+    kp = _kp_limbs()
+    s = _sums(a.Y - a.X + kp, a.Y + a.X)
+    if c.shape[0] == 3:
+        A, B, C = _muls([s[0], s[1], a.T], list(c))
+        D = 2 * a.Z
+    else:
+        A, B, D, C = _muls([s[0], s[1], a.Z, a.T], list(c))
+    E, Fv, G, H = _sums(B - A + kp, D - C + kp, D + C, B + A)
+    return EdPointJ(*_muls([E, G, Fv, E], [Fv, H, G, H]))
 
 
 def add(a: EdPointJ, b: EdPointJ) -> EdPointJ:
     """Unified complete addition (RFC 8032 / HWCD08 'add-2008-hwcd-3')."""
-    F = ed25519_field()
-    A = F.mul(F.sub(a.Y, a.X), F.sub(b.Y, b.X))
-    B = F.mul(F.add(a.Y, a.X), F.add(b.Y, b.X))
-    C = F.mul(F.mul(a.T, b.T), jnp.broadcast_to(jnp.asarray(_d2_limbs()), a.T.shape))
-    D = F.mul_small(F.mul(a.Z, b.Z), 2)
-    E = F.sub(B, A)
-    Fv = F.sub(D, C)
-    G = F.add(D, C)
-    H = F.add(B, A)
-    return EdPointJ(F.mul(E, Fv), F.mul(G, H), F.mul(Fv, G), F.mul(E, H))
+    shape = jnp.broadcast_shapes(a.X.shape, b.X.shape)
+    a, b = (EdPointJ(*(jnp.broadcast_to(c, shape) for c in p)) for p in (a, b))
+    return _add_cached(a, _to_cached(b))
 
 
 def double(a: EdPointJ) -> EdPointJ:
-    return add(a, a)
+    """2a by HWCD08 'dbl-2008-hwcd' (a = -1): no 2d and no T read, valid
+    for every point. With E = 2XY, G = Y²−X², H = Y²+X², F = 2Z²−G the
+    result is (E·F : G·H : F·G : E·H), the formula's with all four
+    coordinates negated: the same point."""
+    xx, yy, zz, xy = _muls([a.X, a.Y, a.Z, a.X], [a.X, a.Y, a.Z, a.Y])
+    kp = _kp_limbs()
+    E, H, G, Fv = _sums(2 * xy, yy + xx, yy - xx + kp, 2 * zz - yy + xx + kp)
+    return EdPointJ(*_muls([E, G, Fv, E], [Fv, H, G, H]))
 
 
 def select(mask: jnp.ndarray, a: EdPointJ, b: EdPointJ) -> EdPointJ:
@@ -119,49 +175,112 @@ def scalars_to_bits(ks, n_bits: int = SCALAR_BITS) -> np.ndarray:
     return out
 
 
-def scalar_mul(bits: jnp.ndarray, p: EdPointJ) -> EdPointJ:
-    """Variable-base double-and-add; bits (..., 256) LSB-first."""
-    acc = identity(bits.shape[:-1])
+_WINDOW = 4
+# A lane's table costs 14 additions before the first window: up to this
+# many bits the bit-serial ladder (an addition and a doubling a bit) is
+# the shorter chain.
+_BIT_SERIAL_MAX_BITS = 16
+
+
+def _digits(bits: jnp.ndarray) -> jnp.ndarray:
+    """(..., n) bits LSB-first → (ceil(n / 4), ...) window digits, the
+    least significant window first."""
+    pad = -bits.shape[-1] % _WINDOW
+    if pad:
+        bits = jnp.pad(bits, [(0, 0)] * (bits.ndim - 1) + [(0, pad)])
+    w = bits.reshape(bits.shape[:-1] + (-1, _WINDOW))
+    d = jnp.sum(w << jnp.arange(_WINDOW, dtype=jnp.int32), axis=-1)
+    return jnp.moveaxis(d, -1, 0).astype(jnp.int32)
+
+
+def _pick(table: jnp.ndarray, d: jnp.ndarray) -> jnp.ndarray:
+    """table (rows, 16, ..., 22), one cached entry a digit; d (...,) →
+    each lane's entry (rows, ..., 22) as a one-hot sum: fifteen selects,
+    no address that depends on the digit."""
+    ks = jnp.arange(1 << _WINDOW, dtype=jnp.int32).reshape((-1,) + (1,) * d.ndim)
+    hot = (d[None] == ks)[None, ..., None]
+    return jnp.sum(jnp.where(hot, table, 0), axis=1)
+
+
+def _scalar_mul_bits(bits: jnp.ndarray, p: EdPointJ) -> EdPointJ:
+    """Bit-serial double-and-add, for short scalars."""
 
     def step(carry, bit):
         acc, addend = carry
         acc = select(bit > 0, add(acc, addend), acc)
         return (acc, double(addend)), None
 
-    (acc, _), _ = lax.scan(step, (acc, p), jnp.moveaxis(bits, -1, 0))
+    init = (identity(bits.shape[:-1]), p)
+    (acc, _), _ = lax.scan(step, init, jnp.moveaxis(bits, -1, 0))
+    return acc
+
+
+def scalar_mul(bits: jnp.ndarray, p: EdPointJ) -> EdPointJ:
+    """Variable-base k·P; bits (..., n) LSB-first. By 4-bit windows over
+    the lane's table of 0·P … 15·P, the count of windows following n; a
+    scalar of at most 16 bits by double-and-add."""
+    batch = bits.shape[:-1]
+    p = EdPointJ(*(jnp.broadcast_to(c, batch + c.shape[-1:]) for c in p))
+    if bits.shape[-1] <= _BIT_SERIAL_MAX_BITS:
+        return _scalar_mul_bits(bits, p)
+    cached_p = _to_cached(p)
+
+    def next_row(row, _):
+        row = _add_cached(row, cached_p)
+        return row, row
+
+    _, more = lax.scan(next_row, p, None, length=(1 << _WINDOW) - 2)
+    table = _to_cached(EdPointJ(*(
+        jnp.concatenate([jnp.stack([c0, c1]), cs])
+        for c0, c1, cs in zip(identity(batch), p, more)
+    )))  # (4, 16, ..., 22)
+
+    def step(acc, d):
+        # ONE compiled doubling, run four times; each keeps T, which rides
+        # in the doubling's last stacked product at no operation's cost
+        acc = lax.fori_loop(0, _WINDOW, lambda _, a: double(a), acc)
+        return _add_cached(acc, _pick(table, d)), None
+
+    acc, _ = lax.scan(step, identity(batch), _digits(bits)[::-1])
     return acc
 
 
 @functools.lru_cache(maxsize=None)
-def _base_table() -> tuple:
-    """Constants B·2^i for i in [0, 256): four (256, 22) int32 arrays."""
+def _base_table() -> np.ndarray:
+    """Constants d·16^i·B for window i in [0, 64), digit d in [0, 16), in
+    the cached form of an affine point (Y−X, Y+X, 2d·T; digit 0 the
+    identity's 1, 1, 0): a (64, 3, 16, 22) int32 array."""
     F = ed25519_field()
-    pts = []
-    cur = hm.ED_B
-    for _ in range(SCALAR_BITS):
-        pts.append(cur.affine())
-        cur = hm.ed_add(cur, cur)
-    X = F.from_ints([p[0] for p in pts])
-    Y = F.from_ints([p[1] for p in pts])
-    T = F.from_ints([p[0] * p[1] % hm.ED_P for p in pts])
-    Z = np.broadcast_to(bn.to_limbs(1, PROF), X.shape).copy()
-    return X, Y, Z, T
+    rows = []
+    base = hm.ED_B
+    for _ in range(SCALAR_BITS // _WINDOW):
+        cur = hm.ED_IDENT
+        for _d in range(1 << _WINDOW):
+            x, y = cur.affine()
+            rows.append((y - x, y + x, 2 * hm.ED_D * x * y))
+            cur = hm.ed_add(cur, base)
+        base = cur  # 16·base
+    flat = np.asarray(F.from_ints([v for row in rows for v in row]))
+    return np.ascontiguousarray(flat.reshape(
+        SCALAR_BITS // _WINDOW, 1 << _WINDOW, 3, PROF.n_limbs
+    ).transpose(0, 2, 1, 3))
 
 
 def base_mul(bits: jnp.ndarray) -> EdPointJ:
-    """Fixed-base mult k·B via the B·2^i table: 256 conditional adds, no
-    doubling chain — the hot op for nonce commitments and keygen."""
-    Xt, Yt, Zt, Tt = (jnp.asarray(a) for a in _base_table())
-    acc = identity(bits.shape[:-1])
+    """Fixed-base k·B; bits (..., n ≤ 256) LSB-first. One addition a 4-bit
+    window from the constant table of d·16^i·B, no doublings — the hot op
+    for nonce commitments and keygen."""
+    assert bits.shape[-1] <= SCALAR_BITS
+    digits = _digits(bits)
+    table = jnp.asarray(_base_table())[: digits.shape[0]]
+    # every lane reads the same entries: lane axes of extent 1
+    table = table.reshape(table.shape[:3] + (1,) * (bits.ndim - 1) + table.shape[3:])
 
     def step(acc, sl):
-        bit, X, Y, Z, T = sl
-        tbl = EdPointJ(*(jnp.broadcast_to(c, acc.X.shape) for c in (X, Y, Z, T)))
-        return select(bit > 0, add(acc, tbl), acc), None
+        d, rows = sl
+        return _add_cached(acc, _pick(rows, d)), None
 
-    acc, _ = lax.scan(
-        step, acc, (jnp.moveaxis(bits, -1, 0), Xt, Yt, Zt, Tt)
-    )
+    acc, _ = lax.scan(step, identity(bits.shape[:-1]), (digits, table))
     return acc
 
 

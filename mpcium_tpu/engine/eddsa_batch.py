@@ -176,15 +176,15 @@ def verify_signatures(
     s·B == R + c·A. Returns (B,) bool. (The challenge c64 = SHA512(R‖A‖M)
     is hashed host-side; everything else runs on device.)"""
     L = ed.scalar_ring()
-    R_pt, okR = ed.decompress(sig[..., :32])
-    A_pt, okA = ed.decompress(A_comp)
+    pts, ok_pts = ed.decompress(jnp.stack([sig[..., :32], A_comp]))
+    R_pt, A_pt = (ed.EdPointJ(*(c[i] for c in pts)) for i in (0, 1))
     s = bn.bytes_to_limbs_le(sig[..., 32:], PROF, PROF.n_limbs)
     l_l = jnp.broadcast_to(jnp.asarray(bn.to_limbs(hm.ED_L, PROF)), s.shape)
     ok_range = bn.compare(s, l_l) < 0
     c = _reduce_wide(c64)
     lhs = ed.base_mul(bn.limbs_to_bits(s, PROF, ed.SCALAR_BITS))
     rhs = ed.add(R_pt, ed.scalar_mul(bn.limbs_to_bits(c, PROF, ed.SCALAR_BITS), A_pt))
-    return ed.equal(lhs, rhs) & okR & okA & ok_range
+    return ed.equal(lhs, rhs) & jnp.all(ok_pts, axis=0) & ok_range
 
 
 @jax.jit
