@@ -12,6 +12,7 @@ from __future__ import annotations
 import json
 import tempfile
 import threading
+import time
 from pathlib import Path
 from typing import Dict, List, Optional
 
@@ -29,7 +30,7 @@ from .trace import arm as _trace_arm
 from .trace import recorder as _trace_recorder
 from .trace import snapshot_chrome as _trace_snapshot_chrome
 from .transport.loopback import LoopbackFabric
-from .utils import log
+from .utils import log, tracing
 from .utils.metrics import MetricsRegistry
 
 
@@ -221,6 +222,7 @@ class LocalCluster(SyncOps):
         self.consumers: List[EventConsumer] = []
         self.signing_consumers: List[SigningConsumer] = []
         self.node_consumers: Dict[str, EventConsumer] = {}
+        self.node_signing: Dict[str, SigningConsumer] = {}
         for nid in self.node_ids:
             self._spawn_node(nid)
         for node in self.nodes.values():
@@ -248,7 +250,8 @@ class LocalCluster(SyncOps):
 
             wal = SessionWALStore(kv)
         registry = PeerRegistry(
-            nid, self.node_ids, self.control_kv, poll_interval_s=0.05
+            nid, self.node_ids, self.control_kv, poll_interval_s=0.05,
+            metrics=metrics,
         )
         transport = self._wrap_faults(nid, self._mk_transport())
         node = Node(
@@ -279,17 +282,48 @@ class LocalCluster(SyncOps):
                              metrics=ec.metrics)
         sc.run()
         self.signing_consumers.append(sc)
+        self.node_signing[nid] = sc
         TimeoutConsumer(transport).run()
         registry.ready()
         return ec
 
+    def stop_node(self, node_id: str) -> None:
+        """``node_id`` leaves the way a daemon does on SIGTERM
+        (node/daemon.py ``run_node``), in that order: its signing bridge
+        closed (it takes no more from the durable queue; what it held
+        un-acked is redelivered), its event consumer closed, its ready
+        key resigned (peers drop it from their quorums at their next
+        poll), its transport closed. Its sealed share store is closed and
+        stays on disk, unread, for :meth:`respawn_node`. The node keeps
+        its entries in ``nodes``, ``node_consumers`` and
+        :meth:`metrics_snapshot` (its counters simply stop); a second
+        call and the later :meth:`close` find nothing left to do."""
+        node = self.nodes[node_id]
+        steps = (
+            ("signing_s", self.node_signing[node_id].close),
+            ("consumer_s", self.node_consumers[node_id].close),
+            ("resign_s", node.registry.resign),
+            ("transport_s", lambda: _close_transport(node.transport)),
+        )
+        t0_ns = tracing.now_ns()
+        took = {}
+        for name, step in steps:
+            t0 = time.perf_counter()
+            step()
+            took[name] = time.perf_counter() - t0
+        node.kvstore.close()
+        tracing.emit("cluster:stop_node", t0_ns, tracing.now_ns(),
+                     node=node_id, **took)
+        log.info("node stopped", node=node_id)
+
     def respawn_node(self, node_id: str) -> EventConsumer:
-        """In-process 'restart after SIGKILL': rebuild ``node_id``'s entire
-        runtime over its surviving on-disk state (identity keys, encrypted
-        share store, session WALs) the way a fresh daemon boot would, then
-        replay incomplete WAL sessions. The dead incarnation's objects are
-        deliberately left in place — a killed process never cleans up; its
-        crashed transport keeps black-holing whatever still reaches it."""
+        """In-process restart, after a SIGKILL or after :meth:`stop_node`:
+        rebuild ``node_id``'s entire runtime over its surviving on-disk
+        state (identity keys, encrypted share store, session WALs) the way
+        a fresh daemon boot would, then replay incomplete WAL sessions.
+        The dead incarnation's objects are deliberately left in place — a
+        killed process never cleans up; its crashed transport keeps
+        black-holing whatever still reaches it."""
         old_ft = self.fault_transports.pop(node_id, None)
         if old_ft is not None:
             self._retired_fault_transports.append(old_ft)
@@ -395,6 +429,15 @@ class LocalCluster(SyncOps):
             self.broker.close()
         if self.standby_broker is not None:
             self.standby_broker.close()
+
+
+def _close_transport(transport) -> None:
+    """A node's own connection: the TCP bundle's ``client``. A loopback
+    bundle is a view of the cluster's one fabric and holds nothing once
+    the node's consumers have unsubscribed."""
+    client = getattr(transport, "client", None)
+    if client is not None:
+        client.close()
 
 
 class RemoteCluster(SyncOps):

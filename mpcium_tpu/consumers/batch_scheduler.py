@@ -237,6 +237,7 @@ def _manifest_body(
     "_batch_claims",
     "_live_claims",
     "_settled",
+    "_released",
     "_sessions",
     "_decline_responders",
     "_digest_cache",
@@ -341,6 +342,14 @@ class BatchSigningScheduler:
         # a retry — absorb it. (kg/rs dedup keys are wallet-scoped and
         # ARE reused by retries, so they never enter this map.)
         self._settled: OrderedDict[str, float] = OrderedDict()
+        # SIGN dedup strings a batch RELEASED without an answer (quorum
+        # short of t + 1, a share not loadable, the session failed): the
+        # durable queue redelivers those requests, and the redelivery has
+        # to be buffered again, not absorbed as the duplicate of an
+        # answered one. The next settle of such a string is skipped, and
+        # consumes the mark (the batch thread's exit or the session's
+        # prune always brings one)
+        self._released: set = set()
         # ONE timing-wheel thread serves every window, liveness fallback,
         # deadline sweep, and decline expiry — keys ("win"|"fb"|"dl", bucket)
         # and ("decl", session_id)
@@ -404,6 +413,8 @@ class BatchSigningScheduler:
         self._m_decl_evict = m.counter("scheduler.declines_evicted_total")
         self._m_admit = m.histogram("batch.manifest_admit_s")
         self._m_prepare = m.histogram("batch.prepare_s")
+        self._m_quorum_select = m.histogram("batch.quorum_select_s")
+        self._m_quorum_size = m.histogram("scheduler.quorum_size")
         self._m_share_load = m.histogram("batch.share_load_s")
         self._m_egress = m.histogram("egress.result_s")
         self._m_pubsub_wait = m.histogram("transport.pubsub_wait_s")
@@ -1170,6 +1181,7 @@ class BatchSigningScheduler:
                     "host:manifest_admit", self._m_admit, seen["batch_id"],
                     t0_ns, n=seen["n"], outcome=seen["outcome"],
                     parse_s=seen["parse_s"], verify_s=seen["verify_s"],
+                    leader=seen["leader"],
                 )
 
     def _admit_manifest(self, raw: bytes, seen: dict) -> None:
@@ -1195,7 +1207,7 @@ class BatchSigningScheduler:
             return
         if not reqs:
             return
-        seen.update(kind=kind, batch_id=batch_id, n=len(reqs),
+        seen.update(kind=kind, batch_id=batch_id, n=len(reqs), leader=leader,
                     parse_s=time.perf_counter() - t0)
         # the cohort count is leader-advertised but engine-clamped: an
         # off-grid K degrades to the serial oracle, it cannot force a
@@ -1337,6 +1349,9 @@ class BatchSigningScheduler:
         absorption window (see _settled). Caller holds self._lock."""
         now = time.monotonic()
         for d in dedups:
+            if d in self._released:
+                self._released.discard(d)
+                continue
             self._settled[d] = now
             self._settled.move_to_end(d)
         while len(self._settled) > _SETTLED_CAP:
@@ -1831,6 +1846,12 @@ class BatchSigningScheduler:
         owned = list(owned_set)
 
         def release_all(reason: str = ""):
+            # unanswered: a redelivery is a retry, not a late duplicate
+            with self._lock:
+                for k in owned:
+                    d = self._dedup_str("sign", k)
+                    self._released.add(d)
+                    self._settled.pop(d, None)
             for w, t in owned:
                 self.on_tx_released(w, t)
             # tell peers (possibly mid-compile at their hello barrier) we
@@ -1839,6 +1860,7 @@ class BatchSigningScheduler:
                 f"bsign:{batch_id}", f"bsign:broadcast:{batch_id}", reason
             )
 
+        t_quorum0 = tracing.now_ns()
         try:
             quorum = node._ready_quorum(
                 info.participant_peer_ids, info.threshold + 1
@@ -1846,6 +1868,15 @@ class BatchSigningScheduler:
         except NotEnoughParticipants as e:
             release_all(str(e))
             return  # no reply ⇒ durable redelivery retries
+        # who signs this batch, as THIS node's registry has it: the READY
+        # participants (q below the committee while a node is out), the
+        # smallest of whom leads
+        self._m_quorum_size.observe(len(quorum))
+        self._batch_stage(
+            "host:quorum_select", self._m_quorum_select, batch_id, t_quorum0,
+            q=len(quorum), participants=len(info.participant_peer_ids),
+            leader=quorum[0],
+        )
         if node.node_id not in quorum:
             release_all("not in quorum")
             return

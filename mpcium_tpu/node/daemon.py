@@ -171,7 +171,7 @@ def run_node(
         initiator_pubkey=bytes.fromhex(cfg.event_initiator_pubkey),
         passphrase=passphrase,
     )
-    registry = PeerRegistry(name, list(peers), control_kv)
+    registry = PeerRegistry(name, list(peers), control_kv, metrics=metrics)
     node = Node(
         node_id=name,
         peer_ids=list(peers),

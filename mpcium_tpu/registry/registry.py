@@ -4,7 +4,14 @@
 reference's 1 Hz (registry.go:16), maintains the ready map/count, logs
 connect/disconnect transitions, and flips cluster-ready when everyone is
 present (registry.go:68-89). `resign()` removes the key on shutdown
-(registry.go:198-207)."""
+(registry.go:198-207).
+
+What it counts (OBSERVABILITY.md): gauge ``registry.ready_peers``, counters
+``registry.peer_joined_total`` / ``registry.peer_lost_total``, and histogram
+``registry.loss_detect_s``: from the last heartbeat this observer saw a
+peer's key change to the poll that dropped the peer, on the local
+monotonic clock (a resignation: a poll or two; a crash: the staleness
+window)."""
 from __future__ import annotations
 
 import threading
@@ -13,6 +20,7 @@ from typing import Dict, List, Optional, Set
 
 from ..store.kvstore import KVStore
 from ..utils import log
+from ..utils.metrics import MetricsRegistry
 
 READY_PREFIX = "ready/"
 DEFAULT_POLL_S = 1.0  # reference registry.go:16
@@ -34,6 +42,7 @@ class PeerRegistry:
         peer_ids: List[str],
         kv: KVStore,
         poll_interval_s: float = DEFAULT_POLL_S,
+        metrics: Optional[MetricsRegistry] = None,  # the node's own
     ):
         self.node_id = node_id
         self.peer_ids = sorted(set(peer_ids) | {node_id})
@@ -58,6 +67,11 @@ class PeerRegistry:
         # merely EXISTING proves nothing (a SIGKILLed peer's stale key
         # persists; "confirmed" flips only once a change is observed)
         self._hb_seen: Dict[str, tuple] = {}
+        m = metrics or MetricsRegistry()
+        self._m_ready = m.gauge("registry.ready_peers")
+        self._m_joined = m.counter("registry.peer_joined_total")
+        self._m_lost = m.counter("registry.peer_lost_total")
+        self._m_loss_detect = m.histogram("registry.loss_detect_s")
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -194,19 +208,26 @@ class PeerRegistry:
                 prev[2] or self._coarse_fresh(raw)
             ):
                 now.add(pid)
-        # explicit resign (key deleted) forgets the peer immediately
-        for pid in list(self._hb_seen):
-            if pid not in seen_pids:
-                del self._hb_seen[pid]
         with self._lock:
             joined = now - self._ready_map
             left = self._ready_map - now
             self._ready_map = now
             was_ready = self._cluster_ready
             self._cluster_ready = now == set(self.peer_ids)
+        self._m_ready.set(len(now))
         for p in sorted(joined):
+            if p != self.node_id:
+                self._m_joined.inc()
             log.info("peer ready", peer=p, node=self.node_id)
         for p in sorted(left):
+            seen = self._hb_seen.get(p)  # none for our own key
+            if seen is not None:
+                self._m_lost.inc()
+                self._m_loss_detect.observe(local_now - seen[1])
             log.warn("peer disconnected!", peer=p, node=self.node_id)  # registry.go:135
+        # explicit resign (key deleted) forgets the peer immediately
+        for pid in list(self._hb_seen):
+            if pid not in seen_pids:
+                del self._hb_seen[pid]
         if self._cluster_ready and not was_ready:
             log.info("ALL PEERS ARE READY", node=self.node_id)  # registry.go:86
