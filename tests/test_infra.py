@@ -219,9 +219,9 @@ def test_encrypted_kv(tmp_path):
     blobs = [
         p.read_bytes()
         for p in (tmp_path / "db").iterdir()
-        if not p.name.startswith(".")
+        if p.name != ".salt"
     ]
-    assert all(b"share-data" not in b for b in blobs)
+    assert blobs and all(b"share-data" not in b for b in blobs)
     # reopen with right/wrong password
     kv2 = EncryptedFileKV(tmp_path / "db", "pw123")
     assert kv2.get("ecdsa:w1") == b"share-data"
