@@ -43,7 +43,7 @@ class MPCClient:
 
     def sign_transaction(self, msg: wire.SignTxMessage) -> None:
         with tracing.span("client:submit", node="client", tid="sign",
-                          tx=msg.tx_id) as sp:
+                          tx=msg.tx_id, cpu=True) as sp:
             t0 = time.perf_counter()
             msg.signature = self.initiator.sign(msg.raw())
             sp.set(sign_s=time.perf_counter() - t0)

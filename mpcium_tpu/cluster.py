@@ -30,7 +30,7 @@ from .trace import arm as _trace_arm
 from .trace import recorder as _trace_recorder
 from .trace import snapshot_chrome as _trace_snapshot_chrome
 from .transport.loopback import LoopbackFabric
-from .utils import log, tracing
+from .utils import interp, log, tracing
 from .utils.metrics import MetricsRegistry
 
 
@@ -50,7 +50,6 @@ class SyncOps:
 
     @staticmethod
     def _await_result(subscribe, fire, matches, timeout_s, what: str):
-        import threading
 
         done = threading.Event()
         box: list = []
@@ -155,6 +154,8 @@ class LocalCluster(SyncOps):
         # under the cluster root so drills can attach them to reports
         _trace_arm(node_ids=self.node_ids,
                    dump_dir=str(self.root / "trace_incidents"))
+        self.node_consumers: Dict[str, EventConsumer] = {}
+        self._canary = interp.Canary(self._observe_handover_lag)
         # None overrides are skipped by init_config → config defaults apply
         init_config(path=str(self.root / "nonexistent.yaml"),
                     mpc_threshold=threshold,
@@ -189,7 +190,6 @@ class LocalCluster(SyncOps):
         # their transport wrapped; with no plan nothing is constructed and
         # behavior is byte-identical to a bare cluster
         self._fault_plans = fault_plans or {}
-        self._fabric_stats_lock = threading.Lock()
         self.fault_transports: Dict[str, object] = {}
         self._retired_fault_transports: List[object] = []
         self._hello_timeout_s = hello_timeout_s
@@ -221,7 +221,6 @@ class LocalCluster(SyncOps):
         self.nodes: Dict[str, Node] = {}
         self.consumers: List[EventConsumer] = []
         self.signing_consumers: List[SigningConsumer] = []
-        self.node_consumers: Dict[str, EventConsumer] = {}
         self.node_signing: Dict[str, SigningConsumer] = {}
         for nid in self.node_ids:
             self._spawn_node(nid)
@@ -336,25 +335,30 @@ class LocalCluster(SyncOps):
         """Per-node operational snapshots (EventConsumer.health): live
         sessions, dedup claims, and every scheduler metric — lane queue
         depths, shed counters, fill ratios, latency percentiles."""
-        self._fold_fabric_stats()
+        self._fold_process_stats()
         return {nid: ec.health() for nid, ec in self.node_consumers.items()}
 
-    def _fold_fabric_stats(self) -> None:
-        """Bring ``transport.dedup_hits`` / ``transport.dedup_keys`` /
-        ``transport.subscriptions`` up to what the loopback fabric counts
-        now, in the first node's registry only: one fabric stands behind
-        every node, and a sum over the nodes counts it once. Nothing over
-        TCP (the broker is another process and keeps its own)."""
-        if self.fabric is None or not self.node_consumers:
+    def _observe_handover_lag(self, lag_s: float) -> None:
+        if self.node_consumers:
+            next(iter(self.node_consumers.values())).metrics.histogram(
+                "interp.handover_lag_s").observe(lag_s)
+
+    def _fold_process_stats(self) -> None:
+        """Bring what no node owns up to now, in the FIRST node's
+        registry only (the one process and the one fabric stand behind
+        every node, and a sum over the nodes counts them once): the
+        interpreter account (``interp.cpu_s.<role>``,
+        ``interp.threads.<role>``), the log handler's totals, and the
+        loopback fabric's ``transport.dedup_hits`` / ``transport.dedup_keys``
+        / ``transport.subscriptions`` (nothing of the fabric over TCP: the
+        broker is another process and keeps its own)."""
+        if not self.node_consumers:
             return
-        stats = self.fabric.stats()
-        metrics = next(iter(self.node_consumers.values())).metrics
-        with self._fabric_stats_lock:  # two snapshots, one delta
-            for name, total in stats["counters"].items():
-                counter = metrics.counter(name)
-                counter.inc(max(0.0, total - counter.value))
-        for name, value in stats["gauges"].items():
-            metrics.gauge(name).set(value)
+        stats = (self.fabric.stats() if self.fabric is not None
+                 else {"counters": {}, "gauges": {}})
+        next(iter(self.node_consumers.values())).metrics.fold(
+            counters={**stats["counters"], **log.totals()},
+            gauges={**stats["gauges"], **interp.gauges()})
 
     def metrics_snapshot(self) -> Dict[str, dict]:
         """Just the metric registries, keyed by node id (the soak harness
@@ -362,8 +366,9 @@ class LocalCluster(SyncOps):
         up to date first: each node's own ring, and on the first node also
         the rings no node owns (``engine``, ``client``, ``local``), so the
         sum over the snapshot counts every ring once. The loopback
-        fabric, which no node owns either, is counted the same way
-        (:meth:`_fold_fabric_stats`)."""
+        fabric, the process's threads and its log handler, which no node
+        owns either, are counted the same way
+        (:meth:`_fold_process_stats`)."""
         dropped = _trace_recorder.dropped_totals()
         shared = sum(d for ring, d in dropped.items()
                      if ring not in self.node_consumers)
@@ -371,7 +376,7 @@ class LocalCluster(SyncOps):
             ec.metrics.gauge("trace.dropped_spans").set(
                 float(dropped.get(nid, 0) + shared))
             shared = 0
-        self._fold_fabric_stats()
+        self._fold_process_stats()
         return {
             nid: ec.metrics.snapshot()
             for nid, ec in self.node_consumers.items()
@@ -410,6 +415,7 @@ class LocalCluster(SyncOps):
         return ft
 
     def close(self) -> None:
+        self._canary.close()
         for ec in self.consumers:
             try:
                 ec.close()

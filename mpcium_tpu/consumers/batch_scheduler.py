@@ -69,7 +69,7 @@ from ..node.session import Session
 from ..protocol.base import KeygenShare, ProtocolError
 from ..protocol.eddsa.batch_signing import BatchedEDDSASigningParty
 from ..transport.api import Transport, observe_delivery_wait
-from ..utils import log, tracing
+from ..utils import interp, log, tracing
 from ..utils.annotations import locked_by
 from ..utils.metrics import MetricsRegistry
 
@@ -93,14 +93,14 @@ class _TimingWheel:
     real work to a batch thread.
     """
 
-    def __init__(self, name: str = "timing-wheel") -> None:
+    def __init__(self, name: str = "") -> None:
         self._cond = threading.Condition()
         self._heap: List[Tuple[float, int, object]] = []
         self._armed: Dict[object, Tuple[int, Callable[[], None]]] = {}
         self._seq = itertools.count()
         self._closed = False
         self._thread = threading.Thread(
-            target=self._run, name=name, daemon=True
+            target=self._run, name=f"batch-wheel-{name}", daemon=True
         )
         self._thread.start()
 
@@ -353,7 +353,7 @@ class BatchSigningScheduler:
         # ONE timing-wheel thread serves every window, liveness fallback,
         # deadline sweep, and decline expiry — keys ("win"|"fb"|"dl", bucket)
         # and ("decl", session_id)
-        self._wheel = _TimingWheel(name=f"batch-wheel-{node.node_id}")
+        self._wheel = _TimingWheel(name=node.node_id)
         self._sessions: List[Session] = []
         self.batches_run = 0  # engine-dispatch diagnostic (tests assert ≪ N)
         # GG18 exponent domains (None = production defaults); tests with
@@ -1153,11 +1153,14 @@ class BatchSigningScheduler:
     # -- all quorum members: manifest execution ------------------------------
 
     def _batch_stage(self, name: str, histogram, batch_id: str, t0_ns: int,
-                     **attrs) -> None:
+                     cpu0_ns: Optional[int] = None, **attrs) -> None:
         """A batch-level host stage of ``bsign:<batch_id>`` ends now: its
         seconds since ``t0_ns`` go to ``histogram``, and the interval is
         the span ``name`` on the track and under the trace id the
-        session's spans have."""
+        session's spans have. ``cpu0_ns``: this thread's CPU clock when
+        the stage began ON THIS THREAD; what it ran since is ``cpu_s``."""
+        if cpu0_ns is not None:
+            attrs["cpu_s"] = (tracing.thread_cpu_ns() - cpu0_ns) / 1e9
         t1_ns = tracing.now_ns()
         histogram.observe((t1_ns - t0_ns) / 1e9)
         sid = f"bsign:{batch_id}"
@@ -1171,7 +1174,7 @@ class BatchSigningScheduler:
         span (and ``batch.manifest_admit_s``): raw bytes in → its batch
         thread started, or refused with the ``outcome`` that says why."""
         observe_delivery_wait(self._m_pubsub_wait)
-        t0_ns = tracing.now_ns()
+        t0_ns, cpu0_ns = tracing.now_ns(), tracing.thread_cpu_ns()
         seen: dict = {"outcome": "bad_manifest", "verify_s": 0.0}
         try:
             self._admit_manifest(raw, seen)
@@ -1179,7 +1182,7 @@ class BatchSigningScheduler:
             if seen.get("kind") == "sign":
                 self._batch_stage(
                     "host:manifest_admit", self._m_admit, seen["batch_id"],
-                    t0_ns, n=seen["n"], outcome=seen["outcome"],
+                    t0_ns, cpu0_ns, n=seen["n"], outcome=seen["outcome"],
                     parse_s=seen["parse_s"], verify_s=seen["verify_s"],
                     leader=seen["leader"],
                 )
@@ -1411,6 +1414,7 @@ class BatchSigningScheduler:
             raise
         finally:
             self._forget_batch_claims(kind, keys)
+            interp.retire()  # the batch thread's last act
 
     # -- batched DKG (kind == "kg") ------------------------------------------
 
@@ -1826,7 +1830,7 @@ class BatchSigningScheduler:
         inherited: List[Tuple[str, str]] = (),
     ) -> None:
         node = self.node
-        t_prep0 = tracing.now_ns()
+        t_prep0, cpu_prep0 = tracing.now_ns(), tracing.thread_cpu_ns()
         first = reqs[0][0]
         info = node.keyinfo.get(first.key_type, first.wallet_id)
         if info is None:
@@ -1922,7 +1926,7 @@ class BatchSigningScheduler:
             return
 
         def on_done(result):
-            t_egress0 = tracing.now_ns()
+            t_egress0, cpu_egress0 = tracing.now_ns(), tracing.thread_cpu_ns()
             enqueue_s = 0.0
             ok = result["ok"]
             for i, (msg, reply) in enumerate(reqs):
@@ -1969,7 +1973,8 @@ class BatchSigningScheduler:
                     self.on_tx_done(msg.wallet_id, msg.tx_id)
                 self._observe_e2e("sign", (msg.wallet_id, msg.tx_id))
             self._batch_stage("host:result_egress", self._m_egress, batch_id,
-                              t_egress0, n=len(reqs), enqueue_s=enqueue_s)
+                              t_egress0, cpu_egress0, n=len(reqs),
+                              enqueue_s=enqueue_s)
             log.info("batch signed", batch=batch_id, size=len(reqs),
                      node=node.node_id)
             _prune()
@@ -2032,4 +2037,5 @@ class BatchSigningScheduler:
             self.batches_run += 1
         session.listen()
         self._batch_stage("host:batch_prepare", self._m_prepare, batch_id,
-                          t_prep0, n=len(reqs), load_s=load_s, party_s=party_s)
+                          t_prep0, cpu_prep0, n=len(reqs), load_s=load_s,
+                          party_s=party_s)

@@ -28,7 +28,7 @@ from .. import wire
 from ..node.node import Node, NotEnoughParticipants
 from ..node.session import RetryableSessionError
 from ..transport.api import Transport, observe_delivery_wait
-from ..utils import log, tracing
+from ..utils import interp, log, tracing
 
 SESSION_TIMEOUT_S = 30 * 60  # event_consumer.go:71
 GC_INTERVAL_S = 5 * 60  # event_consumer.go:72
@@ -468,8 +468,10 @@ class EventConsumer:
                          node=self.node.node_id)
             finally:
                 self._finish(dedup)
+                interp.retire()  # the waiter's last act
 
-        threading.Thread(target=waiter, daemon=True).start()
+        threading.Thread(target=waiter, name=f"keygen-wait-{wallet_id[:24]}",
+                         daemon=True).start()
 
     # -- signing ------------------------------------------------------------
 

@@ -505,7 +505,8 @@ def _drill_kill_resume(seed: int, scale: float):
                 box["err"] = e
             box["t_done"] = time.monotonic()
 
-        th = threading.Thread(target=signer, daemon=True)
+        th = threading.Thread(target=signer, name="chaos-signer",
+                              daemon=True)
         th.start()
 
         if not _wait(lambda: ft.crash_switch.crashed, timeout_s=30.0):
@@ -714,7 +715,8 @@ def _drill_cheater(seed: int, scale: float):
             except Exception as e:  # noqa: BLE001 — surfaced via the box
                 live["err"] = e
 
-        live_th = threading.Thread(target=_live_signer, daemon=True)
+        live_th = threading.Thread(target=_live_signer,
+                                   name="chaos-live-signer", daemon=True)
         live_th.start()
 
         # -- the deviation, and the checks catching it --------------------

@@ -169,6 +169,7 @@ class _Timers:
 
         t = threading.Timer(delay_s, run)
         t.daemon = True
+        t.name = "timer-fault-delay"
         with self._lock:
             if self._closed:
                 return t

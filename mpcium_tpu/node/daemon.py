@@ -24,7 +24,7 @@ from ..store.keyinfo import KeyinfoStore
 from ..store.kvstore import EncryptedFileKV, FileKV
 from ..trace import arm as trace_arm
 from ..transport.tcp import tcp_transport
-from ..utils import log
+from ..utils import interp, log
 from ..utils.metrics import MetricsRegistry
 from .node import Node
 
@@ -34,7 +34,10 @@ def publish_health(consumer, control_kv, name: str) -> dict:
     JSON under ``health/<name>`` and the same registry as Prometheus text
     exposition under ``health/<name>.prom`` — so ``kv get health/node0``
     stays the whole monitoring story and a scrape sidecar can serve
-    ``.prom`` verbatim. Returns the JSON snapshot (tests assert on it)."""
+    ``.prom`` verbatim. The process's interpreter account and log totals
+    (``interp.*``, ``log.*``) are brought up to date in the consumer's
+    registry first. Returns the JSON snapshot (tests assert on it)."""
+    consumer.metrics.fold(counters=log.totals(), gauges=interp.gauges())
     snap = consumer.health()
     snap["ts"] = time.time()
     control_kv.put(
@@ -99,6 +102,8 @@ def run_node(
     # dumps (shed / timeout / drill failure) land under the db dir
     trace_arm(node_ids=[name],
               dump_dir=str(Path(cfg.db_dir) / name / "trace_incidents"))
+    metrics = MetricsRegistry()  # the node's: its store's books too
+    canary = interp.Canary(metrics.histogram("interp.handover_lag_s").observe)
     # compile ledger: this node is alive but cold until boot completes —
     # health publishes state=warming so a restart paying the compile
     # wall is distinguishable from a dead node. The ledger file lands
@@ -153,7 +158,6 @@ def run_node(
     if name not in peers:
         raise SystemExit(f"node {name!r} not in peer set {sorted(peers)}")
 
-    metrics = MetricsRegistry()  # the node's: its store's books too
     share_store = EncryptedFileKV(Path(cfg.db_dir) / name, cfg.badger_password,
                                   metrics=metrics)
     # crash-recovery WAL (default off): journals live sessions under the
@@ -248,6 +252,7 @@ def run_node(
     stop.wait()
     log.info("shutting down", node=name)
     health_stop.set()
+    canary.close()
     signing.close()
     consumer.close()
     registry.resign()

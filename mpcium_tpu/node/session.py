@@ -23,7 +23,7 @@ from ..identity.identity import IdentityStore
 from ..protocol.base import PartyBase, ProtocolError, RoundMsg
 from ..store.session_wal import SessionWALWriter
 from ..transport.api import Transport, TransportError, observe_delivery_wait
-from ..utils import log, tracing
+from ..utils import interp, log, tracing
 from ..utils.annotations import locked_by
 from ..wire import Envelope
 
@@ -207,6 +207,7 @@ class Session:
                 self.hello_timeout_s, self._hello_deadline
             )
             self._hello_timer.daemon = True
+            self._hello_timer.name = f"timer-hello-{self.session_id[:24]}"
             self._hello_timer.start()
 
     def _hello_deadline(self) -> None:
@@ -417,27 +418,30 @@ class Session:
             self._wal = None
 
     def _send_loop(self) -> None:
-        while True:
-            item = self._out_q.get()
-            if item is None:
-                return
-            to, raw = item
-            # acked unicast (reference session.go:126, point2point.go:
-            # 26-45). With patience, the WHOLE budget rides one transport
-            # call: one delivery, waited on — never re-delivered to a busy
-            # receiver (duplicate floods starve shared delivery pools)
-            try:
-                if self.send_patience_s > 0:
-                    self.transport.direct.send(
-                        self.direct_topic_fn(to), raw,
-                        timeout_s=self.send_patience_s,
-                    )
-                else:
-                    self.transport.direct.send(self.direct_topic_fn(to), raw)
-            except TransportError as e:
-                if not self._failed and not self.party.done:
-                    self._fail(e)
-                return
+        try:
+            while True:
+                item = self._out_q.get()
+                if item is None:
+                    return
+                to, raw = item
+                # acked unicast (reference session.go:126, point2point.go:
+                # 26-45). With patience, the WHOLE budget rides one transport
+                # call: one delivery, waited on — never re-delivered to a busy
+                # receiver (duplicate floods starve shared delivery pools)
+                try:
+                    if self.send_patience_s > 0:
+                        self.transport.direct.send(
+                            self.direct_topic_fn(to), raw,
+                            timeout_s=self.send_patience_s,
+                        )
+                    else:
+                        self.transport.direct.send(self.direct_topic_fn(to), raw)
+                except TransportError as e:
+                    if not self._failed and not self.party.done:
+                        self._fail(e)
+                    return
+        finally:
+            interp.retire()  # the sender's last act
 
     # -- inbound ------------------------------------------------------------
 

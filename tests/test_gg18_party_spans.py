@@ -202,7 +202,12 @@ def test_each_handler_has_one_phase_span_under_its_round(stubbed, spans):
         assert parent["name"].startswith(parent_round[handler]), s["name"]
         assert parent["node"] == s["node"] and parent["tid"] == s["tid"]
         assert parent["t0_ns"] <= s["t0_ns"] <= s["t1_ns"] <= parent["t1_ns"]
-        assert s["attrs"] == {"batch": "b-1", "n": B, "cohort": 0}
+        attrs = dict(s["attrs"])
+        # the handler's own CPU seconds (PR 40): never more than the span
+        # lasted, give or take one 10 ms step of the thread clock
+        assert 0 <= attrs.pop("cpu_s") <= (
+            s["t1_ns"] - s["t0_ns"]) / 1e9 + 0.010
+        assert attrs == {"batch": "b-1", "n": B, "cohort": 0}
         assert s["trace_id"] == tracing.trace_id_for(SID)
 
     # the registry of each node: ten phases observed, and its responses:

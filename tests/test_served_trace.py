@@ -20,6 +20,13 @@ from mpcium_tpu.utils import tracing
 N = 64
 BATCH_SPANS = ("host:manifest_admit", "host:batch_prepare", "wait:hello",
                "host:result_egress", "session")
+LAST_TX = f"st-tx-{N - 1}"
+CLOCK_STEP_S = 0.010  # the coarsest thread CPU clock seen (a 10 ms tick)
+
+
+def _intakes(nid):
+    spans, _dropped = recorder.snapshot_all().get(nid, ([], 0))
+    return [s for s in spans if s["name"] == "intake"]
 
 
 @pytest.fixture(scope="module")
@@ -34,6 +41,11 @@ def served(tmp_path_factory):
     )
     try:
         ids = cluster.node_ids
+        # arming resets the nodes' rings only: the shared `client` ring
+        # still holds what an earlier module of this worker submitted
+        # (under `--dist loadfile` that was now and then another cluster's
+        # `client:submit` spans, and the tx sets below did not match)
+        recorder.recorder_for("client").snapshot(clear=True)
         shares = eb.dealer_keygen_batch(N, ids, threshold=1)
         for w in range(N):
             for i, nid in enumerate(ids):
@@ -48,14 +60,29 @@ def served(tmp_path_factory):
             if len(results) == N:
                 done.set()
 
+        def submit(w):
+            cluster.client.sign_transaction(wire.SignTxMessage(
+                key_type="ed25519", wallet_id=f"st{w}",
+                network_internal_code="sol", tx_id=f"st-tx-{w}",
+                tx=bytes([w]) * 32, deadline_ms=900_000,
+            ))
+
         sub = cluster.client.on_sign_result(on_result)
         try:
-            for w in range(N):
-                cluster.client.sign_transaction(wire.SignTxMessage(
-                    key_type="ed25519", wallet_id=f"st{w}",
-                    network_internal_code="sol", tx_id=f"st-tx-{w}",
-                    tx=bytes([w]) * 32, deadline_ms=900_000,
-                ))
+            for w in range(N - 1):
+                submit(w)
+            # the leader fires its manifest at its Nth request, and a
+            # follower whose own copy of a request comes after that
+            # manifest's batch has claimed it takes it in as `duplicate`,
+            # by design. Hold the last request back until every node has
+            # taken in the others, so that none of THEIR copies races the
+            # manifest (under load one did, now and then)
+            deadline = time.monotonic() + 120
+            while (min(len(_intakes(nid)) for nid in ids) < N - 1
+                   and time.monotonic() < deadline):
+                time.sleep(0.02)
+            assert min(len(_intakes(nid)) for nid in ids) == N - 1
+            submit(N - 1)
             assert done.wait(600), f"{len(results)}/{N} results"
         finally:
             sub.unsubscribe()
@@ -146,6 +173,8 @@ def test_tx_joins_client_submit_intake_and_queue(served):
                <= (s["t1_ns"] - s["t0_ns"]) / 1e9 for s in submits.values())
     results = Counter(s["attrs"]["tx"] for s in _named(client, "client:result"))
     assert set(results) == txs
+    (leader,) = {s["node"] for nid in served.ids
+                 for s in _named(served.spans[nid], "dispatch")}
     for nid in served.ids:
         intakes = {s["attrs"]["tx"]: s
                    for s in _named(served.spans[nid], "intake")}
@@ -154,7 +183,12 @@ def test_tx_joins_client_submit_intake_and_queue(served):
             assert s["kind"] == "X" and s["tid"] == "lane:bulk"
             assert s["attrs"]["req_kind"] == "sign"
             assert s["attrs"]["deadline_ms"] == 900_000
-            assert s["attrs"]["outcome"] == "batched"
+            # every node had taken in all but the last request before the
+            # leader could fire; a follower's copy of the last one may
+            # come after the manifest's batch claimed it (`duplicate`)
+            late = tx == LAST_TX and nid != leader
+            assert s["attrs"]["outcome"] in (
+                ("batched", "duplicate") if late else ("batched",)), (nid, tx)
             assert s["attrs"]["verify_s"] > 0
             assert s["t0_ns"] >= submits[tx]["t0_ns"]
     queued = [s for nid in served.ids
@@ -178,6 +212,64 @@ def test_a_stage_histogram_counts_requests_or_batches_per_node(
         h = served.metrics[nid]["histograms"][name]
         assert h["count"] == per_node, (nid, name)
         assert h["sum"] > 0
+
+
+@pytest.mark.parametrize("name,per_node", [
+    ("host:manifest_admit", 1),
+    ("host:batch_prepare", 1),
+    ("host:result_egress", 1),
+    ("phase:bsign_nonce_commit", 1),
+    ("phase:bsign_aggregate_partial", 1),
+    ("phase:bsign_combine_verify", 1),
+])
+def test_a_batch_level_span_carries_its_threads_cpu_seconds_on_every_node(
+        served, name, per_node):
+    """``cpu_s``: what the span's own thread ran between its two ends, so
+    never more than the span lasted (give or take one step of the clock)."""
+    for nid in served.ids:
+        spans = _named(served.spans[nid], name)
+        assert len(spans) == per_node, (nid, name)
+        for s in spans:
+            wall_s = (s["t1_ns"] - s["t0_ns"]) / 1e9
+            assert 0 <= s["attrs"]["cpu_s"] <= wall_s + CLOCK_STEP_S, (nid, s)
+
+
+def test_client_submit_carries_cpu_seconds_and_no_other_request_span_does(
+        served):
+    submits = _named(served.spans["client"], "client:submit")
+    assert len(submits) == N
+    for s in submits:
+        wall_s = (s["t1_ns"] - s["t0_ns"]) / 1e9
+        assert 0 <= s["attrs"]["cpu_s"] <= wall_s + CLOCK_STEP_S
+    # the SDK ran: the window's sum is no clock artefact
+    assert sum(s["attrs"]["cpu_s"] for s in submits) > 0
+    for ring in served.spans.values():
+        for s in ring:
+            if s["name"] in ("client:result", "intake", "queue", "session",
+                             "host:envelope_in", "host:quorum_select",
+                             "wait:hello", "dispatch") or s["name"].startswith(
+                                 "round:"):
+                assert "cpu_s" not in s["attrs"], s["name"]
+
+
+def test_the_interpreter_account_stands_in_the_first_nodes_snapshot_only(
+        served):
+    first, others = served.ids[0], served.ids[1:]
+    own = served.metrics[first]
+    roles = {k[len("interp.cpu_s."):] for k in own["gauges"]
+             if k.startswith("interp.cpu_s.")}
+    # the caller, the fabric's two pools, a batch thread and a sender a node
+    assert {"main", "loopback", "loopback-q", "bsign", "send"} <= roles
+    assert own["gauges"]["interp.cpu_s.bsign"] > 0
+    assert own["gauges"]["interp.cpu_s.loopback"] > 0
+    assert own["gauges"]["interp.threads.loopback-q"] >= 1
+    assert own["histograms"]["interp.handover_lag_s"]["count"] >= 10
+    assert own["counters"]["log.lines_total"] >= N  # "signing requested"
+    assert own["counters"]["log.emit_s_total"] > 0
+    for nid in others:
+        for kind in ("gauges", "counters", "histograms"):
+            assert not [k for k in served.metrics[nid][kind]
+                        if k.startswith(("interp.", "log."))], (nid, kind)
 
 
 @pytest.mark.parametrize("name", ["transport.queue_wait_s",
