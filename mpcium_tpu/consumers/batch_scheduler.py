@@ -412,6 +412,8 @@ class BatchSigningScheduler:
         self._m_e2e = m.histogram("scheduler.e2e_latency_s")
         self._m_decl_evict = m.counter("scheduler.declines_evicted_total")
         self._m_admit = m.histogram("batch.manifest_admit_s")
+        self._m_verify_reused = m.counter("batch.admit_verify_reused_total")
+        self._m_verify_checked = m.counter("batch.admit_verify_checked_total")
         self._m_prepare = m.histogram("batch.prepare_s")
         self._m_quorum_select = m.histogram("batch.quorum_select_s")
         self._m_quorum_size = m.histogram("scheduler.quorum_size")
@@ -1175,7 +1177,8 @@ class BatchSigningScheduler:
         thread started, or refused with the ``outcome`` that says why."""
         observe_delivery_wait(self._m_pubsub_wait)
         t0_ns, cpu0_ns = tracing.now_ns(), tracing.thread_cpu_ns()
-        seen: dict = {"outcome": "bad_manifest", "verify_s": 0.0}
+        seen: dict = {"outcome": "bad_manifest", "verify_s": 0.0,
+                      "reused": 0, "verified": 0}
         try:
             self._admit_manifest(raw, seen)
         finally:
@@ -1184,6 +1187,7 @@ class BatchSigningScheduler:
                     "host:manifest_admit", self._m_admit, seen["batch_id"],
                     t0_ns, cpu0_ns, n=seen["n"], outcome=seen["outcome"],
                     parse_s=seen["parse_s"], verify_s=seen["verify_s"],
+                    reused=seen["reused"], verified=seen["verified"],
                     leader=seen["leader"],
                 )
 
@@ -1267,14 +1271,35 @@ class BatchSigningScheduler:
                 log.warn("mixed-topology batch manifest dropped",
                          batch=batch_id, wallet=msg.wallet_id)
                 return
-        # the leader is untrusted for content: re-verify every initiator
-        # signature
+        # the leader is untrusted for content: every initiator signature
+        # must have been verified by THIS node. A request in our buckets
+        # was (submit's contract: the consumer verifies before it buffers),
+        # so an entry that is byte for byte that request (what the
+        # initiator signed, and the signature) carries the verdict intake
+        # reached; any other entry is verified here. Bytes, not fields:
+        # to == a tx_id of 1, 1.0 and true are one, to the initiator's
+        # signature they are three.
         t0 = time.perf_counter()
-        verified = all(
-            self.node.identity.verify_initiator(msg.raw(), msg.signature)
-            for msg, _reply in reqs
-        )
+        covered = {_entry_key("sign", m) for m, _ in reqs}
+        held = self._buffered_msgs("sign", covered)
+        reused = checked = 0
+        verified = True
+        for msg, _reply in reqs:
+            own = held.get(_entry_key("sign", msg))
+            if (own is not None and own.signature == msg.signature
+                    and own.raw() == msg.raw()):
+                reused += 1
+                continue
+            checked += 1
+            if not self.node.identity.verify_initiator(
+                msg.raw(), msg.signature
+            ):
+                verified = False
+                break
         seen["verify_s"] += time.perf_counter() - t0
+        seen.update(reused=reused, verified=checked)
+        self._m_verify_reused.inc(reused)
+        self._m_verify_checked.inc(checked)
         if not verified:
             seen["outcome"] = "bad_initiator_signature"
             log.warn("batch manifest with BAD initiator signature dropped",
@@ -1286,7 +1311,6 @@ class BatchSigningScheduler:
         # the consumer's _on_sign before submit() — the batch inherits those
         # claims and must finish/release them (a claim whose entry was never
         # in a bucket belongs to a live per-session run, not to us).
-        covered = {_entry_key("sign", m) for m, _ in reqs}
         inherited = self._inherit_covered("sign", covered)
         threading.Thread(
             target=self._run_guarded,
@@ -1325,6 +1349,21 @@ class BatchSigningScheduler:
                     ) == dedup_key:
                         return True
         return False
+
+    def _buffered_msgs(
+        self, kind: str, covered
+    ) -> Dict[Tuple[str, str], object]:
+        """The messages of ``kind`` this node holds in its buckets under
+        the ``covered`` keys: each was verified at intake, and stays
+        where it is (a refused manifest must leave the buckets whole)."""
+        with self._lock:
+            return {
+                k: e.msg
+                for bucket in self._buckets.values()
+                for e in bucket
+                if e.kind == kind
+                and (k := _entry_key(e.kind, e.msg)) in covered
+            }
 
     def _inherit_covered(self, kind: str, covered) -> List[Tuple[str, str]]:
         """Remove manifest-covered entries of ``kind`` from local buckets,

@@ -100,6 +100,8 @@ def waves(tmp_path_factory):
             yield SimpleNamespace(
                 ids=ids, results=results, failed=failed,
                 before=before, after=after, fabric=cluster.fabric.stats(),
+                registries={nid: snap["counters"] for nid, snap
+                            in cluster.metrics_snapshot().items()},
                 spans={nid: [s for s in spans if s["t0_ns"] >= t_start_ns]
                        for nid, (spans, _d)
                        in recorder.snapshot_all().items()})
@@ -172,3 +174,26 @@ def test_a_follower_takes_a_request_from_whichever_copy_comes_first(waves):
         assert set(intakes) == txs and set(intakes.values()) == {1}, nid
         allowed = {"batched"} if nid == leader else {"batched", "duplicate"}
         assert set(outcomes) <= allowed, (nid, outcomes)
+
+
+def test_admission_verifies_only_what_a_node_did_not_take_in(waves):
+    """Every manifest entry is one a node verified at intake (``reused``)
+    or verifies at admission (``verified``: the manifest beat the node's
+    own ``mpc:sign`` copy). The leader fired the manifest from what it had
+    buffered, so it verifies nothing twice."""
+    (leader,) = {nid for nid in waves.ids
+                 if any(s["name"] == "dispatch" for s in waves.spans[nid])}
+    for nid in waves.ids:
+        admits = [s["attrs"] for s in waves.spans[nid]
+                  if s["name"] == "host:manifest_admit"]
+        assert len(admits) == WAVES, nid
+        for a in admits:
+            assert a["outcome"] == "admitted", (nid, a)
+            assert a["reused"] + a["verified"] == a["n"] == N, (nid, a)
+            if nid == leader:
+                assert a["verified"] == 0, a
+        reg = waves.registries[nid]
+        assert (reg["batch.admit_verify_reused_total"],
+                reg["batch.admit_verify_checked_total"]) == (
+            sum(a["reused"] for a in admits),
+            sum(a["verified"] for a in admits)), nid
