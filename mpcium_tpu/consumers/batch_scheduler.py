@@ -412,6 +412,7 @@ class BatchSigningScheduler:
         self._m_e2e = m.histogram("scheduler.e2e_latency_s")
         self._m_decl_evict = m.counter("scheduler.declines_evicted_total")
         self._m_admit = m.histogram("batch.manifest_admit_s")
+        self._m_manifest_bytes = m.counter("batch.manifest_bytes_total")
         self._m_verify_reused = m.counter("batch.admit_verify_reused_total")
         self._m_verify_checked = m.counter("batch.admit_verify_checked_total")
         self._m_prepare = m.histogram("batch.prepare_s")
@@ -1174,7 +1175,9 @@ class BatchSigningScheduler:
     def _on_manifest_raw(self, raw: bytes) -> None:
         """A sign manifest's admission is the ``host:manifest_admit``
         span (and ``batch.manifest_admit_s``): raw bytes in → its batch
-        thread started, or refused with the ``outcome`` that says why."""
+        thread started, or refused with the ``outcome`` that says why.
+        The span's ``bytes`` (and ``batch.manifest_bytes_total``) is the
+        manifest as it arrived: every request's payload rides in it."""
         observe_delivery_wait(self._m_pubsub_wait)
         t0_ns, cpu0_ns = tracing.now_ns(), tracing.thread_cpu_ns()
         seen: dict = {"outcome": "bad_manifest", "verify_s": 0.0,
@@ -1183,9 +1186,11 @@ class BatchSigningScheduler:
             self._admit_manifest(raw, seen)
         finally:
             if seen.get("kind") == "sign":
+                self._m_manifest_bytes.inc(len(raw))
                 self._batch_stage(
                     "host:manifest_admit", self._m_admit, seen["batch_id"],
-                    t0_ns, cpu0_ns, n=seen["n"], outcome=seen["outcome"],
+                    t0_ns, cpu0_ns, n=seen["n"], bytes=len(raw),
+                    outcome=seen["outcome"],
                     parse_s=seen["parse_s"], verify_s=seen["verify_s"],
                     reused=seen["reused"], verified=seen["verified"],
                     leader=seen["leader"],
@@ -1955,7 +1960,7 @@ class BatchSigningScheduler:
             else:
                 party = BatchedEDDSASigningParty(
                     f"bsign:{batch_id}", node.node_id, quorum, shares,
-                    messages, cohorts=cohorts,
+                    messages, cohorts=cohorts, metrics=self.metrics,
                 )
             party_s = time.perf_counter() - t_party0
         except (ProtocolError, NotEnoughParticipants) as e:

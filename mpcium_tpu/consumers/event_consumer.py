@@ -60,6 +60,7 @@ class EventConsumer:
         self._gc_stop = threading.Event()
         self._gc_thread: Optional[threading.Thread] = None
         self._m_intake = self.metrics.histogram("intake.handle_s")
+        self._m_tx_bytes = self.metrics.counter("intake.tx_bytes_total")
         self._m_verify = self.metrics.histogram("intake.verify_initiator_s")
         self._m_pubsub_wait = self.metrics.histogram("transport.pubsub_wait_s")
         self.scheduler = None
@@ -478,7 +479,9 @@ class EventConsumer:
     def _on_sign(self, raw: bytes) -> None:
         """Handles mpc:sign — wrapped by publish_with_reply, so the payload
         carries the reply inbox. The whole handling is the request's
-        ``intake`` span on its lane (and ``intake.handle_s``)."""
+        ``intake`` span on its lane (and ``intake.handle_s``);
+        ``intake.tx_bytes_total`` counts the payload (``tx``) of every
+        request that parsed."""
         observe_delivery_wait(self._m_pubsub_wait)
         t0_ns = tracing.now_ns()
         seen: dict = {"outcome": "bad_event", "verify_s": 0.0}
@@ -489,6 +492,7 @@ class EventConsumer:
             self._m_intake.observe((t1_ns - t0_ns) / 1e9)
             msg = seen.get("msg")
             if msg is not None:
+                self._m_tx_bytes.inc(len(msg.tx))
                 sched = self.scheduler
                 tracing.emit(
                     "intake", t0_ns, t1_ns, node=self.node.node_id,

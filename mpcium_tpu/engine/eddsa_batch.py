@@ -9,8 +9,10 @@ matching reference pkg/mpc/eddsa_rounds.go:23-25); here the per-round math
 runs on device over ``(B, …)`` tensors, and since the device hash suite
 (ops.hash_suite) the hashing does too: commitments batch through the
 SHA-256 kernel and the RFC 8032 challenge through the 64-bit-lane
-SHA-512 kernel, so the round tensors never round-trip through the host
-(MPCIUM_EDDSA_DEVICE_HASH=0 restores the native/hashlib path).
+SHA-512 kernel, over the raw messages whatever their lengths (lengths are
+data to that kernel, never a compile), so the round tensors never
+round-trip through the host (MPCIUM_EDDSA_DEVICE_HASH=0 restores the
+native/hashlib path).
 
 Wire format for batched rounds is *byte tensors*, not JSON: a party's
 round-1 message is the (B, 32) array of compressed nonce commitments, etc.
@@ -283,45 +285,75 @@ def device_hash_enabled() -> bool:
     return os.environ.get("MPCIUM_EDDSA_DEVICE_HASH", "1") != "0"
 
 
-def challenge_device(R_comp, A_comp, M) -> jnp.ndarray:
+def pack_messages(
+    messages: Sequence[bytes],
+) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+    """The batch's messages as the device challenge hash takes them:
+    ``(M, lens)`` with ``M`` (B, cap − 64) uint8, each row its message
+    zero-filled to the width of the SHA-512 rung that holds the longest
+    ``R ‖ A ‖ M`` (``hash_suite.sha512_rung_cap``), and ``lens`` (B,)
+    int32 the message lengths. Lengths are data to the hash program: two
+    batches on one rung compile once whatever their lengths. ``None``
+    where a message is past the top rung (1,967 bytes): the caller hashes
+    on the host."""
+    lens = np.fromiter((len(m) for m in messages), np.int32, len(messages))
+    cap = hs.sha512_rung_cap(64 + int(lens.max(initial=0)))
+    if cap is None:
+        return None
+    width = cap - 64
+    M = np.frombuffer(
+        b"".join(m.ljust(width, b"\0") for m in messages), np.uint8
+    ).reshape(len(messages), width)
+    return M, lens
+
+
+def challenge_device(R_comp, A_comp, M, lens=None) -> jnp.ndarray:
     """Device challenge hashes: SHA-512(R ‖ A ‖ M) over (B, 32)/(B, 32)/
-    (B, L) uint8 rows (device or host) → (B, 64) device digests, one
-    fused dispatch through the 64-bit-lane kernel. The batch engine calls
-    this directly so c64 never leaves the device."""
-    msg = jnp.concatenate(
+    (B, W) uint8 rows (device or host) → (B, 64) device digests, through
+    the masked 64-bit-lane kernel (``hash_suite.sha512_masked``). ``lens``
+    (B,) int32 on the host: each row's message length, the rows
+    zero-filled past it and ``W`` a rung's width (:func:`pack_messages`
+    makes both); without it every row is ``W`` bytes long. The batch
+    engine and the served party call this directly so R and c64 never
+    leave the device."""
+    if lens is None:
+        B, W = M.shape
+        lens = np.full((B,), W, np.int32)
+        cap = hs.sha512_rung_cap(64 + W)
+        if cap is None:
+            raise ValueError(f"a {W}-byte message is past the top rung")
+        M = jnp.pad(jnp.asarray(M), ((0, 0), (0, cap - 64 - W)))
+    rows = jnp.concatenate(
         [jnp.asarray(R_comp), jnp.asarray(A_comp), jnp.asarray(M)], axis=-1
     )
-    return hs.sha512(msg)
+    return hs.sha512_masked(rows, to_dev(lens + 64))
 
 
 def challenge_hashes(
     R_comp: np.ndarray, A_comp: np.ndarray, messages: Sequence[bytes]
 ) -> np.ndarray:
-    """Per-session SHA-512(R ‖ A ‖ M) → (B, 64) uint8.
+    """Per-session SHA-512(R ‖ A ‖ M) → (B, 64) uint8 on the host.
 
-    Equal-length messages (the common case: 32-byte tx digests) hash on
-    device as ONE fused dispatch (:func:`challenge_device`);
-    MPCIUM_EDDSA_DEVICE_HASH=0 falls back to the native C++ batch call
-    and ragged batches fall back to per-row hashlib. All three paths are
-    byte-identical (tests/test_hash_suite.py, tests/test_eddsa_batch.py).
+    Messages of any lengths up to the top rung hash on the device as one
+    dispatch (:func:`challenge_device`). MPCIUM_EDDSA_DEVICE_HASH=0 and a
+    message past the top rung hash on the host: the native C++ batch call
+    for equal lengths, else one ``hashlib`` call a row, the reference
+    every path is held to byte for byte (tests/test_hash_suite.py,
+    tests/test_eddsa_batch.py).
     """
     from .. import native
 
+    packed = pack_messages(messages) if device_hash_enabled() else None
+    if packed is not None:
+        return np.asarray(challenge_device(R_comp, A_comp, *packed))  # mpcflow: host-ok — host-facing helper egress; the batch engine uses challenge_device and keeps c64 on device
+    R = np.asarray(R_comp)  # mpcflow: host-ok — host hash (MPCIUM_EDDSA_DEVICE_HASH=0, or a message past the top rung): the host hashers read host rows
+    A = np.asarray(A_comp)  # mpcflow: host-ok — host hash (MPCIUM_EDDSA_DEVICE_HASH=0, or a message past the top rung): the host hashers read host rows
     lens = {len(m) for m in messages}
     if len(lens) == 1:
         M = np.frombuffer(b"".join(messages), dtype=np.uint8).reshape(
             len(messages), lens.pop()
         )
-        if device_hash_enabled():
-            return np.asarray(challenge_device(R_comp, A_comp, M))  # mpcflow: host-ok — host-facing helper egress; the batch engine uses challenge_device and keeps c64 on device
-        return native.batch_sha512(
-            b"",
-            np.concatenate(
-                [np.asarray(R_comp), np.asarray(A_comp), M], axis=1  # mpcflow: host-ok — MPCIUM_EDDSA_DEVICE_HASH=0 fallback: the native batch hasher reads host rows
-            ),
-        )
-    R = np.asarray(R_comp)  # mpcflow: host-ok — ragged-message fallback: per-row hashlib reads host bytes
-    A = np.asarray(A_comp)  # mpcflow: host-ok — ragged-message fallback: per-row hashlib reads host bytes
+        return native.batch_sha512(b"", np.concatenate([R, A, M], axis=1))
     out = np.empty((len(messages), 64), dtype=np.uint8)
     for i, m in enumerate(messages):
         out[i] = np.frombuffer(
@@ -424,9 +456,11 @@ class BatchedCoSigners:
         cohort's host stage (fraud verdict, signature egress) drains on
         the pipeline worker. ALL nonce/blind bytes are drawn for the
         full batch, in K=1 serial order, before the split — signatures
-        are bit-identical for every K (tests/test_pipeline.py). The
-        hashlib/native fallback paths (MPCIUM_EDDSA_DEVICE_HASH=0,
-        ragged messages) stay serial.
+        are bit-identical for every K (tests/test_pipeline.py). Messages
+        of different lengths take the same path: their lengths are data
+        to the challenge hash (:func:`pack_messages`). The host-hash
+        paths (MPCIUM_EDDSA_DEVICE_HASH=0, a message past the top rung)
+        stay serial.
 
         With mpctrace armed, device-phase spans (``phase:*``) are emitted
         with a sync at each phase boundary; untraced runs take the no-op
@@ -445,8 +479,8 @@ class BatchedCoSigners:
         ])
 
         use_dev_hash = device_hash_enabled()
-        lens = {len(m) for m in messages}
-        if not use_dev_hash or len(lens) != 1:
+        packed = pack_messages(messages) if use_dev_hash else None
+        if packed is None:
             out = self._sign_fallback(messages, r64, blinds, use_dev_hash)
             compile_watch.finish(_cw)
             return out
@@ -454,9 +488,7 @@ class BatchedCoSigners:
         from . import pipeline as pl
 
         plan = pl.CohortPlan.for_batch(B, cohorts)
-        Mrows = np.frombuffer(b"".join(messages), np.uint8).reshape(
-            B, lens.pop()
-        )
+        Mrows, Mlens = packed
         pref = jnp.asarray(
             np.frombuffer(b"mpcium-tpu/eddsa-commit", np.uint8)
         )
@@ -485,7 +517,9 @@ class BatchedCoSigners:
                 if not fraud_free:
                     raise RuntimeError("commitment fraud detected")
                 A_c = self._A_dev[sl]
-                c64 = challenge_device(st["R_sum"], A_c, to_dev(Mrows[sl]))
+                c64 = challenge_device(
+                    st["R_sum"], A_c, to_dev(Mrows[sl]), Mlens[sl]
+                )
                 st = round_step_partial(
                     st, c64, to_dev(self.lamx[:, sl], axis=1)
                 )
@@ -520,9 +554,10 @@ class BatchedCoSigners:
         blinds: np.ndarray,
         use_dev_hash: bool,
     ) -> Tuple[np.ndarray, np.ndarray]:
-        """The serial (K=1) path for the native/hashlib fallbacks:
-        MPCIUM_EDDSA_DEVICE_HASH=0 and ragged message batches. Same
-        rounds, host hashing, no cohort split."""
+        """The serial (K=1) path for the host challenge hash:
+        MPCIUM_EDDSA_DEVICE_HASH=0, and a message past the top rung
+        (``use_dev_hash`` then still holds for the commitments). Same
+        rounds, no cohort split."""
         q, B = self.q, self.B
         _pt = tracing.PhaseTimer(
             "eddsa.sign", _trace_sync, node="engine", tid=f"eddsa:B{B}",
@@ -578,20 +613,13 @@ class BatchedCoSigners:
             R_sum, ok_R = aggregate_nonce(jnp.asarray(R_host))
         _pt.mark("r2_decommit_aggregate", R_sum)
 
-        # -- round 3: challenge (device SHA-512, fused; ragged messages
-        # fall back to the host hasher) + partials (one (q, B) dispatch)
-        lens = {len(m) for m in messages}
-        if use_dev_hash and len(lens) == 1:
-            Mrows = np.frombuffer(b"".join(messages), np.uint8).reshape(
-                B, lens.pop()
+        # -- round 3: challenge on the host (this path is the host
+        # hash's) + partials (one (q, B) dispatch)
+        c64 = jnp.asarray(
+            challenge_hashes(
+                np.asarray(R_sum), self.A_comp, messages  # mpcflow: host-ok — host challenge hash (MPCIUM_EDDSA_DEVICE_HASH=0, or a message past the top rung); sign() keeps R on device
             )
-            c64 = challenge_device(R_sum, self._A_dev, Mrows)
-        else:
-            c64 = jnp.asarray(
-                challenge_hashes(
-                    np.asarray(R_sum), self.A_comp, messages  # mpcflow: host-ok — ragged-message fallback: per-row hashlib reads host bytes; the equal-length default stays on device
-                )
-            )
+        )
         parts = partial_signature(
             r_limbs,
             jnp.broadcast_to(c64, (q,) + c64.shape),

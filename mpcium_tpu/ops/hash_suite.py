@@ -10,10 +10,14 @@ HOST_TRANSFER_BUDGET.json):
   delegates so its public API is unchanged.
 * **SHA-512** — 64-bit lanes as ``(hi, lo)`` uint32 limb pairs with
   explicit carry, because JAX defaults to 32-bit ints and the TPU has
-  no native 64-bit integer path; 80 rounds, 128-byte blocks. Wired into
-  ``engine/eddsa_batch.py::challenge_hashes`` (the Ed25519 3.1k/s
-  plateau was the host SHA-512 round-trip) and usable per-session by
-  ``protocol/eddsa/signing.py``.
+  no native 64-bit integer path; 80 rounds, 128-byte blocks. The
+  RFC 8032 challenge of ``engine/eddsa_batch.py`` (``challenge_device``)
+  runs through ``sha512_masked``: rows of DIFFERENT lengths in one
+  program, the lengths data and the width a rung of ``SHA512_RUNGS``, so
+  raw messages (a Solana transaction message is 150–1,167 bytes) never
+  compile by length and never go back to the host. ``sha512_fixed`` (one
+  static length) stays for the per-session path of
+  ``protocol/eddsa/signing.py`` and as a reference.
 * **PRG expansion** (``prg_expand_device``) — the IKNP seed→keystream
   expansion ``sha256(prefix ‖ seed ‖ le16(j) ‖ le32(blk))``,
   byte-identical to ``native.prg_expand`` / ``mta_ot._prg``, batched
@@ -337,6 +341,91 @@ def sha512_fixed(data: jnp.ndarray, msg_len: int) -> jnp.ndarray:
 def sha512(data: jnp.ndarray) -> jnp.ndarray:
     """Batched SHA-512 over the last axis: (..., L) uint8 → (..., 64)."""
     return sha512_fixed(data, data.shape[-1])
+
+
+# -- rows of different lengths in one program --------------------------------
+#
+# ``sha512_fixed`` compiles once per message length. A batch of RFC 8032
+# challenges over raw messages (a Solana transaction message is 150 to
+# 1,167 bytes, and the lengths differ inside one batch) needs the length
+# to be DATA: the rows come zero-filled to a common width, the padding is
+# placed per lane, every block of the width is compressed, and a lane
+# keeps the state it had after its own last block. The width comes from a
+# short ladder of block counts, so a process compiles one program per
+# (batch, rung) whatever lengths arrive.
+
+SHA512_RUNGS = (1, 2, 4, 8, 16)  # blocks of 128 bytes
+_SHA512_TAIL = 17  # the 0x80 byte and the 16-byte bit length
+
+
+def sha512_rung_cap(longest: int):
+    """The row width (bytes) of the smallest rung that holds a row of
+    ``longest`` bytes with its padding: ``blocks * 128 - 17``. ``None``
+    past the top rung (2,031 bytes)."""
+    for blocks in SHA512_RUNGS:
+        if longest + _SHA512_TAIL <= blocks * 128:
+            return blocks * 128 - _SHA512_TAIL
+    return None
+
+
+def sha512_masked_blocks(cap: int) -> int:
+    """128-byte blocks :func:`sha512_masked` compresses for every lane of
+    rows ``cap`` bytes wide."""
+    return -(-(cap + _SHA512_TAIL) // 128)
+
+
+def sha512_masked_core(rows: jnp.ndarray, lens: jnp.ndarray) -> jnp.ndarray:
+    """Pure trace function: ``rows`` (..., cap) uint8, ``lens`` (...,)
+    int32 with ``0 <= lens <= cap`` → (..., 64) digests of each lane's
+    first ``lens`` bytes. Bytes past a lane's length are ignored."""
+    batch, cap = rows.shape[:-1], rows.shape[-1]
+    n_blocks = sha512_masked_blocks(cap)
+    width = n_blocks * 128
+    lens = lens.astype(jnp.int32)
+    pos = jnp.arange(width, dtype=jnp.int32)
+    full = jnp.concatenate(
+        [rows, jnp.zeros(batch + (width - cap,), jnp.uint8)], axis=-1
+    )
+    at = lens[..., None]
+    full = jnp.where(
+        pos < at, full, jnp.where(pos == at, jnp.uint8(0x80), jnp.uint8(0))
+    )
+    words = bytes_to_words32(full)  # (..., 32·n_blocks) BE uint32 halves
+    # the lane's last block is the one its bit length ends: 64-bit length
+    # in the last two words (the field's high quadword stays zero)
+    last = (lens + (_SHA512_TAIL - 1)) // 128
+    widx = jnp.arange(n_blocks * 32, dtype=jnp.int32)
+    ulen = lens.astype(jnp.uint32)[..., None]
+    end = (last * 32 + 31)[..., None]
+    words = words | jnp.where(widx == end, ulen << 3, jnp.uint32(0))
+    words = words | jnp.where(widx == end - 1, ulen >> 29, jnp.uint32(0))
+    blocks = jnp.moveaxis(
+        words.reshape(batch + (n_blocks, 32)), -2, 0
+    )  # (n_blocks, ..., 32)
+    sh = jnp.broadcast_to(jnp.asarray(_H512_HI), batch + (8,))
+    sl = jnp.broadcast_to(jnp.asarray(_H512_LO), batch + (8,))
+
+    def step(st, xs):
+        i, blk = xs
+        nh, nl = sha512_compress(*st, blk[..., 0::2], blk[..., 1::2])
+        live = (i <= last)[..., None]
+        return (jnp.where(live, nh, st[0]), jnp.where(live, nl, st[1])), None
+
+    # the blocks through ONE compiled compression, as sha256_core's
+    (sh, sl), _ = lax.scan(
+        step, (sh, sl), (jnp.arange(n_blocks, dtype=jnp.int32), blocks)
+    )
+    out = jnp.stack([sh, sl], axis=-1).reshape(batch + (16,))
+    return words32_to_bytes(out)
+
+
+@jax.jit
+def sha512_masked(rows: jnp.ndarray, lens: jnp.ndarray) -> jnp.ndarray:
+    """Batched SHA-512 over rows of different lengths: ``rows`` (..., cap)
+    uint8 zero-filled past each lane's length, ``lens`` (...,) int32 →
+    (..., 64) uint8. One compile per (batch shape, ``cap``); callers take
+    ``cap`` from :func:`sha512_rung_cap`, so lengths never compile."""
+    return sha512_masked_core(rows, lens)
 
 
 def sha512_bytes(data: bytes) -> bytes:

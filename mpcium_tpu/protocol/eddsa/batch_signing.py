@@ -35,6 +35,7 @@ from ...core import bignum as bn
 from ...core import hostmath as hm
 from ...engine import eddsa_batch as eb
 from ...engine import pipeline as pl
+from ...ops import hash_suite as hs
 from ...perf import compile_watch
 from ...utils import tracing
 from ..base import KeygenShare, PartyBase, ProtocolError, RoundMsg, party_xs
@@ -63,9 +64,19 @@ class BatchedEDDSASigningParty(PartyBase):
 
     ``shares``: this node's key shares, one per wallet (batch order is the
     manifest order, identical on every quorum member). ``messages``: the
-    B digests/transactions to sign. All wallets must share the signing
-    quorum (``party_ids``); universes may differ per wallet (λ is computed
-    per wallet from its own keygen universe).
+    B raw messages to sign (RFC 8032 has no prehash: the challenge is
+    SHA-512(R ‖ A ‖ M) over the message as it came), of any lengths,
+    which may differ inside the batch; they are packed for the device
+    hash once, here (``eb.pack_messages``). All wallets must share the
+    signing quorum (``party_ids``); universes may differ per wallet (λ is
+    computed per wallet from its own keygen universe).
+
+    ``metrics``: the node's registry (the scheduler hands its own):
+    counters ``party.eddsa.hash_blocks_total`` (128-byte blocks the
+    device compressed for challenges: lanes × the rung) and
+    ``party.eddsa.host_hash_rows_total`` (challenges hashed on the host:
+    MPCIUM_EDDSA_DEVICE_HASH=0, or a message past the top rung); none,
+    nothing is counted.
     """
 
     def __init__(
@@ -77,6 +88,7 @@ class BatchedEDDSASigningParty(PartyBase):
         messages: Sequence[bytes],
         rng=None,
         cohorts: Optional[int] = None,
+        metrics=None,
     ):
         import secrets as _secrets
 
@@ -86,6 +98,20 @@ class BatchedEDDSASigningParty(PartyBase):
             raise ValueError("one share per message required")
         self.B = len(shares)
         self.messages = [bytes(m) for m in messages]
+        # (M, lens) for the device challenge hash; None: hashed on the host
+        self._packed = (
+            eb.pack_messages(self.messages)
+            if eb.device_hash_enabled() else None
+        )
+        self._msg_lens = np.fromiter(
+            (len(m) for m in self.messages), np.int64, self.B
+        )
+        self._m_hash_blocks = self._m_host_rows = None
+        if metrics is not None:
+            self._m_hash_blocks = metrics.counter(
+                "party.eddsa.hash_blocks_total")
+            self._m_host_rows = metrics.counter(
+                "party.eddsa.host_hash_rows_total")
         lamx = []
         for s in shares:
             if s.key_type != "ed25519":
@@ -208,25 +234,44 @@ class BatchedEDDSASigningParty(PartyBase):
             [np.frombuffer(b, dtype=np.uint8).reshape(self.B, 32) for b in R_blocks]
         )
 
+        packed = self._packed
+        # 128-byte blocks a lane of this batch costs the device hash
+        rung = 0 if packed is None else hs.sha512_masked_blocks(
+            64 + packed[0].shape[1])
+
         def make_job(ci: int, sl: slice):
             def job():
+                lanes = sl.stop - sl.start
                 with tracing.span(
                     "phase:bsign_aggregate_partial",
-                    batch=sl.stop - sl.start, cohort=ci,
+                    batch=lanes, cohort=ci,
                     q=len(self.party_ids), cpu=True,
+                    msg_bytes=int(self._msg_lens[sl].sum()),
+                    hash_blocks=lanes * rung,
                 ):
                     R_sum, ok_R = eb.aggregate_nonce(
                         eb.to_dev(R_all[:, sl], axis=1)
                     )
-                    R_sum_h = np.asarray(R_sum)  # mpcflow: host-ok — R enters the host challenge hash
-                    c64 = eb.challenge_hashes(
-                        R_sum_h, self.A_comp[sl], self.messages[sl]
-                    )
+                    A_c = eb.to_dev(self.A_comp[sl])
+                    if packed is not None:
+                        c64 = eb.challenge_device(
+                            R_sum, A_c, eb.to_dev(packed[0][sl]),
+                            packed[1][sl],
+                        )
+                    else:
+                        R_sum_h = np.asarray(R_sum)  # mpcflow: host-ok — host challenge hash (MPCIUM_EDDSA_DEVICE_HASH=0, or a message past the top rung), counted in party.eddsa.host_hash_rows_total
+                        c64 = eb.to_dev(eb.challenge_hashes(
+                            R_sum_h, self.A_comp[sl], self.messages[sl]
+                        ))
                     parts = eb.partial_signature(
-                        self._r_limbs_c[ci], eb.to_dev(c64),
-                        eb.to_dev(self.lamx[sl]),
+                        self._r_limbs_c[ci], c64, eb.to_dev(self.lamx[sl]),
                     )
                     _span_sync(parts)
+                if packed is None:
+                    if self._m_host_rows is not None:
+                        self._m_host_rows.inc(lanes)
+                elif self._m_hash_blocks is not None:
+                    self._m_hash_blocks.inc(lanes * rung)
                 egress = yield (
                     "partial_egress",
                     lambda: (
@@ -234,18 +279,18 @@ class BatchedEDDSASigningParty(PartyBase):
                         np.asarray(ok_R),
                     ),
                 )
-                return R_sum_h, np.asarray(c64), parts, egress
+                return (R_sum, A_c, c64), parts, egress
 
             return job
 
         outs = pl.run_counter_phase(
             [make_job(ci, sl) for ci, sl in enumerate(self._plan.slices())]
         )
-        self._R_sum = pl.merge_rows([o[0] for o in outs])
-        self._c64 = pl.merge_rows([o[1] for o in outs])
-        self._parts_c = [o[2] for o in outs]
-        self._ok_R = pl.merge_rows([o[3][1] for o in outs])
-        s_block = pl.merge_rows([o[3][0] for o in outs])
+        # R, A and the challenge stay on the device for the last round
+        self._dev_c = [o[0] for o in outs]
+        self._parts_c = [o[1] for o in outs]
+        self._ok_R = pl.merge_rows([o[2][1] for o in outs])
+        s_block = pl.merge_rows([o[2][0] for o in outs])
         return self.broadcast(R3_PARTIAL, {"s": s_block.tobytes().hex()})
 
     def _finalize(self) -> None:
@@ -274,13 +319,9 @@ class BatchedEDDSASigningParty(PartyBase):
                             )
                         )
                     parts = jnp.stack(stacked)
-                    sigs, _s = eb.combine_signatures(
-                        parts, eb.to_dev(self._R_sum[sl])
-                    )
-                    ok = eb.verify_signatures(
-                        sigs, eb.to_dev(self.A_comp[sl]),
-                        eb.to_dev(self._c64[sl]),
-                    )
+                    R_sum, A_c, c64 = self._dev_c[ci]
+                    sigs, _s = eb.combine_signatures(parts, R_sum)
+                    ok = eb.verify_signatures(sigs, A_c, c64)
                     _span_sync(ok)
                 egress = yield (
                     "sig_egress",

@@ -163,6 +163,21 @@ def _sha512(s):
         msg_len=96)
 
 
+def _sha512_masked(blocks):
+    def build(s):
+        from mpcium_tpu.ops import hash_suite as hs
+
+        # the challenge rows of the served party: R ‖ A ‖ the raw message
+        # zero-filled to a rung's width, lengths as data (1 block: the
+        # 32-byte digests; 16: Solana messages up to the packet's limit)
+        cap = blocks * 128 - 17
+        assert hs.sha512_rung_cap(cap) == cap
+        return hs.sha512_masked, (
+            _sds((ED_B, cap), jnp.uint8, s), _sds((ED_B,), jnp.int32, s)), {}
+
+    return build
+
+
 CASES = {
     "ed25519.nonce_commitments": (_ed_nonce, None),
     "ed25519.aggregate_nonce": (_ed_aggregate, None),
@@ -175,6 +190,8 @@ CASES = {
     "secp256k1.base_mul": (_secp_base_mul, None),
     "hash.sha256": (_sha256, None),
     "hash.sha512": (_sha512, None),
+    "hash.sha512_masked.1": (_sha512_masked(1), None),
+    "hash.sha512_masked.16": (_sha512_masked(16), None),
 }
 
 

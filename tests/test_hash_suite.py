@@ -207,3 +207,115 @@ def test_ops_sha256_shim_unchanged():
     got = np.asarray(dev_sha256(jnp.asarray(rows)))
     for i in range(4):
         assert bytes(got[i]) == hashlib.sha256(rows[i].tobytes()).digest()
+
+
+# ---------------------------------------------------------------------------
+# SHA-512 over rows of different lengths (lengths are data, not a compile)
+# ---------------------------------------------------------------------------
+
+
+def _ragged(seed: bytes, lens, cap: int) -> np.ndarray:
+    rows = np.zeros((len(lens), cap), np.uint8)
+    for i, n in enumerate(lens):
+        rows[i, :n] = _rows(seed + b"|%d" % i, 1, max(n, 1))[0, :n]
+    return rows
+
+
+def _assert_masked_matches_hashlib(rows, lens):
+    got = np.asarray(hs.sha512_masked(
+        jnp.asarray(rows), jnp.asarray(np.asarray(lens, np.int32))))
+    for i, n in enumerate(lens):
+        assert bytes(got[i]) == hashlib.sha512(
+            rows[i, :n].tobytes()).digest(), (i, n)
+
+
+def test_the_rung_ladder_holds_every_length_up_to_the_top():
+    assert hs.SHA512_RUNGS == (1, 2, 4, 8, 16)
+    caps = [blocks * 128 - 17 for blocks in hs.SHA512_RUNGS]
+    assert caps == [111, 239, 495, 1007, 2031]
+    for cap, nxt in zip(caps, caps[1:] + [None]):
+        assert hs.sha512_rung_cap(cap) == cap
+        assert hs.sha512_rung_cap(cap + 1) == nxt
+        assert hs.sha512_masked_blocks(cap) * 128 == cap + 17
+    assert hs.sha512_rung_cap(0) == 111
+    # R ‖ A ‖ M: a 32-byte digest is the 1-block rung, a Solana message
+    # at the packet's limit the 16-block one, 1,967 bytes the longest
+    assert hs.sha512_rung_cap(64 + 32) == 111
+    assert hs.sha512_rung_cap(64 + 1167) == 2031
+    assert hs.sha512_rung_cap(64 + 1967) == 2031
+    assert hs.sha512_rung_cap(64 + 1968) is None
+
+
+@pytest.mark.parametrize("blocks", hs.SHA512_RUNGS)
+def test_sha512_masked_matches_hashlib_on_ragged_rows(blocks):
+    """Bit for bit against ``hashlib``, a row at a time: seeded ragged
+    rows at every rung, every length whose padding lands on a block's
+    edge (total lengths at 110, 111, 112, 127, 128, 129 modulo 128), the
+    empty row and the full one, lanes of one batch ending in different
+    blocks."""
+    cap = blocks * 128 - 17
+    edges = sorted({n for k in range(blocks + 1)
+                    for n in (128 * k + r for r in (-18, -17, -16, -1, 0, 1))
+                    if 0 <= n <= cap})
+    rng = np.random.default_rng(blocks)
+    drawn = [int(n) for n in rng.integers(0, cap + 1, size=8)]
+    lens = [0, cap] + edges + drawn
+    last_blocks = {(n + 16) // 128 for n in lens}
+    assert last_blocks == set(range(blocks))  # every block ends some lane
+    assert {n % 128 for n in edges} >= (
+        {110, 111} if blocks == 1 else {110, 111, 112, 127, 0, 1})
+    _assert_masked_matches_hashlib(
+        _ragged(b"masked|%d" % blocks, lens, cap), lens)
+
+
+def test_sha512_masked_ignores_what_stands_past_a_lanes_length():
+    lens = [0, 5, 96, 111]
+    rows = _rows(b"dirty", 4, 111)  # nothing zero-filled
+    _assert_masked_matches_hashlib(rows, lens)
+
+
+def test_sha512_masked_compiles_once_for_any_lengths():
+    """Two batches of one width and other lengths: one compile (the
+    lengths are data); another rung is another program."""
+    a = _ragged(b"once-a", [3, 200, 239, 17], 239)
+    b = _ragged(b"once-b", [239, 0, 64, 128], 239)
+    _assert_masked_matches_hashlib(a, [3, 200, 239, 17])
+    before = hs.sha512_masked._cache_size()
+    _assert_masked_matches_hashlib(b, [239, 0, 64, 128])
+    assert hs.sha512_masked._cache_size() == before
+    _assert_masked_matches_hashlib(_ragged(b"once-c", [9], 495), [9])
+    assert hs.sha512_masked._cache_size() == before + 1
+
+
+def test_sha512_masked_agrees_with_the_fixed_kernel_on_equal_rows():
+    rows = _rows(b"equal", 8, 96)
+    want = np.asarray(hs.sha512(jnp.asarray(rows)))
+    padded = np.zeros((8, 111), np.uint8)
+    padded[:, :96] = rows
+    got = np.asarray(hs.sha512_masked(
+        jnp.asarray(padded), jnp.full((8,), 96, jnp.int32)))
+    assert np.array_equal(got, want)
+
+
+def test_challenge_device_takes_lengths_as_data():
+    from mpcium_tpu.engine import eddsa_batch as eb
+
+    # 64 + L at 110, 111, 112, 127, 128 and 129 modulo 128 among them
+    sizes = (150, 215, 1167, 400, 46, 47, 48, 63, 64, 65, 1070, 1071, 1072)
+    R = _rows(b"R3", len(sizes), 32)
+    A = _rows(b"A3", len(sizes), 32)
+    msgs = [bytes(_rows(b"msg|%d" % n, 1, n)[0]) for n in sizes]
+    M, lens = eb.pack_messages(msgs)
+    assert M.shape == (len(sizes), 2031 - 64) and list(lens) == [len(m) for m in msgs]
+    assert all(bytes(M[i, :n]) == msgs[i] and not M[i, n:].any()
+               for i, n in enumerate(lens))
+    got = np.asarray(eb.challenge_device(R, A, M, lens))
+    for i, m in enumerate(msgs):
+        assert bytes(got[i]) == hashlib.sha512(
+            R[i].tobytes() + A[i].tobytes() + m).digest()
+    # the rung follows the longest message: 47 bytes is the last length
+    # of the 1-block rung, 1,967 the last of the top one, then the host
+    assert eb.pack_messages([b"x" * 47, b""])[0].shape == (2, 47)
+    assert eb.pack_messages([b"x" * 48])[0].shape == (1, 239 - 64)
+    assert eb.pack_messages([b"x" * 1967])[0].shape == (1, 1967)
+    assert eb.pack_messages([b"x" * 1968, b"y"]) is None
