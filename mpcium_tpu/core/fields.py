@@ -182,9 +182,88 @@ def ed25519_field() -> PseudoMersenneField:
     return PseudoMersenneField(k=255, c=19)
 
 
+# a chain's registers: x, the powers x^(2^n − 1) that later runs multiply
+# by, the running value and the accumulator a run squares into
+_X, _X2, _X3, _X22, _X44, _T, _ACC = range(7)
+
+
+def _secp256k1_chain(tail) -> np.ndarray:
+    """An addition chain for a secp256k1 exponent as a straight-line
+    program: rows (i, j, k), meaning r[k] = r[i]·r[j] over the seven
+    registers above, which all hold x at the start. The trunk is
+    libsecp256k1's (`fe_inv`, `fe_sqrt`): x^(2^n − 1) for n = 2, 3, 6, 9,
+    11, 22, 44, 88, 176, 220, 223, then 23 squarings and x22, which spells
+    the exponent's upper 246 bits; ``tail`` is the runs (squarings,
+    register multiplied in, or None) that spell its low ten. A run squares
+    into the accumulator, so the register it started from is whole at its
+    end; the result is in register T."""
+    prog = []
+
+    def run(src, n, by, dst):  # r[dst] = r[src]^(2^n) · r[by]
+        for _ in range(n):
+            prog.append((src, src, _ACC))
+            src = _ACC
+        if by is None:
+            prog[-1] = prog[-1][:2] + (dst,)
+        else:
+            prog.append((src, by, dst))
+
+    run(_X, 1, _X, _X2)
+    run(_X2, 1, _X, _X3)
+    run(_X3, 3, _X3, _T)  # x6
+    run(_T, 3, _X3, _T)  # x9
+    run(_T, 2, _X2, _T)  # x11
+    run(_T, 11, _T, _X22)
+    run(_X22, 22, _X22, _X44)
+    run(_X44, 44, _X44, _T)  # x88
+    run(_T, 88, _T, _T)  # x176
+    run(_T, 44, _X44, _T)  # x220
+    run(_T, 3, _X3, _T)  # x223
+    run(_T, 23, _X22, _T)
+    for n, by in tail:
+        run(_T, n, by, _T)
+    return np.asarray(prog, np.int32)
+
+
+class Secp256k1Field(PseudoMersenneField):
+    """F_p for p = 2^256 − 2^32 − 977, with its two constant powers (the
+    inverse and the square root) by addition chains: 255 squarings and 15
+    products for p − 2, 253 and 13 for (p + 1)/4, where square-and-multiply
+    over the exponent's bits (:meth:`pow_const`, 512 products) is nearly
+    twice the chain of field operations. A chain runs as ONE `lax.scan`
+    over its straight-line program, so a compiled program holds one
+    multiplication a power however long the chain."""
+
+    _INV = _secp256k1_chain([(5, _X), (3, _X2), (2, _X)])
+    _SQRT = _secp256k1_chain([(6, _X2), (2, None)])
+
+    def __init__(self):
+        super().__init__(k=256, c=(1 << 32) + 977)
+
+    def _chain(self, x: jnp.ndarray, prog: np.ndarray) -> jnp.ndarray:
+        def step(regs, row):
+            a = lax.dynamic_index_in_dim(regs, row[0], 0, keepdims=False)
+            b = lax.dynamic_index_in_dim(regs, row[1], 0, keepdims=False)
+            return lax.dynamic_update_index_in_dim(
+                regs, self.mul(a, b), row[2], 0
+            ), None
+
+        regs = jnp.broadcast_to(x, (_ACC + 1,) + x.shape)
+        regs, _ = lax.scan(step, regs, jnp.asarray(prog))
+        return regs[_T]
+
+    def inv(self, x: jnp.ndarray) -> jnp.ndarray:
+        """x^(p − 2): the inverse, inv(0) = 0 (callers gate on is_zero)."""
+        return self._chain(x, self._INV)
+
+    def sqrt_candidate(self, x: jnp.ndarray) -> jnp.ndarray:
+        """x^((p + 1)/4): a square root of x if it has one (p ≡ 3 mod 4)."""
+        return self._chain(x, self._SQRT)
+
+
 @functools.lru_cache(maxsize=None)
-def secp256k1_field() -> PseudoMersenneField:
-    return PseudoMersenneField(k=256, c=(1 << 32) + 977)
+def secp256k1_field() -> Secp256k1Field:
+    return Secp256k1Field()
 
 
 class Ed25519Sqrt:
@@ -215,6 +294,6 @@ class Secp256k1Sqrt:
 
     def sqrt(self, x: jnp.ndarray):
         F = self.F
-        root = F.pow_const(x, (F.p + 1) // 4)
+        root = F.sqrt_candidate(x)
         ok = F.eq(F.square(root), x)
         return root, ok

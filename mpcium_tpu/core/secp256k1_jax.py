@@ -7,6 +7,18 @@ doubling and the identity (0:1:0). Completeness costs ~40% more field muls
 than dedicated Jacobian add/double but removes all data-dependent control
 flow — the right trade for XLA/TPU batching (SURVEY.md §7).
 
+Hot-path design: a curve program's time on the chip is its count of field
+operations run one after another (each ends in sequential carry scans over
+the limbs), not their width. So (1) the independent field operations of
+the addition run as ONE operation over a leading stack axis, and every
+linear step of the formula reaches the reduction as a raw limb sum: an
+addition is four field operations in a row (two stacked products, two
+stacked sums; `tests/test_secp_chain_length.py` pins the count); (2) both
+ladders take 4-bit windows, and a window's entry is read by a one-hot sum
+over the sixteen entries: selects only, so no address depends on a digit
+of a secret scalar (SECURITY.md); (3) the field's inverse and square root
+are addition chains (`fields.Secp256k1Field`).
+
 This is the curve under GG18 ECDSA (reference uses tss.S256() via
 btcec/dcrec — pkg/mpc/ecdsa_keygen_session.go:83); the hot ops are the nonce
 commitments Γ_i = γ_i·G and R reconstruction in the signing rounds.
@@ -76,44 +88,71 @@ def to_host(p: SecpPointJ) -> list:
     return out
 
 
-def _mul_small_each(x: jnp.ndarray, ks) -> jnp.ndarray:
-    """Row i of a stack (k, ..., 22) times the small constant ks[i]."""
+@functools.lru_cache(maxsize=None)
+def _kp_limbs() -> np.ndarray:
+    """K·p ≥ 2^264 (the field's borrow-free subtraction offset) as 22
+    limbs, the top one above the radix: k of it added to a limb sum that
+    subtracts k field elements keeps the total non-negative."""
+    kp = secp256k1_field().kp_limbs.copy()
+    kp[-2] += kp[-1] << PROF.bits
+    return kp[:-1]
+
+
+def _sums(*rows: jnp.ndarray) -> jnp.ndarray:
+    """Integer-linear combinations of field elements, each row given as
+    its raw limb sum (non-negative total below 2^276, limbs of either
+    sign far inside int32), reduced as ONE field operation over a leading
+    stack axis: one carry, one fold."""
     F = secp256k1_field()
-    k = jnp.asarray(ks, jnp.int32).reshape((len(ks),) + (1,) * (x.ndim - 1))
-    return F.fold(bn.carry(bn.pad_limbs(x * k, 1), PROF))
+    return F.fold(bn.carry(bn.pad_limbs(jnp.stack(rows), 1), PROF))
+
+
+def _muls_of_sums(xs, ys) -> jnp.ndarray:
+    """The products xs[i]·ys[i] as ONE field multiplication over a leading
+    stack axis, each operand a field element or the RAW limb sum of two
+    (limbs ≤ 8,190): a column is at most 22·8,190² < 2^31, the product
+    below 2^530, so it is carried into 45 limbs, one more than `bn.mul`
+    pads for normalized operands."""
+    n = PROF.n_limbs
+    cols = jnp.einsum("...i,...j,ijn->...n", jnp.stack(xs), jnp.stack(ys),
+                      jnp.asarray(bn._conv_tensor(n, n)))
+    return secp256k1_field().fold(bn.carry(bn.pad_limbs(cols, 2), PROF))
 
 
 def add(a: SecpPointJ, b: SecpPointJ) -> SecpPointJ:
     """Complete addition, RCB15 Algorithm 7 (a=0, b3=21).
 
-    The formula's independent field operations run as ONE operation over a
-    leading stack axis (its twelve multiplications as two of six, its
-    additions and subtractions as five), so a compiled addition holds nine
-    field operations one after another and not thirty-six: the programs
-    that inline it are a quarter of the size, and a ladder's chain of
-    dependent operations a quarter of the length."""
+    Four field operations one after another: the formula's twelve
+    multiplications are two stacked products of six, and each of its
+    additions, subtractions and small multiples is a raw limb sum handed
+    to the reduction that follows it. The opening sums (X+Y, Y+Z, X+Z of
+    each operand) go into the first product unreduced; everything between
+    the products is one stacked :func:`_sums`, and so are the three
+    closing sums. Operands are normalized (limbs < 2^12) and so are the
+    results: the bounds in `_sums` and `_muls_of_sums` rest on that."""
     F = secp256k1_field()
     shape = jnp.broadcast_shapes(a.X.shape, b.X.shape)
     aX, aY, aZ, bX, bY, bZ = (
         jnp.broadcast_to(c, shape) for c in (*a, *b)
     )
-    st = jnp.stack
-    # aX+aY, aY+aZ, aX+aZ and the same of b
-    s = F.add(st([aX, aY, aX, bX, bY, bX]), st([aY, aZ, aZ, bY, bZ, bZ]))
-    m = F.mul(st([aX, aY, aZ, s[0], s[1], s[2]]),
-              st([bX, bY, bZ, s[3], s[4], s[5]]))
-    t0, t1, t2 = m[0], m[1], m[2]
-    k = _mul_small_each(st([t0, t2]), (3, _B3))       # 3·t0, b3·t2
-    p = F.add(st([t0, t1, t0, t1]), st([t1, t2, t2, k[1]]))
-    z3 = p[3]                                          # t1 + b3·t2
-    # t3, t4, y3 (the cross terms) and t1 - b3·t2
-    d = F.sub(st([m[3], m[4], m[5], t1]), st([p[0], p[1], p[2], k[1]]))
-    t3, t4, t1 = d[0], d[1], d[3]
-    y3 = F.mul_small(d[2], _B3)
-    w = F.mul(st([t4, t3, y3, t1, k[0], z3]),
-              st([y3, t1, k[0], z3, t3, t4]))
-    yz = F.add(st([w[3], w[5]]), st([w[2], w[4]]))
-    return SecpPointJ(F.sub(w[1], w[0]), yz[0], yz[1])
+    t0, t1, t2, m3, m4, m5 = _muls_of_sums(
+        [aX, aY, aZ, aX + aY, aY + aZ, aX + aZ],
+        [bX, bY, bZ, bX + bY, bY + bZ, bX + bZ],
+    )
+    kp = _kp_limbs()
+    t2b = _B3 * t2
+    # 3·t0, t1 + b3·t2, t1 − b3·t2, the cross terms t3 and t4, b3·(cross)
+    k0, z3, t1, t3, t4, y3 = _sums(
+        3 * t0,
+        t1 + t2b,
+        t1 - t2b + _B3 * kp,
+        m3 - t0 - t1 + 2 * kp,
+        m4 - t1 - t2 + 2 * kp,
+        _B3 * (m5 - t0 - t2 + 2 * kp),
+    )
+    w = F.mul(jnp.stack([t4, t3, y3, t1, k0, z3]),
+              jnp.stack([y3, t1, k0, z3, t3, t4]))
+    return SecpPointJ(*_sums(w[1] - w[0] + kp, w[3] + w[2], w[5] + w[4]))
 
 
 def double(a: SecpPointJ) -> SecpPointJ:
@@ -154,15 +193,13 @@ def _digits(bits: jnp.ndarray) -> jnp.ndarray:
     return jnp.moveaxis(d, -1, 0)[::-1].astype(jnp.int32)
 
 
-def _pick(table: SecpPointJ, d: jnp.ndarray) -> SecpPointJ:
-    """table: coordinates (16, ..., 22), one entry a digit; d (...,) →
-    the entry of each lane's digit."""
-    idx = d[None, ..., None]
-    return SecpPointJ(*(
-        jnp.take_along_axis(c, jnp.broadcast_to(idx, (1,) + c.shape[1:]),
-                            axis=0)[0]
-        for c in table
-    ))
+def _pick(table: jnp.ndarray, d: jnp.ndarray) -> SecpPointJ:
+    """table (3, 16, ..., 22), one entry a digit; d (...,) → each lane's
+    entry as a one-hot sum: fifteen selects, no address that depends on
+    the digit."""
+    ks = jnp.arange(1 << _WINDOW, dtype=jnp.int32).reshape((-1,) + (1,) * d.ndim)
+    hot = (d[None] == ks)[None, ..., None]
+    return SecpPointJ(*jnp.sum(jnp.where(hot, table, 0), axis=1))
 
 
 def scalar_mul(bits: jnp.ndarray, p: SecpPointJ) -> SecpPointJ:
@@ -173,17 +210,17 @@ def scalar_mul(bits: jnp.ndarray, p: SecpPointJ) -> SecpPointJ:
         bits = jnp.pad(
             bits, [(0, 0)] * (bits.ndim - 1) + [(0, SCALAR_BITS - n_bits)]
         )
-    rows = [identity(bits.shape[:-1]), p]
+    batch = bits.shape[:-1]
 
     def next_row(row, _):
         row = add(row, p)
         return row, row
 
     _, more = lax.scan(next_row, p, None, length=(1 << _WINDOW) - 2)
-    table = SecpPointJ(*(
-        jnp.concatenate([jnp.stack([r0, r1]), m], axis=0)
-        for r0, r1, m in zip(rows[0], rows[1], more)
-    ))
+    table = jnp.stack([
+        jnp.concatenate([jnp.stack([c0, c1]), cs])
+        for c0, c1, cs in zip(identity(batch), p, more)
+    ])  # (3, 16, ..., 22)
 
     def step(acc, d):
         entry = _pick(table, d)
@@ -198,42 +235,44 @@ def scalar_mul(bits: jnp.ndarray, p: SecpPointJ) -> SecpPointJ:
 
         return lax.fori_loop(0, _WINDOW + 1, run, acc), None
 
-    acc, _ = lax.scan(step, identity(bits.shape[:-1]), _digits(bits))
+    acc, _ = lax.scan(step, identity(batch), _digits(bits))
     return acc
 
 
 @functools.lru_cache(maxsize=None)
-def _base_table() -> tuple:
-    """Constants d·16^i·G for window i in [0, 64), digit d in [0, 16):
-    three (64, 16, 22) int32 arrays, the entry of digit 0 the identity
-    (0:1:0)."""
+def _base_table() -> np.ndarray:
+    """Constants d·16^i·G for window i in [0, 64), digit d in [0, 16): a
+    (64, 3, 16, 22) int32 array of X, Y, Z, the entry of digit 0 the
+    identity (0:1:0)."""
     F = secp256k1_field()
-    xs, ys, zs = [], [], []
+    rows = []
     base = hm.SECP_G
     for _ in range(_N_WINDOWS):
-        xs.append(0), ys.append(1), zs.append(0)
+        rows.append((0, 1, 0))
         cur = base
         for _d in range(1, 1 << _WINDOW):
-            xs.append(cur.x), ys.append(cur.y), zs.append(1)
+            rows.append((cur.x, cur.y, 1))
             cur = hm.secp_add(cur, base)
         base = cur  # 16·base
-    shape = (_N_WINDOWS, 1 << _WINDOW, PROF.n_limbs)
-    return tuple(np.asarray(F.from_ints(v)).reshape(shape)
-                 for v in (xs, ys, zs))
+    flat = np.asarray(F.from_ints([v for row in rows for v in row]))
+    return np.ascontiguousarray(flat.reshape(
+        _N_WINDOWS, 1 << _WINDOW, 3, PROF.n_limbs
+    ).transpose(0, 2, 1, 3))
 
 
 def base_mul(bits: jnp.ndarray) -> SecpPointJ:
     """Fixed-base k·G: one addition a 4-bit window from the table of
     d·16^i·G (no doublings)."""
-    table = tuple(jnp.asarray(a) for a in _base_table())
     digits = _digits(bits)[::-1]  # the table's window 0 is the lowest
+    table = jnp.asarray(_base_table())
+    # every lane reads the same entries: lane axes of extent 1
+    table = table.reshape(table.shape[:3] + (1,) * (bits.ndim - 1) + table.shape[3:])
 
     def step(acc, sl):
-        d, X, Y, Z = sl
-        entry = SecpPointJ(*(c[d] for c in (X, Y, Z)))
-        return add(acc, entry), None
+        d, rows = sl
+        return add(acc, _pick(rows, d)), None
 
-    acc, _ = lax.scan(step, identity(bits.shape[:-1]), (digits,) + table)
+    acc, _ = lax.scan(step, identity(bits.shape[:-1]), (digits, table))
     return acc
 
 

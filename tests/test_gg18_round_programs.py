@@ -26,6 +26,8 @@ from mpcium_tpu.core import secp256k1_jax as sp
 from mpcium_tpu.engine import gg18_batch as gb
 
 Q = hm.SECP_N
+WIRE_DIGEST_AT_PR_43 = (
+    "11965ea807853982999e026785cc928e91888a9700046c339782189c71c29d92")
 IDS = ["node0", "node1", "node2"]
 XS = {"node0": 1, "node1": 2, "node2": 3}
 B = 2
@@ -192,6 +194,15 @@ def _sign(w: _Wave, tamper=None):
         r, sig, rec, ok = gb.gg18_final(
             s["ok"], s["s"], _stack(r9, p, "s"), m, s["r"], s["rec"], Y)
         out[p] = tuple(np.asarray(x) for x in (r, sig, rec, ok))
+    wire = hashlib.sha256()
+    for blocks in (r4, r5, r6, r8, r9):
+        for p in IDS:
+            for field in sorted(blocks[p]):
+                wire.update(np.ascontiguousarray(blocks[p][field]).tobytes())
+    for p in IDS:
+        for x in out[p]:
+            wire.update(x.tobytes())
+    seen["wire"] = wire.hexdigest()
     return out, seen
 
 
@@ -260,6 +271,16 @@ def test_an_honest_wave_signs_what_openssl_accepts(honest):
                      ec.ECDSA(utils.Prehashed(hashes.SHA256())))
             assert int(rec[i]) & 1 == (R[i].y & 1) ^ (
                 si != (kinv[i] * (m[i] + ri * w.keys[i])) % Q)
+
+
+def test_the_wave_is_byte_for_byte_what_it_was_before_pr_44(honest):
+    """Every block a signer sends in rounds 4 to 9 and every signature of
+    the seeded wave, hashed in order: the digest PR 43's tree (commit
+    f921b1d) gives for this file's `_Wave(seed=7)`. A change to the curve
+    arithmetic under the round programs (PR 44: the four-call addition,
+    the chains, the one-hot reads) moves no byte of the wire."""
+    _w, (_out, seen) = honest
+    assert seen["wire"] == WIRE_DIGEST_AT_PR_43
 
 
 def _flip(blocks, pid, field, lane):
