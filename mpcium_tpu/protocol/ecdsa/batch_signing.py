@@ -407,10 +407,11 @@ class BatchedECDSASigningParty(BatchBlockMixin, PartyBase):
     # session+sender binding, so the two cannot drift)
     _parse_bytes = BatchBlockMixin._parse_block
 
-    def _phase(self, handler: str):
-        """The ``phase:gg18_*`` span of a handler's device work, and its
-        seconds into ``party.ecdsa.phase_s``."""
-        return _Phase(self, PHASES[handler])
+    def _phase(self, handler: str, **attrs):
+        """The ``phase:gg18_*`` span of a handler's device work (``attrs``:
+        what the handler adds to the span's own), and its seconds into
+        ``party.ecdsa.phase_s``."""
+        return _Phase(self, PHASES[handler], attrs)
 
     def _wide(self) -> np.ndarray:
         """(B, 40) uniform bytes: a scalar mod q once reduced (bias 2^-64)."""
@@ -533,7 +534,8 @@ class BatchedECDSASigningParty(BatchBlockMixin, PartyBase):
         B = self.B
         out = []
         nb = gb.wire_bytes
-        with self._phase("_respond") as ph:
+        # pairs: the ordered MtA pairs answered here as Bob, one a peer
+        with self._phase("_respond", pairs=len(self.others())) as ph:
             payloads, device = {}, []
             for j in self.others():
                 mta = self.mta_in[j]  # alice = j, bob = self
@@ -593,7 +595,8 @@ class BatchedECDSASigningParty(BatchBlockMixin, PartyBase):
             "v": nb(own.pmx.prof_n2), "w": nb(own.ctx_nt.prof),
             "s": nb(own.pmx.prof_n),
         }
-        with self._phase("_delta") as ph:
+        # pairs: those whose answers are verified and decrypted as Alice
+        with self._phase("_delta", pairs=len(self.others())) as ph:
             alphas = []
             for j in self.others():
                 mta = self.mta_out[j]
@@ -769,22 +772,27 @@ class BatchedECDSASigningParty(BatchBlockMixin, PartyBase):
 
 class _Phase:
     """A handler's ``phase:gg18_<name>`` span (attributes ``batch``, ``n``,
-    ``cohort``, ``cpu_s``: the handler's own CPU seconds, the rest of the
-    span being the device wait it ends in; a child of the ``round:`` span
-    open on this thread), ended
-    by ``sync`` of the handler's device results only while tracing is
-    armed, and its seconds into the node's ``party.ecdsa.phase_s``."""
+    ``q``: the party's signers, fewer than the committee while a node is
+    out; ``cohort``; ``cpu_s``: the handler's own CPU seconds, the rest of
+    the span being the device wait it ends in; and what the handler adds:
+    ``pairs`` on the two MtA phases; a child of the ``round:`` span open on
+    this thread), ended by ``sync`` of the handler's device results only
+    while tracing is armed, and its seconds into the node's
+    ``party.ecdsa.phase_s``."""
 
-    def __init__(self, party: BatchedECDSASigningParty, name: str):
+    def __init__(self, party: BatchedECDSASigningParty, name: str,
+                 attrs: dict):
         self._party = party
         self._name = f"phase:gg18_{name}"
+        self._attrs = attrs
 
     def __enter__(self) -> "_Phase":
         party = self._party
         self._t0 = tracing.now_ns()
         self._span = tracing.span(
             self._name, batch=party.session_id.removeprefix("bsign:"),
-            n=party.B, cohort=0, cpu=True,
+            n=party.B, q=len(party.party_ids), cohort=0, cpu=True,
+            **self._attrs,
         )
         self._span.__enter__()
         return self

@@ -1,8 +1,10 @@
 """BatchedECDSASigningParty's device-phase spans, with the engine's round
 programs stubbed out (no GG18 compile): every handler opens and closes one
 ``phase:gg18_*`` span, a child of the ``round:`` span of the message that
-completed its round, with the attributes ``batch``, ``n`` and ``cohort``;
-the table of phase names is exactly what the handlers emit; the node
+completed its round, with the attributes ``batch``, ``n``, ``q`` and
+``cohort`` (and ``pairs`` on the two MtA phases), with every node of the
+committee signing and with node0 out (two signers of three: PR 45); the
+table of phase names is exactly what the handlers emit; the node
 registry gets the phase histogram and the count of MtA responses; and the
 wire blocks have the widths the receiving side parses."""
 import numpy as np
@@ -78,7 +80,8 @@ def stubbed(monkeypatch):
 
     ok = np.ones((B,), bool)
     pt = object()
-    prog("gg18_setup", lambda *a: (pt, (pt,) * 3, (_z(B, 33),) * 3, ok, 0))
+    prog("gg18_setup", lambda pub, C, digests, x_bits, lam_bits: (
+        pt, (pt,) * len(x_bits), (_z(B, 33),) * len(x_bits), ok, 0))
     prog("gg18_r1_commit", lambda own, *a: {
         "k": 0, "gamma": 0, "Gamma": pt, "Gamma_comp": _z(B, 33),
         "commit": _z(B, 32), "kp": 0, "c_k": 0, "ck": _w(own.pmx.prof_n2)})
@@ -138,7 +141,9 @@ def spans():
     tracing.disable()
 
 
-def _parties(registries, caches=None):
+def _parties(registries, caches=None, signers=IDS):
+    """The parties of ``signers``, each over its share of a key dealt to
+    the whole committee ``IDS``."""
     from mpcium_tpu.cluster import load_test_preparams
 
     shares = gb.dealer_keygen_secp_batch(
@@ -146,9 +151,10 @@ def _parties(registries, caches=None):
     digests = [bytes([i]) * 32 for i in range(B)]
     return {
         pid: bs.BatchedECDSASigningParty(
-            SID, pid, IDS, shares[i], digests, metrics=registries[pid],
+            SID, pid, signers, shares[IDS.index(pid)], digests,
+            metrics=registries[pid],
             contexts=caches[pid] if caches else None)
-        for i, pid in enumerate(IDS)
+        for pid in signers
     }
 
 
@@ -171,9 +177,13 @@ def _run_as_a_session_does(parties):
                 queue.extend(p.receive(msg))
 
 
-def test_each_handler_has_one_phase_span_under_its_round(stubbed, spans):
-    registries = {pid: MetricsRegistry() for pid in IDS}
-    parties = _parties(registries)
+@pytest.mark.parametrize("signers", [IDS, ["node1", "node2"]],
+                         ids=["every_node", "node0_out"])
+def test_each_handler_has_one_phase_span_under_its_round(
+        stubbed, spans, signers):
+    q = len(signers)
+    registries = {pid: MetricsRegistry() for pid in signers}
+    parties = _parties(registries, signers=signers)
     _run_as_a_session_does(parties)
     assert all(p.done and p.result["ok"].all() for p in parties.values())
 
@@ -182,9 +192,10 @@ def test_each_handler_has_one_phase_span_under_its_round(stubbed, spans):
     # the table is the set the handlers emit, once a handler and node
     assert {s["name"] for s in phases} == set(bs.PHASE_SPANS)
     assert len(bs.PHASE_SPANS) == len(bs.PHASES) == 10
-    for pid in IDS:
+    for pid in signers:
         mine = [s["name"] for s in phases if s["node"] == pid]
         assert sorted(mine) == sorted(bs.PHASE_SPANS), pid
+    assert {s["node"] for s in phases} == set(signers)
     # each under the round span that caused it: the start handler under
     # ``round:start``, a later one under the round whose last message
     # completed the stage before it
@@ -207,7 +218,12 @@ def test_each_handler_has_one_phase_span_under_its_round(stubbed, spans):
         # lasted, give or take one 10 ms step of the thread clock
         assert 0 <= attrs.pop("cpu_s") <= (
             s["t1_ns"] - s["t0_ns"]) / 1e9 + 0.010
-        assert attrs == {"batch": "b-1", "n": B, "cohort": 0}
+        # the party's signers on every phase (PR 45); on the two MtA
+        # phases the ordered pairs this signer answers, or verifies
+        want = {"batch": "b-1", "n": B, "q": q, "cohort": 0}
+        if handler in ("_respond", "_delta"):
+            want["pairs"] = q - 1
+        assert attrs == want, s["name"]
         assert s["trace_id"] == tracing.trace_id_for(SID)
 
     # the registry of each node: ten phases observed, and its responses:
@@ -216,14 +232,18 @@ def test_each_handler_has_one_phase_span_under_its_round(stubbed, spans):
         snap = reg.snapshot()
         assert snap["histograms"]["party.ecdsa.phase_s"]["count"] == 10
         assert snap["counters"]["party.ecdsa.mta_responses_total"] == (
-            2 * (len(IDS) - 1) * B)
+            2 * (q - 1) * B)
+    # over the signers, a signature: 12 with every node, 4 with node0 out
+    assert sum(reg.snapshot()["counters"]["party.ecdsa.mta_responses_total"]
+               for reg in registries.values()) // B == 2 * q * (q - 1)
 
     # every round program ran, the per-peer ones once a peer (and secret)
-    per_node = {n: stubbed.count(n) // len(IDS)
+    per_node = {n: stubbed.count(n) // q
                 for names in gb.ROUND_PROGRAMS.values() for n in names}
     assert per_node == {
-        "gg18_setup": 1, "gg18_r1_commit": 1, "gg18_r1_prove": 2,
-        "gg18_r2_verify": 2, "gg18_r2_respond": 2, "gg18_r3_verify": 2,
+        "gg18_setup": 1, "gg18_r1_commit": 1, "gg18_r1_prove": q - 1,
+        "gg18_r2_verify": q - 1, "gg18_r2_respond": q - 1,
+        "gg18_r3_verify": q - 1,
         "gg18_r3_delta": 1, "gg18_r4_pok": 1, "gg18_r5a_verify": 1,
         "gg18_r5a_commit": 1, "gg18_r5b": 1, "gg18_r5c_verify": 1,
         "gg18_r5c_commit": 1, "gg18_r5e": 1, "gg18_final": 1}
@@ -287,6 +307,17 @@ def test_a_nodes_cache_keeps_its_committees_contexts_across_batches(stubbed):
     assert all(len(c) == len(IDS) for c in caches.values())
     caches["node0"].clear()
     assert len(caches["node0"]) == 0
+    # with node0 out a live node asks for the two live parties' only
+    live = ["node1", "node2"]
+    fresh = {pid: bs.ContextCache() for pid in live}
+    built.clear()
+    _Ctx.__init__ = counting
+    try:
+        _parties({pid: None for pid in live}, fresh, signers=live)
+    finally:
+        _Ctx.__init__ = real
+    assert sorted(built) == ["node1", "node1", "node2", "node2"]
+    assert all(len(c) == 2 for c in fresh.values())
 
 
 def test_a_cached_context_ages_out_and_the_cap_holds():

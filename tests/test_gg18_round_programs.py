@@ -61,39 +61,43 @@ def _comp(pt: hm.SecpPoint) -> bytes:
 
 
 class _Wave:
-    """One wave of B wallets as three signers hold it after round 3, the
-    MtA's outcome dealt on the host; ``sigma_off`` adds to one signer's
-    share of k·x in the given lanes (shares that do not add up)."""
+    """One wave of B wallets dealt to the three nodes, as the signers
+    ``ids`` (all three; ``tests/test_gg18_subset_quorum.py``: any two) hold
+    it after round 3, the MtA's outcome dealt on the host among the
+    signers; ``sigma_off`` adds to one signer's share of k·x in the given
+    lanes (shares that do not add up)."""
 
-    def __init__(self, seed=7, sigma_off=None):
+    def __init__(self, seed=7, sigma_off=None, ids=IDS):
         rng = self.rng = random.Random(seed)
+        self.ids = ids
         self.keys = [rng.randrange(1, Q) for _ in range(B)]
         coef = [rng.randrange(1, Q) for _ in range(B)]  # threshold 1
         self.share = {p: [(x + c * XS[p]) % Q for x, c in zip(self.keys, coef)]
                       for p in IDS}
-        lam = {p: hm.lagrange_coeff(list(XS.values()), XS[p], Q) for p in IDS}
+        at = [XS[p] for p in ids]
+        lam = {p: hm.lagrange_coeff(at, XS[p], Q) for p in ids}
         self.lam = lam
-        self.w = {p: [lam[p] * s % Q for s in self.share[p]] for p in IDS}
+        self.w = {p: [lam[p] * s % Q for s in self.share[p]] for p in ids}
         self.pub = [hm.secp_mul(x, hm.SECP_G) for x in self.keys]
         self.commit = [[hm.secp_mul(x, hm.SECP_G) for x in self.keys],
                        [hm.secp_mul(c, hm.SECP_G) for c in coef]]
         self.digests = [rng.randbytes(32) for _ in range(B)]
-        self.k = {p: [rng.randrange(1, Q) for _ in range(B)] for p in IDS}
-        self.g = {p: [rng.randrange(1, Q) for _ in range(B)] for p in IDS}
-        ksum = [sum(self.k[p][i] for p in IDS) % Q for i in range(B)]
-        gsum = [sum(self.g[p][i] for p in IDS) % Q for i in range(B)]
+        self.k = {p: [rng.randrange(1, Q) for _ in range(B)] for p in ids}
+        self.g = {p: [rng.randrange(1, Q) for _ in range(B)] for p in ids}
+        ksum = [sum(self.k[p][i] for p in ids) % Q for i in range(B)]
+        gsum = [sum(self.g[p][i] for p in ids) % Q for i in range(B)]
         self.ksum = ksum
 
         def split(total):
-            a, b = rng.randrange(Q), rng.randrange(Q)
-            return [a, b, (total - a - b) % Q]
+            parts = [rng.randrange(Q) for _ in ids[1:]]
+            return parts + [(total - sum(parts)) % Q]
 
         d = [split(ksum[i] * gsum[i] % Q) for i in range(B)]
         s = [split(ksum[i] * self.keys[i] % Q) for i in range(B)]
         self.delta = {p: [d[i][j] for i in range(B)]
-                      for j, p in enumerate(IDS)}
+                      for j, p in enumerate(ids)}
         self.sigma = {p: [s[i][j] for i in range(B)]
-                      for j, p in enumerate(IDS)}
+                      for j, p in enumerate(ids)}
         for lane in sigma_off or ():
             self.sigma["node1"][lane] = (self.sigma["node1"][lane] + 1) % Q
 
@@ -104,17 +108,16 @@ def _setup(w: _Wave):
         np.stack([np.stack([np.frombuffer(_comp(c), np.uint8) for c in row])
                   for row in w.commit]),
         np.stack([np.frombuffer(d, np.uint8) for d in w.digests]),
-        sp.scalars_to_bits([XS[p] for p in IDS], n_bits=8),
-        sp.scalars_to_bits([w.lam[p] for p in IDS]),
+        sp.scalars_to_bits([XS[p] for p in w.ids], n_bits=8),
+        sp.scalars_to_bits([w.lam[p] for p in w.ids]),
     )
 
 
-def _others(pid):
-    return [p for p in IDS if p != pid]
-
-
 def _stack(blocks, pid, field):
-    return np.stack([np.asarray(blocks[j][field]) for j in _others(pid)])
+    """The peers' blocks as the party stacks them, (q − 1, B, n): every
+    signer's but ``pid``'s own, in the signers' order."""
+    return np.stack([np.asarray(blocks[j][field]) for j in blocks
+                     if j != pid])
 
 
 def _sign(w: _Wave, tamper=None):
@@ -129,7 +132,7 @@ def _sign(w: _Wave, tamper=None):
     # round 1's curve half and round 4: Γ_i, its commitment, the PoK of γ_i
     r4 = {}
     gam = {}
-    for p in IDS:
+    for p in w.ids:
         blind = _bytes(rng, B, 32)
         pt, comp, commit = gb._blk_gamma(_limbs(w.g[p]), blind, _bind(p))
         A, spok = gb.gg18_r4_pok(_bytes(rng, B, 40), _limbs(w.g[p]), comp,
@@ -141,7 +144,7 @@ def _sign(w: _Wave, tamper=None):
                  "bind": _bind(p)}
     tamper(4, r4)
     st, r5 = {}, {}
-    for p in IDS:
+    for p in w.ids:
         peers = {f: _stack(r4, p, f) for f in r4[p]}
         ok, delta, Gsum = gb.gg18_r5a_verify(
             np.ones((B,), bool), _limbs(w.delta[p]), gam[p], peers)
@@ -158,7 +161,7 @@ def _sign(w: _Wave, tamper=None):
         seen.setdefault("s_i", {})[p] = _ints(s["s"])
         r5[p] = {"c": np.asarray(s["commit"])}
     r6 = {}
-    for p in IDS:
+    for p in w.ids:
         s = st[p]
         Apok, sa, sb = gb.gg18_r5b(s["ka"], s["kb"], s["s"], s["li"], s["R"],
                                    s["vc"], s["ac"], _bind(p))
@@ -168,7 +171,7 @@ def _sign(w: _Wave, tamper=None):
                  "sb": np.asarray(sb), "bind": _bind(p)}
     tamper(6, r6)
     r8 = {}
-    for p in IDS:
+    for p in w.ids:
         s = st[p]
         peers = {f: _stack(r6, p, f) for f in r6[p]}
         ok, Vsum, Asum = gb.gg18_r5c_verify(s["ok"], s["V"], s["A"], s["R"],
@@ -183,23 +186,23 @@ def _sign(w: _Wave, tamper=None):
                  "bind": _bind(p)}
     tamper(8, r8)
     r9 = {}
-    for p in IDS:
+    for p in w.ids:
         s = st[p]
         peers = {f: _stack(r8, p, f) for f in r8[p]}
         s["ok"], block = gb.gg18_r5e(s["ok"], s["U"], s["T"], s["s"], peers)
         r9[p] = {"s": np.asarray(block)}
     out = {}
-    for p in IDS:
+    for p in w.ids:
         s = st[p]
         r, sig, rec, ok = gb.gg18_final(
             s["ok"], s["s"], _stack(r9, p, "s"), m, s["r"], s["rec"], Y)
         out[p] = tuple(np.asarray(x) for x in (r, sig, rec, ok))
     wire = hashlib.sha256()
     for blocks in (r4, r5, r6, r8, r9):
-        for p in IDS:
+        for p in w.ids:
             for field in sorted(blocks[p]):
                 wire.update(np.ascontiguousarray(blocks[p][field]).tobytes())
-    for p in IDS:
+    for p in w.ids:
         for x in out[p]:
             wire.update(x.tobytes())
     seen["wire"] = wire.hexdigest()
